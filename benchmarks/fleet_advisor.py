@@ -17,8 +17,10 @@ same task:
   * ``speedup``  — the advisories/s ratio (gated for presence, not
     magnitude — the optimizer-ratio precedent);
   * ``sharded``  — the same batched fleet with the cluster axis pmap-split
-    over ``--xla_force_host_platform_device_count=2`` forced host devices
-    (SNIPPETS 2/3): the multi-core serving row;
+    over every local device: two forced host devices on a CPU host
+    (``--xla_force_host_platform_device_count=2``, SNIPPETS 2/3), the
+    chips on a TPU host (the flag does not apply there).  The row name
+    carries the device count it actually sharded over (``_d<n>``);
   * ``cache``    — the dispatch-cache counters after the run (hits /
     misses / traces), recording that steady-state serving retraced
     nothing.
@@ -36,8 +38,9 @@ import statistics
 import sys
 import time
 
-# the sharded row needs forced host devices, and XLA reads the flag at
-# backend init — set it before anything imports jax
+# on a CPU host the sharded row needs forced host devices, and XLA reads
+# the flag at backend init — set it before anything imports jax (a TPU
+# backend ignores it and shards over its chips)
 _FLAG = "--xla_force_host_platform_device_count=2"
 if _FLAG not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()

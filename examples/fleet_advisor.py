@@ -3,7 +3,7 @@ dispatch per shape bucket, with a pmap-sharded serving path.
 
 A small heterogeneous fleet (mixed node counts and failure families, so
 the advisor exercises several shape buckets) is advised three ways —
-batched, per-cluster standalone, and sharded over forced host devices —
+batched, per-cluster standalone, and sharded over the local devices —
 and the answers are asserted bit-identical across all three (the CRN
 contract, docs/fleet.md).
 
@@ -11,8 +11,10 @@ Run:  PYTHONPATH=src python examples/fleet_advisor.py
 """
 import os
 
-# the sharded path fans the cluster axis over host devices; XLA reads the
-# flag at backend init, so it must be set before anything imports jax
+# the sharded path fans the cluster axis over every local device.  On a
+# CPU host the flag below makes two; XLA reads it at backend init, so it is
+# set before anything imports jax.  A TPU backend ignores it and shards over
+# its chips (one chip: a one-lane pmap).
 _FLAG = "--xla_force_host_platform_device_count=2"
 if _FLAG not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
@@ -34,9 +36,9 @@ def main():
     advisor = fleet.FleetAdvisor(**kw)
     advisories = advisor.advise(profiles)
 
+    devices = f"{jax.local_device_count()} {jax.default_backend()} device(s)"
     print(f"{len(advisories)} advisories over "
-          f"{len({p.bucket_key() for p in profiles})} shape buckets "
-          f"({jax.local_device_count()} host devices):")
+          f"{len({p.bucket_key() for p in profiles})} shape buckets:")
     for a in advisories:
         p = a.profile
         print(f"  {p.name}: n={p.n_nodes} {p.family:<11} "
@@ -59,7 +61,7 @@ def main():
     sharded = fleet.FleetAdvisor(shard=True, **kw).advise(profiles)
     for a, b in zip(advisories, sharded):
         assert a.best == b.best and a.knee == b.knee, a.profile.name
-    print(f"sharded ({jax.local_device_count()} devices) == unsharded")
+    print(f"sharded over {devices} == unsharded")
 
     # a repeat fleet is pure cache hits: no new trace, no new program
     before = advisor.cache_stats()

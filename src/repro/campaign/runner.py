@@ -128,7 +128,7 @@ def _dispatch_chunk(chunk: list, progress) -> list:
     mtbf = float(np.mean(failures.as_process(proc).mean_s()))
     cfgs = [r.exp.cfg for r in chunk]
     makespans = np.asarray([r.exp.makespan_s for r in chunk], np.float64)
-    with sweep.enable_x64():
+    with jax.enable_x64():
         # content-memoized float64 stacking (sweep's own input cache), with
         # the renewal preconditions checked per config
         _, stacked = sweep._renewal_device_inputs(cfgs)

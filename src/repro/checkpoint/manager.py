@@ -48,6 +48,9 @@ def _unflatten_into(example, flat: dict):
         if tuple(arr.shape) != tuple(leaf.shape):
             raise ValueError(f"checkpoint shape mismatch at {key}: "
                              f"{arr.shape} vs {leaf.shape}")
+        if arr.dtype.kind == "V":
+            # .npz keeps dtypes numpy does not know (bfloat16) as raw bytes
+            arr = arr.view(leaf.dtype)
         leaves.append(arr.astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -94,7 +94,7 @@ class LadderArrays:
 
     @property
     def num_levels(self) -> int:
-        return int(self.freq_ghz.shape[0])
+        return len(self.freq_ghz)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -40,8 +40,8 @@ residual by age-conditioned inverse CDF:
 
 ``residual(v, age)`` implements exactly that transform per process (``v``
 is the raw uniform draw, ``u = 1 - v``); the exponential's closed form
-``T = -mtbf * log1p(-v)`` drops the age, recovering the legacy sampler
-bit-for-bit.  ``sample_renewal_gaps`` runs the competing-risks recursion —
+``T = -mtbf * log1p(-v)`` drops the age (``Exponential.sample`` is the
+package's one exponential sampler).  ``sample_renewal_gaps`` runs the competing-risks recursion —
 residuals for all nodes, the epoch gap is the minimum, the failing node the
 argmin, survivor clocks advance by the gap, the failed clock resets — as a
 ``lax.scan`` that both the host oracle (``sweep.renewal_failure_gaps``) and
@@ -168,9 +168,9 @@ class FailureProcess:
         """Unconditional (age-0) float32 gap draws of the given shape.
 
         For per-node parameter arrays the trailing axis of ``shape`` is the
-        node axis.  For ``Exponential`` the transform is bit-identical to
-        ``jax.random.exponential(key, shape) * mtbf`` (same uniform, same
-        ``-log1p(-v)`` lowering).
+        node axis.  Every exponential draw of the package comes from here
+        (``Exponential.residual`` on one float32 uniform), so the sampling
+        sites agree bit for bit by construction.
         """
         v = jax.random.uniform(key, shape, jnp.float32)
         return self.residual(v, jnp.zeros_like(v))
@@ -187,8 +187,7 @@ class Exponential(FailureProcess):
         _check_positive("mtbf_s", self.mtbf_s)
 
     def residual(self, v, age):
-        # memoryless: the age drops out; -log1p(-v) matches
-        # jax.random.exponential's lowering bit-for-bit
+        # memoryless: the age drops out
         del age
         return jnp.asarray(self.mtbf_s, jnp.float32) * (-jnp.log1p(-v))
 
@@ -345,10 +344,9 @@ class Gamma(FailureProcess):
         return jnp.maximum(scale * (0.5 * (lo + hi)) - age, 0.0)
 
     def survival(self, t):
-        from jax.experimental import enable_x64
         z = np.asarray(t, np.float64) / np.asarray(self.scale_s, np.float64)
         k = np.asarray(self.k, np.float64)
-        with enable_x64():
+        with jax.enable_x64():
             return np.asarray(jsp.gammaincc(jnp.asarray(k), jnp.asarray(z)),
                               np.float64)
 
@@ -518,9 +516,7 @@ def sample_renewal_gaps(
     time the makespan meters).
     """
     if isinstance(process, Exponential):
-        draws = jax.random.exponential(
-            key, (n_runs, max_failures, n_nodes), dtype=jnp.float32
-        ) * jnp.asarray(process.mtbf_s, jnp.float32)
+        draws = process.sample(key, (n_runs, max_failures, n_nodes))
         return jnp.min(draws, axis=-1), jnp.argmin(draws, axis=-1)
 
     v = jax.random.uniform(

@@ -52,7 +52,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import energy_model as em
 from repro.core import failures, sweep
@@ -257,7 +256,7 @@ def policy_inputs(cfg: ScenarioConfig, table: PolicyTable) -> sweep.SweepInputs:
     """
     _check_grid(cfg, table)
     n_policies = len(table)
-    with enable_x64():
+    with jax.enable_x64():
         base = sweep.sweep_inputs(cfg, jnp.float64)
         stacked = jax.tree.map(
             lambda a: jnp.broadcast_to(a, (n_policies,) + a.shape), base)
@@ -363,7 +362,7 @@ def fleet_policy_inputs(cfgs: Sequence[ScenarioConfig],
             f"blocking topology (got {shapes}, {ladders}, {peers}); "
             "group heterogeneous node counts into shape buckets "
             "(repro.fleet.FleetAdvisor)")
-    with enable_x64():
+    with jax.enable_x64():
         return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *per)
 
 

@@ -35,7 +35,9 @@ __all__ = [
     "advance_checkpoint_sawtooth",
     "balanced_span",
     "timer_checkpoint_count",
+    "floor_mod",
     "checkpoint_plan",
+    "move_ahead_plan",
     "expected_savings",
     "optimal_checkpoint_interval",
 ]
@@ -51,6 +53,41 @@ def _ns(*arrays):
 # ---------------------------------------------------------------------------
 # analytic phase geometry (shared by simulator.py and sweep.py)
 # ---------------------------------------------------------------------------
+
+def _floor_quotient(q, period):
+    """``(floor(q / period), q - j * period)`` for ``q >= 0``.  A division
+    that is not correctly rounded (the TPU's) can land the quotient one off
+    near an integer; the remainder then leaves ``[0, period)`` and the
+    quotient is stepped back."""
+    xp = _ns(q, period)
+    j = xp.floor(q / period)
+    r = q - j * period
+    low, high = r < 0.0, r >= period
+    j = xp.where(low, j - 1.0, xp.where(high, j + 1.0, j))
+    return j, xp.where(low | high, q - j * period, r)
+
+
+def floor_mod(x, y):
+    """``x mod y`` (floor convention, ``y > 0``) as exact as ``jnp.mod`` on
+    an IEEE backend, also where the backend's division and remainder are
+    approximate (Mosaic on TPU).  The product ``q * y`` is split exactly
+    (Dekker; no fused multiply-add needed), so ``x - q * y`` rounds once."""
+    xp = _ns(x, y)
+    x, y = xp.asarray(x), xp.asarray(y)
+    split = 4097.0 if x.dtype == xp.float32 else 134217729.0   # 2^s + 1
+    q = xp.floor(x / y)
+
+    def halves(a):
+        c = split * a
+        hi = c - (c - a)
+        return hi, a - hi
+
+    p = q * y
+    q_hi, q_lo = halves(q)
+    y_hi, y_lo = halves(y)
+    e = ((q_hi * y_hi - p) + q_hi * y_lo + q_lo * y_hi) + q_lo * y_lo
+    r = (x - p) - e
+    return xp.where(r < 0.0, r + y, xp.where(r >= y, r - y, r))
 
 def advance_checkpoint_sawtooth(age0, delta, interval, dur):
     """Advance a timer-checkpoint sawtooth by ``delta`` wall seconds.
@@ -78,8 +115,8 @@ def advance_checkpoint_sawtooth(age0, delta, interval, dur):
     period = interval + dur
     fired = delta >= first
     q = xp.maximum(delta - first, 0.0)
-    j = xp.floor(q / period)                # index of the last fire <= delta
-    r = q - j * period                      # time since that fire began
+    # j: index of the last fire <= delta; r: time since that fire began
+    j, r = _floor_quotient(q, period)
     mid = fired & (r < dur)                 # failure lands inside a checkpoint
     n_fired = xp.where(fired, j + 1.0, 0.0)
     age = xp.where(fired, xp.where(mid, 0.0, r - dur), age0 + delta)
@@ -107,8 +144,8 @@ def balanced_span(age0, span, interval, dur):
     first = interval - age0                  # wall time of the first timer fire
     period = interval + dur
     q = xp.maximum(span - first, 0.0)
-    j = xp.floor(q / period)                 # completed fires before the span end
-    r = q - j * period                       # time since the last fire began
+    # j: completed fires before the span end; r: time since the last began
+    j, r = _floor_quotient(q, period)
     ckpt = xp.where(span > first, j * dur + xp.minimum(r, dur), 0.0)
     return span - ckpt, ckpt
 
@@ -176,7 +213,27 @@ def checkpoint_plan(
     exec_rem, age, t_failed = (xp.asarray(a) for a in (exec_rem, age, t_failed))
     n_timer = timer_checkpoint_count(
         exec_rem[..., None], age[..., None], beta, interval, eps)
-    n0 = n_timer[..., 0]
+    plan_move, age_at_block_fa, wait_at_block_fa = move_ahead_plan(
+        exec_rem, age, t_failed, n_timer[..., 0], interval=interval, dur=dur,
+        move_ahead=move_ahead, move_frac=move_frac)
+    n_ckpt = n_timer + xp.where(plan_move, 1.0, 0.0)[..., None]
+    return CheckpointPlan(
+        n_timer=n_timer,
+        n_ckpt=n_ckpt,
+        plan_move=plan_move,
+        age_at_block_fa=age_at_block_fa,
+        wait_at_block_fa=wait_at_block_fa,
+    )
+
+
+def move_ahead_plan(exec_rem, age, t_failed, n0, *, interval, dur,
+                    move_ahead, move_frac):
+    """The move-ahead half of ``checkpoint_plan`` given the fa-level timer
+    count ``n0`` (``timer_checkpoint_count`` at ``beta[0]``).  Split out so
+    the Pallas renewal kernel, which holds no trailing ladder axis, runs the
+    same operations.  Returns ``(plan_move, age_at_block_fa,
+    wait_at_block_fa)``."""
+    xp = _ns(exec_rem, age, t_failed, n0)
     wait_at_block_fa = t_failed - (exec_rem + n0 * dur)
     # age at block: if a timer fired during the compute phase the age restarts
     # from its end.
@@ -191,14 +248,7 @@ def checkpoint_plan(
         & (age_at_block_fa > move_frac * interval)
         & (wait_at_block_fa > dur)
     )
-    n_ckpt = n_timer + xp.where(plan_move, 1.0, 0.0)[..., None]
-    return CheckpointPlan(
-        n_timer=n_timer,
-        n_ckpt=n_ckpt,
-        plan_move=plan_move,
-        age_at_block_fa=age_at_block_fa,
-        wait_at_block_fa=wait_at_block_fa,
-    )
+    return plan_move, age_at_block_fa, wait_at_block_fa
 
 
 @dataclasses.dataclass(frozen=True)

@@ -49,6 +49,7 @@ __all__ = [
     "failure_clock_ages",
     "shift_failure",
     "post_recovery_anchor",
+    "next_rendezvous",
     "post_recovery_config",
 ]
 
@@ -316,7 +317,15 @@ def post_recovery_anchor(exec_rem, period, p_star=None):
         p_star = xp.max(exec_rem, axis=-1, keepdims=True)
     else:
         p_star = xp.asarray(p_star)[..., None]
-    gap = xp.mod(p_star - exec_rem, period)
+    return next_rendezvous(p_star, exec_rem, period)
+
+
+def next_rendezvous(p_star, exec_rem, period):
+    """The closed form under ``post_recovery_anchor`` with ``p_star``
+    already broadcastable against ``exec_rem`` and ``period`` — any layout
+    of the survivor axis (the Pallas renewal kernel keeps it leading)."""
+    xp = planning._ns(p_star, exec_rem, period)
+    gap = planning.floor_mod(p_star - exec_rem, period)
     return xp.where(gap == 0.0, period, period - gap)
 
 

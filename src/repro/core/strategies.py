@@ -189,6 +189,50 @@ evaluate_strategies = functools.partial(jax.jit, static_argnames=(
     "per_level_n_ckpt",))(evaluate_strategies_impl)
 
 
+def _fold_setup(t_comp_fa, t_failed, n_ckpt_cols, t_ckpt, ladder, sleep,
+                wait_mode, p_idle_wait, mu1, mu2):
+    """Casts shared by ``evaluate_strategies_fold`` and ``decision_margins``,
+    and ``level_terms(f)``: ladder level ``f``'s Algorithm-1 terms, each
+    comparison with both of its sides."""
+    t_comp_fa, t_failed, wait_mode = jnp.broadcast_arrays(
+        jnp.asarray(t_comp_fa, jnp.float32),
+        jnp.asarray(t_failed, jnp.float32),
+        jnp.asarray(wait_mode, jnp.int32),
+    )
+    t_ckpt = jnp.asarray(t_ckpt, jnp.float32)
+    mu1 = jnp.asarray(mu1, jnp.float32)
+    mu2 = jnp.asarray(mu2, jnp.float32)
+    # plain ints, not IntEnum members: enum instances fail JAX's exact-type
+    # literal check and would be captured as jaxpr constants, which the
+    # Pallas kernel reusing this fold (kernels/renewal_scan.py) rejects
+    active = wait_mode == int(em.WaitMode.ACTIVE)
+    min_level = ladder.num_levels - 1
+    p_awake = jnp.where(active, ladder.p_comp[min_level], p_idle_wait)
+    feas_rhs = t_failed * (1.0 + 1e-6) + 1e-3
+    trans_t, trans_e = sleep.transition_time, sleep.transition_energy
+    gate_t = mu1 * trans_t
+
+    def level_terms(f):
+        n_f = jnp.asarray(n_ckpt_cols[f], jnp.float32)
+        # same op order as comp_time / comp_energy / wait branches
+        ct = t_comp_fa * ladder.beta[f] + n_f * t_ckpt * ladder.gamma[f]
+        wt = t_failed - ct
+        e_comp = t_comp_fa * ladder.beta[f] * ladder.p_comp[f] \
+            + n_f * t_ckpt * ladder.gamma[f] * ladder.p_ckpt[f]
+        e_awake = jnp.maximum(wt, 0.0) * p_awake
+        e_sleep = trans_e + jnp.maximum(wt - trans_t, 0.0) * sleep.p_sleep
+        e_awake_mu2 = mu2 * e_awake
+        feasible = ct <= feas_rhs
+        sleeps = (wt > gate_t) & (e_sleep < e_awake_mu2)
+        total = jnp.where(
+            feasible, e_comp + jnp.where(sleeps, e_sleep, e_awake), jnp.inf)
+        return dict(ct=ct, feas_rhs=feas_rhs, wt=wt, gate_t=gate_t,
+                    e_comp=e_comp, e_sleep=e_sleep, e_awake_mu2=e_awake_mu2,
+                    feasible=feasible, sleeps=sleeps, total=total)
+
+    return t_comp_fa, t_failed, wait_mode, active, level_terms
+
+
 def evaluate_strategies_fold(
     t_comp_fa,
     t_failed,
@@ -224,52 +268,31 @@ def evaluate_strategies_fold(
     here so a float64 caller (the x64-traced renewal scan) cannot promote
     the Algorithm-1 energy math.
     """
-    t_comp_fa, t_failed, wait_mode = jnp.broadcast_arrays(
-        jnp.asarray(t_comp_fa, jnp.float32),
-        jnp.asarray(t_failed, jnp.float32),
-        jnp.asarray(wait_mode, jnp.int32),
-    )
-    t_ckpt = jnp.asarray(t_ckpt, jnp.float32)
-    mu1 = jnp.asarray(mu1, jnp.float32)
-    mu2 = jnp.asarray(mu2, jnp.float32)
+    t_comp_fa, t_failed, wait_mode, active, level_terms = _fold_setup(
+        t_comp_fa, t_failed, n_ckpt_cols, t_ckpt, ladder, sleep, wait_mode,
+        p_idle_wait, mu1, mu2)
     ref_level = int(ref_level)
-    # plain ints, not IntEnum members: enum instances fail JAX's exact-type
-    # literal check and would be captured as jaxpr constants, which the
-    # Pallas kernel reusing this fold (kernels/renewal_scan.py) rejects
-    active = wait_mode == int(em.WaitMode.ACTIVE)
-    min_level = ladder.num_levels - 1
-    p_awake = jnp.where(active, ladder.p_comp[min_level], p_idle_wait)
-    feas_rhs = t_failed * (1.0 + 1e-6) + 1e-3
-    trans_t, trans_e = sleep.transition_time, sleep.transition_energy
-    gate_t = mu1 * trans_t
 
     best = None
     for f in range(ladder.num_levels):
-        n_f = jnp.asarray(n_ckpt_cols[f], jnp.float32)
-        # same op order as comp_time / comp_energy / wait branches
-        ct = t_comp_fa * ladder.beta[f] + n_f * t_ckpt * ladder.gamma[f]
-        feasible = ct <= feas_rhs
-        wt = t_failed - ct
-        e_comp = t_comp_fa * ladder.beta[f] * ladder.p_comp[f] \
-            + n_f * t_ckpt * ladder.gamma[f] * ladder.p_ckpt[f]
-        e_awake = jnp.maximum(wt, 0.0) * p_awake
-        e_sleep = trans_e + jnp.maximum(wt - trans_t, 0.0) * sleep.p_sleep
-        sleeps = (wt > gate_t) & (e_sleep < mu2 * e_awake)
-        total = jnp.where(
-            feasible, e_comp + jnp.where(sleeps, e_sleep, e_awake), jnp.inf)
+        t = level_terms(f)
+        ct, sleeps, total = t["ct"], t["sleeps"], t["total"]
         if f == ref_level:
-            ct_ref, e_comp_ref, sleeps_ref = ct, e_comp, sleeps
+            ct_ref, e_comp_ref = ct, t["e_comp"]
         if best is None:
             best = dict(total=total, level=jnp.zeros_like(wait_mode),
-                        ct=ct, sleeps=sleeps, feasible_any=feasible)
+                        freq=jnp.full_like(total, ladder.freq_ghz[f]),
+                        ct=ct, sleeps=sleeps, feasible_any=t["feasible"])
         else:
             better = total < best["total"]  # strict: first minimum, as argmin
             best = dict(
                 total=jnp.where(better, total, best["total"]),
                 level=jnp.where(better, f, best["level"]),
+                freq=jnp.where(better, ladder.freq_ghz[f], best["freq"]),
                 ct=jnp.where(better, ct, best["ct"]),
-                sleeps=jnp.where(better, sleeps, best["sleeps"]),
-                feasible_any=best["feasible_any"] | feasible,
+                # boolean select as logic: Mosaic has no i1 select
+                sleeps=(better & sleeps) | (~better & best["sleeps"]),
+                feasible_any=best["feasible_any"] | t["feasible"],
             )
 
     eni = e_comp_ref + jnp.maximum(t_failed - ct_ref, 0.0) * jnp.where(
@@ -278,7 +301,7 @@ def evaluate_strategies_fold(
     e_sel = jnp.where(feasible_any, best["total"], eni)
     level = jnp.where(feasible_any, best["level"], ref_level)
     comp_time = jnp.where(feasible_any, best["ct"], ct_ref)
-    sleeps = jnp.where(feasible_any, best["sleeps"], sleeps_ref) & feasible_any
+    sleeps = best["sleeps"] & feasible_any
     wait_action = jnp.where(
         sleeps,
         int(em.WaitAction.SLEEP),
@@ -289,7 +312,10 @@ def evaluate_strategies_fold(
     saving = eni - e_sel
     return Decision(
         level=level.astype(jnp.int32),
-        freq_ghz=ladder.freq_ghz[level],
+        # selected, not gathered: the Pallas kernel's ladder is a tuple of
+        # SMEM scalars
+        freq_ghz=jnp.where(feasible_any, best["freq"],
+                           ladder.freq_ghz[ref_level]),
         comp_changed=level != ref_level,
         wait_action=wait_action,
         comp_time=comp_time,
@@ -300,6 +326,38 @@ def evaluate_strategies_fold(
         saving_pct=100.0 * saving / jnp.maximum(eni, 1e-9),
         feasible_any=feasible_any,
     )
+
+
+def decision_margins(t_comp_fa, t_failed, n_ckpt_cols, t_ckpt,
+                     ladder: em.LadderArrays, sleep: em.SleepArrays,
+                     wait_mode, p_idle_wait, mu1=6.0, mu2=1.0) -> jax.Array:
+    """Distance, in seconds, of each node's closest Algorithm-1 comparison
+    from its boundary, taken from the terms ``evaluate_strategies_fold``
+    decides on (same arguments, node-batch shaped result).
+
+    The comparisons: feasibility and the sleep gate (in seconds already),
+    sleep against awake, and the choice between the two cheapest feasible
+    levels (energies, over the ladder's largest compute power).  Two engines
+    whose inputs differ by less than the margin take the same decisions; a
+    node with a margin near zero sits on a tie that rounding decides.
+    """
+    *_, level_terms = _fold_setup(
+        t_comp_fa, t_failed, n_ckpt_cols, t_ckpt, ladder, sleep, wait_mode,
+        p_idle_wait, mu1, mu2)
+    levels = [level_terms(f) for f in range(ladder.num_levels)]
+    p_max = functools.reduce(
+        jnp.maximum, [ladder.p_comp[f] for f in range(ladder.num_levels)])
+    margin = functools.reduce(jnp.minimum, [jnp.minimum(
+        jnp.minimum(jnp.abs(t["ct"] - t["feas_rhs"]),
+                    jnp.abs(t["wt"] - t["gate_t"])),
+        jnp.abs(t["e_sleep"] - t["e_awake_mu2"]) / p_max) for t in levels])
+    totals = jnp.sort(jnp.stack([t["total"] for t in levels], axis=-1),
+                      axis=-1)
+    if ladder.num_levels > 1:
+        # inf - inf (fewer than two feasible levels) is no comparison
+        gap = (totals[..., 1] - totals[..., 0]) / p_max
+        margin = jnp.minimum(margin, jnp.where(jnp.isnan(gap), jnp.inf, gap))
+    return margin
 
 
 def evaluate_strategies_profile(

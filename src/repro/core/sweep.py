@@ -56,7 +56,7 @@ The renewal composition comes in two implementations:
     balanced-span energy, the trailing-span accounting, and (in the
     ``_device`` Monte-Carlo entry) the on-device gap sampling into **one
     jitted program** — no per-epoch host round-trips, no per-scenario
-    re-dispatch.  Geometry is traced under ``jax.experimental.enable_x64``
+    re-dispatch.  Geometry is traced under ``jax.enable_x64``
     so wall-clock times stay float64-exact against the oracle while the
     Algorithm-1 energy math stays float32, exactly as on the host path.
     ``tests/test_renewal_device.py`` pins the two paths together at
@@ -80,7 +80,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import energy_model as em
 from repro.core import failures
@@ -167,7 +166,7 @@ def sweep_inputs(cfg: ScenarioConfig, dtype=jnp.float32) -> SweepInputs:
     """Flatten a ``ScenarioConfig`` into sweep-engine arrays.
 
     ``dtype`` is float32 for the single-failure sweep; the device renewal
-    engine builds float64 inputs (under ``jax.experimental.enable_x64``) so
+    engine builds float64 inputs (under ``jax.enable_x64``) so
     the scan geometry matches the host float64 oracle, down-casting to
     float32 only at the Algorithm-1 dispatch.
     """
@@ -422,9 +421,8 @@ def exponential_failure_offsets(
     phase of each failure relative to the checkpoint/rendezvous sawtooths is
     what the exponential process implies, while float32 stays accurate.
     """
-    gaps = np.asarray(jax.random.exponential(key, (n_samples,)), np.float64)
-    arrivals = np.cumsum(gaps * float(mtbf_s))
-    return np.mod(arrivals, float(wrap_s)).astype(np.float32)
+    return failure_offsets(key, n_samples, failures.Exponential(mtbf_s),
+                           wrap_s)
 
 
 def failure_offsets(
@@ -643,12 +641,8 @@ def renewal_failure_gaps(
         mtbf_s = process.mtbf_s
     if mtbf_s is None:
         raise ValueError("provide mtbf_s or a FailureProcess")
-    draws = np.asarray(
-        jax.random.exponential(key, (n_runs, max_failures, n_nodes),
-                               dtype=jnp.float32)
-        * jnp.asarray(mtbf_s, jnp.float32),
-        np.float64,
-    )
+    draws = np.asarray(failures.Exponential(mtbf_s).sample(
+        key, (n_runs, max_failures, n_nodes)), np.float64)
     return draws.min(axis=-1), draws.argmin(axis=-1)
 
 
@@ -947,6 +941,41 @@ jax.tree_util.register_dataclass(
 )
 
 
+def _felled_race(m, age_surv, age_fail, exec_rem):
+    """Re-execution length and resync point ``P*`` of an epoch.  Felled
+    survivors' lost work joins the re-execution race and ``P*`` is the
+    furthest *non-felled* survivor; ``m=None`` is the single-failure epoch
+    (the failed node's age, the furthest survivor) — the same values an
+    all-False mask gives, without the mask."""
+    if m is None:
+        return age_fail, jnp.maximum(jnp.max(exec_rem, axis=-1), 0.0)
+    reexec = jnp.maximum(
+        age_fail, jnp.max(jnp.where(m, age_surv, -jnp.inf), axis=-1))
+    p_star = jnp.maximum(
+        jnp.max(jnp.where(m, -jnp.inf, exec_rem), axis=-1), 0.0)
+    return reexec, p_star
+
+
+def _ordered_sum(x, axis=None):
+    """``jnp.sum`` over ``axis`` (or all axes) as a fixed pairwise tree of
+    elementwise adds.  XLA orders a reduction's adds by the layout it picks
+    for the program, so one lane of a vmapped program and the same lane
+    computed alone can differ in the last bits (the TPU's float64 does).
+    Elementwise adds are never reassociated, so this order is the same in
+    every program that computes the lane: per-cluster fleet rows equal
+    standalone calls bit for bit on every backend."""
+    x = jnp.asarray(x)
+    axes = range(x.ndim) if axis is None else [axis % x.ndim]
+    x = jnp.moveaxis(x, list(axes), list(range(-len(axes), 0)))
+    x = x.reshape(x.shape[:x.ndim - len(axes)] + (-1,))
+    width = 1 << (x.shape[-1] - 1).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
                   stats: bool = False, felled=None):
     """Whole-run renewal recursion for ONE scenario x ONE run as a
@@ -1007,8 +1036,13 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # stacked (K, ...) epoch states, where XLA vectorizes it across the
     # whole epochs x runs x scenarios grid instead of re-issuing it inside
     # a 32-step sequential loop.
-    m_all = (jnp.zeros(gaps.shape + (n,), bool) if felled is None
-             else jnp.asarray(felled, bool))
+    # None on the single-failure path: an all-False mask is a compile-time
+    # constant that XLA folds through every reduction over it, which made
+    # the TPU compile of the fleet core 141 s against 25 s without it, and
+    # of the scan engine 47 s against 17 s (tests/test_tpu_compile.py, an
+    # ahead-of-time compile for a described v5e; results are the same, an
+    # all-False mask reducing exactly)
+    m_all = None if felled is None else jnp.asarray(felled, bool)
 
     def step(carry, xs):
         # ages_all stacks the survivors' checkpoint ages with the failed
@@ -1022,13 +1056,7 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         rem = jnp.mod(exec_anchor - work[:-1], period)
         exec_rem = jnp.where(rem == 0.0, period, rem)
         d_eff_fail = d_eff_all[-1]
-        # felled survivors' lost work joins the re-execution race; the
-        # resync point is the furthest non-felled survivor (neutral for an
-        # all-False mask: reexec = failed age, p_star = max exec_rem)
-        reexec = jnp.maximum(
-            age_all[-1], jnp.max(jnp.where(m, age_all[:-1], -jnp.inf)))
-        p_star = jnp.maximum(
-            jnp.max(jnp.where(m, -jnp.inf, exec_rem)), 0.0)
+        reexec, p_star = _felled_race(m, age_all[:-1], age_all[-1], exec_rem)
         t_e = t_dr + reexec + p_star                             # epoch span T_E
 
         # re-anchor: coordinated resync checkpoint -> ages 0, progress P*
@@ -1054,16 +1082,11 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
 
     # --- per-epoch accounting, vectorized over the stacked epochs ----------
     age_f = age_all[..., :-1]                                    # (K, N)
-    # felled survivors' lost work joins the re-execution race (neutral for
-    # the all-False mask — see the step comment)
-    reexec_f = jnp.maximum(
-        age_all[..., -1],
-        jnp.max(jnp.where(m_all, age_f, -jnp.inf), axis=-1))     # (K,)
+    reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
+                                    exec_rem_k)                  # (K,)
     d_eff_fail = d_eff_all[..., -1]
     t_recover = t_dr + reexec_f                                  # (K,)
     t_failed_k = t_recover[..., None] + exec_rem_k               # (K, N)
-    p_star = jnp.maximum(
-        jnp.max(jnp.where(m_all, -jnp.inf, exec_rem_k), axis=-1), 0.0)
     t_e = t_recover + p_star
 
     # balanced span energy up to each node's (snapped) failure instant,
@@ -1072,9 +1095,9 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # checkpoints, so ``work``/``d_eff - work`` from the scan's sawtooth
     # *is* the ``balanced_span`` decomposition (both are exact multiples
     # of ``dur`` — tests pin the identity) without recomputing it.
-    e_bal = jnp.sum(work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0,
-                    axis=-1)
-    balanced = jnp.sum(jnp.where(
+    e_bal = _ordered_sum(
+        work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0, axis=-1)
+    balanced = _ordered_sum(jnp.where(
         valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
 
     # failed node over [failure, T_E]: down (0 W) + restart at P_ckpt +
@@ -1082,10 +1105,10 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # plays the same closed-form role (identical in reference and
     # intervened runs, so the saving is untouched); the factor is 1 for the
     # single-failure path.
+    n_felled = 1.0 if m_all is None else 1.0 + jnp.sum(m_all, axis=-1)
     epoch_failed = jnp.where(
         valid,
-        (1.0 + jnp.sum(m_all, axis=-1))
-        * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0), 0.0)
+        n_felled * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0), 0.0)
 
     # per-level checkpoint plan as F separate node-batch columns: the fa
     # column comes from the shared checkpoint_plan (it also decides the
@@ -1114,7 +1137,8 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         t_e2 - jnp.maximum(t_failed_k, f8(decision.comp_time)), 0.0) * p_comp0
     # felled slots are accounted through epoch_failed's closed form, not the
     # survivor window energies (their Algorithm-1 point is meaningless)
-    v2 = valid[..., None] & ~m_all
+    v2 = (jnp.broadcast_to(valid[..., None], exec_rem_k.shape)
+          if m_all is None else valid[..., None] & ~m_all)
     epoch_ref = jnp.where(v2, f8(decision.energy_reference) + trail_ref, 0.0)
     epoch_int = jnp.where(v2, f8(decision.energy_intervened) + trail_int, 0.0)
 
@@ -1122,11 +1146,11 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # can nudge bal_elapsed slightly past the makespan; clamp)
     span = jnp.maximum(makespan - bal_elapsed, 0.0)
     w_t, ck_t = planning.balanced_span(ages_all, span, interval, dur)
-    balanced = balanced + jnp.sum(w_t * p_comp0 + ck_t * p_ckpt0)
+    balanced = balanced + _ordered_sum(w_t * p_comp0 + ck_t * p_ckpt0)
 
-    e_failed = jnp.sum(epoch_failed)
-    energy_ref = balanced + jnp.sum(epoch_ref) + e_failed
-    energy_int = balanced + jnp.sum(epoch_int) + e_failed
+    e_failed = _ordered_sum(epoch_failed)
+    energy_ref = balanced + _ordered_sum(epoch_ref) + e_failed
+    energy_int = balanced + _ordered_sum(epoch_int) + e_failed
     common = dict(
         valid=valid,
         n_failures=jnp.sum(valid.astype(jnp.int32)),
@@ -1398,7 +1422,7 @@ def renewal_compose_policies(stacked: SweepInputs, gaps, makespan_s,
     likewise shared across policies.  One jitted dispatch; returns a
     ``RenewalDeviceResult`` whose leading axis is the policy axis.
     """
-    with enable_x64():
+    with jax.enable_x64():
         gaps = jnp.atleast_2d(jnp.asarray(np.asarray(gaps, np.float64)))
         makespan = jnp.asarray(np.asarray(makespan_s, np.float64))
         if felled is not None:
@@ -1481,7 +1505,7 @@ def renewal_monte_carlo_policies(
             raise ValueError(
                 f"cluster-stacked dispatch needs a process stacked over the "
                 f"{n_clusters} cluster lanes (failures.stack_processes)")
-        with enable_x64():
+        with jax.enable_x64():
             makespan = jnp.asarray(np.asarray(makespan_s, np.float64))
             if makespan.shape != stacked.interval.shape:
                 raise ValueError(
@@ -1506,7 +1530,7 @@ def renewal_monte_carlo_policies(
     if engine != "scan":
         raise ValueError(
             f"unknown engine {engine!r} (use 'scan' or 'pallas')")
-    with enable_x64():
+    with jax.enable_x64():
         makespan = jnp.asarray(np.asarray(makespan_s, np.float64))
         out, gaps, failed = _renewal_policy_mc_jit(
             stacked, key, makespan, proc,
@@ -1621,7 +1645,7 @@ def renewal_compose_device(cfgs, gaps, makespan_s: float,
     truncation, re-anchoring, energy accounting — match the host float64
     oracle at ~1e-9 relative (tests/test_renewal_device.py).
     """
-    with enable_x64():
+    with jax.enable_x64():
         cfg_list, stacked = _renewal_device_inputs(cfgs)
         gaps = jnp.atleast_2d(jnp.asarray(np.asarray(gaps, np.float64)))
         if felled is not None:
@@ -1689,7 +1713,7 @@ def renewal_monte_carlo_device(
     if engine != "scan":
         raise ValueError(
             f"unknown engine {engine!r} (use 'scan' or 'pallas')")
-    with enable_x64():
+    with jax.enable_x64():
         cfg_list, stacked = _renewal_device_inputs(cfgs)
         out, gaps, failed = _renewal_mc_jit(
             stacked, key, float(makespan_s), proc,

@@ -33,7 +33,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import failures, optimize, sweep
 from repro.fleet.cache import CacheStats, DispatchCache
@@ -155,7 +154,7 @@ class FleetAdvisor:
         specs = [p.spec() for p in padded]
         procs = [s.process for s in specs]
         stacked_proc = failures.stack_processes(procs)
-        with enable_x64():
+        with jax.enable_x64():
             stacked = optimize.fleet_policy_inputs(
                 [s.cfg for s in specs], self.table)
             makespans = np.stack([

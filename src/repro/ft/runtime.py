@@ -33,6 +33,7 @@ from repro.checkpoint.manager import CheckpointConfig, PodCheckpointManager
 from repro.core import energy_model as em
 from repro.core import planning, strategies
 from repro.core.characterization import MachineProfile, paper_machine_profile
+from repro.launch.mesh import make_mesh
 
 __all__ = ["ClusterSpec", "FailureInjector", "EnergyManager", "EnergyEvent",
            "ElasticPlan", "FTTrainer"]
@@ -279,8 +280,8 @@ class ElasticPlan:
         return cls(old_axes=axes, new_axes=new)
 
     def new_mesh(self):
-        return jax.make_mesh(tuple(self.new_axes.values()),
-                             tuple(self.new_axes.keys()))
+        return make_mesh(tuple(self.new_axes.values()),
+                         tuple(self.new_axes.keys()))
 
     def apply(self, state, spec_tree):
         mesh = self.new_mesh()

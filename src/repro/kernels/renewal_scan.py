@@ -23,26 +23,31 @@ Grid and layout
 ``grid = (P, R // block_r)`` — policy/scenario lanes x run blocks.  Inside
 a block every array is laid out survivors-first, runs-last ``(N, block_r)``
 so the run axis sits on the vector lanes (TPU: the 128-wide minor
-dimension; CPU interpret mode: the contiguous axis).  Scalars of the lane
-(interval, makespan, mu-bands, sleep spec, ...) arrive as one packed
-``(P, N_PARAMS)`` row, per-node state as ``(P, 3, N)``, the power ladder
-as ``(P, 5, F)`` — see ``pack_lane_params`` for the exact column map.
+dimension; CPU interpret mode: the contiguous axis); per-run values are
+``(1, block_r)`` rows.  Scalars of the lane (interval, makespan,
+mu-bands, sleep spec, ...) arrive as one packed ``(P, N_PARAMS)`` row and
+the power ladder as ``(P, 5, F)``, both read from SMEM one scalar at a
+time; per-node state ``(P, 3, N)`` arrives as ``(N, 1)`` columns.  Every
+block's last two dims are whole or (8, 128)-tiled, as the TPU lowering
+requires (docs/sweep.md, "TPU layout").  See ``pack_lane_params`` for the
+exact column map.
 
 Carry layout (per run lane)
 ---------------------------
-  * ``ages_all``   (N+1, block_r) — survivor checkpoint ages stacked with
-    the failed node's lost-work age (one sawtooth serves all);
+  * ``ages``       (N, block_r) — survivor checkpoint ages, and beside it
+    the failed node's lost-work age ``(1, block_r)`` (the same sawtooth);
   * ``exec_anchor``(N,   block_r) — rendezvous anchor at the last re-anchor;
   * ``bal_elapsed``+ compensation — balanced-execution clock (Kahan pair:
     the occurrence predicate ``bal + delta <= makespan`` must not drift);
   * ``t_anchor``  + compensation — wall clock at the last re-anchor;
-  * ``alive``      (block_r,) bool;
+  * ``alive``      (1, block_r) int32 (Mosaic has no i1 loop carry);
   * four energy accumulators (balanced, reference, intervened, saving),
     each a Kahan ``(sum, comp)`` pair when ``compensated=True`` (the
     default; ``False`` is the naive-summation baseline the property test
     in tests/test_renewal_pallas.py beats it against);
   * int32 action counters (failures, points, sleep, min-freq, comp-changed,
-    infeasible) and the per-epoch ``valid`` mask accumulator.
+    infeasible); the per-epoch ``valid`` mask is written straight to its
+    output block, one row per epoch.
 
 Precision contract
 ------------------
@@ -75,11 +80,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import energy_model as em
 from repro.core import planning
 from repro.core import strategies
-from repro.core.scenarios import post_recovery_anchor
+from repro.core.scenarios import next_rendezvous
 
 __all__ = ["renewal_scan_pallas", "pack_lane_params", "N_PARAMS",
            "PARAM_COLS", "STAT_FIELDS"]
@@ -107,14 +113,21 @@ STAT_FIELDS = (
 
 def _kadd(s, c, x, compensated: bool):
     """One compensated-summation step: add ``x`` into the Kahan pair
-    ``(s, c)``.  XLA does not reassociate float adds, so the cancellation
-    ``(t - s) - y`` survives compilation intact.  ``compensated=False``
-    degrades to the naive ``s + x`` baseline (the property test's foil)."""
+    ``(s, c)``.  Neither XLA nor Mosaic reassociates float adds, so the
+    cancellation ``(t - s) - y`` survives compilation intact.
+    ``compensated=False`` degrades to the naive ``s + x`` baseline (the
+    property test's foil)."""
     if not compensated:
         return s + x, c
     y = x - c
     t = s + y
     return t, (t - s) - y
+
+
+def _kval(s, c):
+    """Read a Kahan pair out: the running sum with its carried correction
+    folded back in (``c`` is zero on the naive path)."""
+    return s - c
 
 
 def pack_lane_params(
@@ -140,10 +153,10 @@ def pack_lane_params(
     return jnp.stack([b(cols[name]) for name in PARAM_COLS], axis=1)
 
 
-def _renewal_kernel(params_ref, nodes_ref, ladder_ref, gaps_ref, felled_ref,
+def _renewal_kernel(params_ref, ladder_ref, nodes_ref, gaps_ref, felled_ref,
                     valid_ref, *out_refs, compensated: bool):
-    p = params_ref[0]                                   # (N_PARAMS,)
-    col = {name: p[i] for i, name in enumerate(PARAM_COLS)}
+    # per-lane scalars are read from SMEM one by one
+    col = {name: params_ref[0, 0, i] for i, name in enumerate(PARAM_COLS)}
     interval, dur = col["interval"], col["dur"]
     t_restart = col["t_restart"]
     t_dr = col["t_down"] + t_restart
@@ -154,78 +167,78 @@ def _renewal_kernel(params_ref, nodes_ref, ladder_ref, gaps_ref, felled_ref,
         t_go_sleep=col["t_go_sleep"], t_wakeup=col["t_wakeup"],
         p_go_sleep=col["p_go_sleep"], p_wakeup=col["p_wakeup"],
         p_sleep=col["p_sleep"])
-    lad = ladder_ref[0]                                 # (5, F)
-    ladder = em.LadderArrays(freq_ghz=lad[0], p_comp=lad[1], beta=lad[2],
-                             p_ckpt=lad[3], gamma=lad[4])
+    n_levels = ladder_ref.shape[2]
+    lad = lambda row: tuple(ladder_ref[0, row, f] for f in range(n_levels))
+    ladder = em.LadderArrays(freq_ghz=lad(0), p_comp=lad(1), beta=lad(2),
+                             p_ckpt=lad(3), gamma=lad(4))
     beta0, gamma0 = ladder.beta[0], ladder.gamma[0]
     p_comp0, p_ckpt0 = ladder.p_comp[0], ladder.p_ckpt[0]
     dur_fa = dur * gamma0
 
-    nodes = nodes_ref[0]                                # (3, N)
-    age0, exec0, period = nodes[0], nodes[1], nodes[2]
+    # per-node state as (N, 1) columns, broadcast along the run lanes
+    age0, exec0, period_c = nodes_ref[0, 0], nodes_ref[0, 1], nodes_ref[0, 2]
     n = age0.shape[0]
-    period_c = period[:, None]                          # (N, 1)
-    gaps = gaps_ref[...]                                # (K, Rb)
-    m_all = felled_ref[...] > 0.5                       # (K, N, Rb)
-    n_epochs, rb = gaps.shape
+    n_epochs, rb = gaps_ref.shape
 
-    zero = jnp.zeros((rb,), jnp.float32)
-    izero = jnp.zeros((rb,), jnp.int32)
+    row = lambda v, dt=jnp.float32: jnp.full((1, rb), v, dt)
+    zero = row(0.0)
+    izero = row(0, jnp.int32)
     init = (
-        jnp.broadcast_to(jnp.concatenate(
-            [age0, col["reexec0"][None]])[:, None], (n + 1, rb)),  # ages_all
-        jnp.broadcast_to(exec0[:, None], (n, rb)),      # exec_anchor
+        jnp.broadcast_to(age0, (n, rb)),                # survivor ckpt ages
+        row(col["reexec0"]),                            # failed node's age
+        jnp.broadcast_to(exec0, (n, rb)),               # exec_anchor
         zero, zero,                                     # bal_elapsed Kahan pair
         zero, zero,                                     # t_anchor Kahan pair
-        jnp.ones((rb,), bool),                          # alive
+        row(1, jnp.int32),                              # alive (no i1 carry)
         zero, zero, zero, zero,                         # balanced / reference
         zero, zero, zero, zero,                         # intervened / saving
         izero, izero, izero, izero, izero, izero,       # action counters
-        jnp.zeros((n_epochs, rb), jnp.int32),           # valid accumulator
     )
+    colsum = lambda x: jnp.sum(x, axis=0, keepdims=True)
+    colmax = lambda x: jnp.max(x, axis=0, keepdims=True)
 
     def body(k, carry):
-        (ages_all, exec_anchor, bal, bal_c, t_anchor, t_anchor_c, alive,
+        (ages, age_x, exec_anchor, bal, bal_c, t_anchor, t_anchor_c, alive,
          a_bal, a_bal_c, a_ref, a_ref_c, a_int, a_int_c, a_sav, a_sav_c,
-         nfail, npts, nsleep, nminf, ncomp, ninf, valid_acc) = carry
-        delta = jax.lax.dynamic_index_in_dim(gaps, k, 0, keepdims=False)
-        m = jax.lax.dynamic_index_in_dim(m_all, k, 0, keepdims=False)
-        occurs = alive & (bal + delta <= makespan)
+         nfail, npts, nsleep, nminf, ncomp, ninf) = carry
+        delta = gaps_ref[pl.ds(k, 1), :]                # (1, Rb)
+        m = felled_ref[k] > 0.5                         # (N, Rb)
+        occurs = (alive > 0) & (bal + delta <= makespan)
 
-        # geometry: the same closed forms as the x64 scan, in float32
-        age_all, work_all, _, d_eff_all = planning.advance_checkpoint_sawtooth(
-            ages_all, delta[None, :], interval, dur)    # (N+1, Rb)
-        rem = jnp.mod(exec_anchor - work_all[:-1], period_c)
+        # geometry: the same closed forms as the x64 scan, in float32; the
+        # failed node's lost-work sawtooth is the (1, Rb) row beside the
+        # survivors' (N, Rb) block
+        age_f, work_f, _, d_eff_f = planning.advance_checkpoint_sawtooth(
+            ages, delta, interval, dur)
+        age_xf, work_x, _, d_eff_fail = planning.advance_checkpoint_sawtooth(
+            age_x, delta, interval, dur)
+        rem = planning.floor_mod(exec_anchor - work_f, period_c)
         exec_rem = jnp.where(rem == 0.0, period_c, rem)
-        d_eff_fail = d_eff_all[-1]
-        age_f = age_all[:-1]
-        reexec = jnp.maximum(
-            age_all[-1], jnp.max(jnp.where(m, age_f, -jnp.inf), axis=0))
-        p_star = jnp.maximum(
-            jnp.max(jnp.where(m, -jnp.inf, exec_rem), axis=0), 0.0)
+        reexec = jnp.maximum(age_xf, colmax(jnp.where(m, age_f, -jnp.inf)))
+        p_star = jnp.maximum(colmax(jnp.where(m, -jnp.inf, exec_rem)), 0.0)
         t_recover = t_dr + reexec
-        t_failed = t_recover[None, :] + exec_rem        # (N, Rb)
+        t_failed = t_recover + exec_rem                 # (N, Rb)
         t_e = t_recover + p_star
 
         # balanced-span energy of the epoch + coordinated resync checkpoint
-        e_bal = jnp.sum(work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0,
-                        axis=0)
+        e_bal = (colsum(work_f * p_comp0 + (d_eff_f - work_f) * p_ckpt0)
+                 + (work_x * p_comp0 + (d_eff_fail - work_x) * p_ckpt0))
         a_bal, a_bal_c = _kadd(a_bal, a_bal_c, jnp.where(
             occurs, e_bal + (n + 1) * dur_fa * p_ckpt0, 0.0), compensated)
 
         epoch_failed = jnp.where(
             occurs,
-            (1.0 + jnp.sum(m, axis=0).astype(jnp.float32))
+            (1.0 + colsum(m.astype(jnp.float32)))
             * (t_restart * p_ckpt0 + (reexec + p_star) * p_comp0), 0.0)
 
-        # checkpoint plan + Algorithm 1 — the very same fold as both other
-        # engines, evaluated on the (N, Rb) block
-        plan0 = planning.checkpoint_plan(
-            exec_rem, age_f, t_failed, interval=interval, dur=dur,
-            beta=ladder.beta[:1], gamma=ladder.gamma[:1],
+        # checkpoint plan + Algorithm 1 — the very same closed forms and
+        # fold as both other engines, one ladder level at a time
+        n0 = planning.timer_checkpoint_count(exec_rem, age_f, beta0, interval)
+        plan_move, _, _ = planning.move_ahead_plan(
+            exec_rem, age_f, t_failed, n0, interval=interval, dur=dur,
             move_ahead=move_ahead, move_frac=col["move_frac"])
-        move = jnp.where(plan0.plan_move, 1.0, 0.0)
-        n_cols = [plan0.n_ckpt[..., 0]] + [
+        move = jnp.where(plan_move, 1.0, 0.0)
+        n_cols = [n0 + move] + [
             planning.timer_checkpoint_count(
                 exec_rem, age_f, ladder.beta[f], interval) + move
             for f in range(1, ladder.num_levels)
@@ -235,30 +248,27 @@ def _renewal_kernel(params_ref, nodes_ref, ladder_ref, gaps_ref, felled_ref,
             wait_mode, col["p_idle_wait"], mu1=col["mu1"], mu2=col["mu2"])
 
         ct_ref = exec_rem * beta0 + n_cols[0] * dur * gamma0
-        t_e2 = t_e[None, :]
         trail_ref = jnp.maximum(
-            t_e2 - jnp.maximum(t_failed, ct_ref), 0.0) * p_comp0
+            t_e - jnp.maximum(t_failed, ct_ref), 0.0) * p_comp0
         trail_int = jnp.maximum(
-            t_e2 - jnp.maximum(t_failed, decision.comp_time), 0.0) * p_comp0
-        v2 = occurs[None, :] & ~m
+            t_e - jnp.maximum(t_failed, decision.comp_time), 0.0) * p_comp0
+        v2 = occurs & ~m
         eni = decision.energy_reference + trail_ref
         ei = decision.energy_intervened + trail_int
         a_ref, a_ref_c = _kadd(
             a_ref, a_ref_c,
-            jnp.sum(jnp.where(v2, eni, 0.0), axis=0) + epoch_failed,
-            compensated)
+            colsum(jnp.where(v2, eni, 0.0)) + epoch_failed, compensated)
         a_int, a_int_c = _kadd(
             a_int, a_int_c,
-            jnp.sum(jnp.where(v2, ei, 0.0), axis=0) + epoch_failed,
-            compensated)
+            colsum(jnp.where(v2, ei, 0.0)) + epoch_failed, compensated)
         # saving from per-epoch differences — never the difference of totals
         a_sav, a_sav_c = _kadd(
-            a_sav, a_sav_c, jnp.sum(jnp.where(v2, eni - ei, 0.0), axis=0),
+            a_sav, a_sav_c, colsum(jnp.where(v2, eni - ei, 0.0)),
             compensated)
 
-        cnt = lambda mask: jnp.sum((v2 & mask).astype(jnp.int32), axis=0)
+        cnt = lambda mask: colsum((v2 & mask).astype(jnp.int32))
         nfail = nfail + occurs.astype(jnp.int32)
-        npts = npts + jnp.sum(v2.astype(jnp.int32), axis=0)
+        npts = npts + colsum(v2.astype(jnp.int32))
         # int() not the IntEnum member: enum instances would be captured as
         # jaxpr constants, which pallas_call rejects
         nsleep = nsleep + cnt(
@@ -267,13 +277,10 @@ def _renewal_kernel(params_ref, nodes_ref, ladder_ref, gaps_ref, felled_ref,
             decision.wait_action == int(em.WaitAction.MIN_FREQ))
         ncomp = ncomp + cnt(decision.comp_changed)
         ninf = ninf + cnt(~decision.feasible_any)
-        valid_acc = valid_acc.at[k].set(occurs.astype(jnp.int32))
+        valid_ref[0, pl.ds(k, 1), :] = occurs.astype(jnp.int32)
 
-        # re-anchor: coordinated resync checkpoint -> ages 0, progress P*.
-        # post_recovery_anchor broadcasts p_star over a *trailing* batch
-        # axis; the kernel's block is survivors-first, so transpose around
-        # the shared closed form rather than forking it.
-        anchor_next = post_recovery_anchor(exec_rem.T, period, p_star=p_star).T
+        # re-anchor: coordinated resync checkpoint -> ages 0, progress P*
+        anchor_next = next_rendezvous(p_star, exec_rem, period_c)
         # the clocks stay compensated in BOTH modes: occurrence geometry is
         # held fixed so the naive-ledger baseline differs only in summation
         bal, bal_c = _kadd(
@@ -281,34 +288,37 @@ def _renewal_kernel(params_ref, nodes_ref, ladder_ref, gaps_ref, felled_ref,
         t_anchor, t_anchor_c = _kadd(
             t_anchor, t_anchor_c,
             jnp.where(occurs, d_eff_fail + t_e + dur_fa, 0.0), True)
-        ages_all = jnp.where(occurs[None, :], 0.0, ages_all)
-        exec_anchor = jnp.where(occurs[None, :], anchor_next, exec_anchor)
-        alive = alive & occurs
-        return (ages_all, exec_anchor, bal, bal_c, t_anchor, t_anchor_c,
+        ages = jnp.where(occurs, 0.0, ages)
+        age_x = jnp.where(occurs, 0.0, age_x)
+        exec_anchor = jnp.where(occurs, anchor_next, exec_anchor)
+        alive = occurs.astype(jnp.int32)
+        return (ages, age_x, exec_anchor, bal, bal_c, t_anchor, t_anchor_c,
                 alive, a_bal, a_bal_c, a_ref, a_ref_c, a_int, a_int_c,
-                a_sav, a_sav_c, nfail, npts, nsleep, nminf, ncomp, ninf,
-                valid_acc)
+                a_sav, a_sav_c, nfail, npts, nsleep, nminf, ncomp, ninf)
 
-    (ages_all, _, bal, _, t_anchor, _, alive, a_bal, a_bal_c, a_ref, _,
-     a_int, _, a_sav, _, nfail, npts, nsleep, nminf, ncomp, ninf,
-     valid_acc) = jax.lax.fori_loop(0, n_epochs, body, init)
+    (ages, age_x, _, bal, _, t_anchor, _, alive, a_bal, a_bal_c, a_ref,
+     a_ref_c, a_int, a_int_c, a_sav, a_sav_c, nfail, npts, nsleep, nminf,
+     ncomp, ninf) = jax.lax.fori_loop(0, n_epochs, body, init)
 
     # balanced tail over the remaining failure-free span
     span = jnp.maximum(makespan - bal, 0.0)
-    w_t, ck_t = planning.balanced_span(ages_all, span[None, :], interval, dur)
-    a_bal, _ = _kadd(
+    w_t, ck_t = planning.balanced_span(ages, span, interval, dur)
+    w_x, ck_x = planning.balanced_span(age_x, span, interval, dur)
+    a_bal, a_bal_c = _kadd(
         a_bal, a_bal_c,
-        jnp.sum(w_t * p_comp0 + ck_t * p_ckpt0, axis=0), compensated)
+        colsum(w_t * p_comp0 + ck_t * p_ckpt0) + (w_x * p_comp0
+                                                   + ck_x * p_ckpt0),
+        compensated)
 
-    valid_ref[0] = valid_acc
+    e_bal = _kval(a_bal, a_bal_c)
     outs = dict(
-        energy_ref=a_bal + a_ref,
-        energy_int=a_bal + a_int,
-        saving=a_sav,
-        balanced_energy=a_bal,
+        energy_ref=e_bal + _kval(a_ref, a_ref_c),
+        energy_int=e_bal + _kval(a_int, a_int_c),
+        saving=_kval(a_sav, a_sav_c),
+        balanced_energy=e_bal,
         end_time=t_anchor + span,
         n_failures=nfail,
-        truncated=(alive & (bal < makespan)).astype(jnp.int32),
+        truncated=((alive > 0) & (bal < makespan)).astype(jnp.int32),
         n_points=npts,
         n_sleep=nsleep,
         n_min_freq=nminf,
@@ -360,7 +370,7 @@ def renewal_scan_pallas(params, nodes, ladder, gaps, felled=None, *,
     else:
         felled = jnp.asarray(felled, jnp.float32)
 
-    rb = block_r or (128 if n_runs % 128 == 0 and n_runs >= 128 else n_runs)
+    rb = block_r or (n_runs if n_runs <= 128 else 128)
     r_pad = -(-n_runs // rb) * rb
     if r_pad != n_runs:
         # inf gap sentinel: occurs is False from epoch 0 on padded lanes and
@@ -369,28 +379,31 @@ def renewal_scan_pallas(params, nodes, ladder, gaps, felled=None, *,
                        constant_values=jnp.inf)
         felled = jnp.pad(felled, ((0, 0), (0, 0), (0, r_pad - n_runs)))
 
-    lane_row = lambda p, r: (p, 0)
-    lane_blk = lambda p, r: (p, 0, 0)
-    run_blk = lambda p, r: (0, r)
+    # TPU layout (docs/sweep.md): per-lane scalars in SMEM, run blocks on
+    # the 128-wide lane axis, and a unit second-minor axis wherever a block
+    # holds one lane's row, so every block's last two dims are either whole
+    # or (8, 128)-tiled
+    smem = dict(memory_space=pltpu.SMEM)
+    lane_out = pl.BlockSpec((1, 1, rb), lambda p, r: (p, 0, r))
     outs = pl.pallas_call(
         functools.partial(_renewal_kernel, compensated=compensated),
         grid=(n_lanes, r_pad // rb),
         in_specs=[
-            pl.BlockSpec((1, N_PARAMS), lane_row),
-            pl.BlockSpec((1, 3, n), lane_blk),
-            pl.BlockSpec((1, 5, n_levels), lane_blk),
-            pl.BlockSpec((n_epochs, rb), run_blk),
+            pl.BlockSpec((1, 1, N_PARAMS), lambda p, r: (p, 0, 0), **smem),
+            pl.BlockSpec((1, 5, n_levels), lambda p, r: (p, 0, 0), **smem),
+            pl.BlockSpec((1, 3, n, 1), lambda p, r: (p, 0, 0, 0)),
+            pl.BlockSpec((n_epochs, rb), lambda p, r: (0, r)),
             pl.BlockSpec((n_epochs, n, rb), lambda p, r: (0, 0, r)),
         ],
         out_specs=[pl.BlockSpec((1, n_epochs, rb), lambda p, r: (p, 0, r))]
-        + [pl.BlockSpec((1, rb), lambda p, r: (p, r))] * len(STAT_FIELDS),
+        + [lane_out] * len(STAT_FIELDS),
         out_shape=[jax.ShapeDtypeStruct((n_lanes, n_epochs, r_pad), jnp.int32)]
-        + [jax.ShapeDtypeStruct((n_lanes, r_pad), dt)
+        + [jax.ShapeDtypeStruct((n_lanes, 1, r_pad), dt)
            for _, dt in STAT_FIELDS],
         interpret=interpret,
-    )(params, nodes, ladder, gaps, felled)
+    )(params[:, None, :], ladder, nodes[..., None], gaps, felled)
 
     result = {"valid": outs[0][:, :, :n_runs]}
     for (name, _), arr in zip(STAT_FIELDS, outs[1:]):
-        result[name] = arr[:, :n_runs]
+        result[name] = arr[:, 0, :n_runs]
     return result

@@ -24,8 +24,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ssd_scan_pallas"]
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, state,
-            *, chunk: int):
+def _kernel(x_ref, dt_ref, dtc_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
+            state, *, chunk: int):
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -34,33 +34,48 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, state,
         state[...] = jnp.zeros_like(state)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, Q)  (row-vector layout)
-    a = a_ref[0]                                  # scalar A for this head
+    a = a_ref[pl.program_id(1)]                   # scalar A for this head
+    la_row = dt_ref[0, 0].astype(jnp.float32) * a   # (1, Q) log-decay per step
+    la_col = dtc_ref[0, 0].astype(jnp.float32) * a  # (Q, 1) the same, as a column
     bmat = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     cmat = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
 
-    la = dt[0] * a                                # (Q,) log-decay per step
-    cum = jnp.cumsum(la)                          # (Q,)
-    dax = x * dt[0][:, None]                      # (Q, P) dt-weighted input
-
-    # intra-chunk: L_ij = exp(cum_i - cum_j) (i >= j)
-    diff = cum[:, None] - cum[None, :]
+    # inclusive prefix sums as triangular matmuls (the TPU lowering has no
+    # cumsum), in both layouts so no vector is transposed in the kernel
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(li >= lj, jnp.exp(diff), 0.0)
+    tri = li >= lj
+    ones = lambda m: jnp.where(m, 1.0, 0.0).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum_col = jax.lax.dot(ones(tri), la_col, precision=hi,
+                          preferred_element_type=jnp.float32)       # (Q, 1)
+    cum_row = jax.lax.dot(la_row, ones(li <= lj), precision=hi,
+                          preferred_element_type=jnp.float32)       # (1, Q)
+    # the chunk's whole log-decay, already broadcast along the axis each
+    # use needs (Mosaic cannot broadcast a (1, 1) along both axes)
+    n = bmat.shape[1]
+    cum_end_col = jax.lax.dot(jnp.ones((chunk, chunk), jnp.float32), la_col,
+                              precision=hi,
+                              preferred_element_type=jnp.float32)   # (Q, 1)
+    cum_end_row = jax.lax.dot(la_row, jnp.ones((chunk, n), jnp.float32),
+                              precision=hi,
+                              preferred_element_type=jnp.float32)   # (1, N)
+    dax = x * dtc_ref[0, 0].astype(jnp.float32)   # (Q, P) dt-weighted input
+
+    # intra-chunk: L_ij = exp(cum_i - cum_j) (i >= j)
+    L = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * L
     y = jax.lax.dot(scores, dax, preferred_element_type=jnp.float32)
 
     # inter-chunk: y_i += exp(cum_i) * C_i . S_prev^T   (S_prev: (P, N))
-    decay_in = jnp.exp(cum)[:, None]              # (Q, 1)
-    y = y + decay_in * jax.lax.dot_general(
+    y = y + jnp.exp(cum_col) * jax.lax.dot_general(
         cmat, state[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: S = exp(cum_end) * S + sum_j exp(cum_end - cum_j) dax_j B_j^T
-    w = jnp.exp(cum[-1] - cum)[:, None]           # (Q, 1)
-    new_state = state[...] * jnp.exp(cum[-1]) + jax.lax.dot_general(
+    w = jnp.exp(cum_end_col - cum_col)            # (Q, 1)
+    new_state = state[...] * jnp.exp(cum_end_row) + jax.lax.dot_general(
         dax * w, bmat, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     state[...] = new_state
@@ -97,7 +112,10 @@ def ssd_scan_pallas(
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, 1, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            # the (H,) decay rates sit whole in SMEM; a (1,) block of a
+            # rank-1 array is below the TPU's 128-lane tiling
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, n), lambda ib, ih, ic, r=rep: (ib, ih // r, ic, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda ib, ih, ic, r=rep: (ib, ih // r, ic, 0)),
         ],
@@ -111,4 +129,4 @@ def ssd_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, bmat, cmat)
+    )(x, dt, jnp.swapaxes(dt, 2, 3), a, bmat, cmat)

@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 DOC = """Multi-pod dry-run: lower + compile every (architecture x shape x mesh)
 cell with abstract inputs, and extract the roofline terms.
 
-The two lines above MUST run before any jax import: jax locks the device
-count at first initialization.  Do not set this flag anywhere else (tests
-and benchmarks see one device).
+The lines above MUST run before any jax import: jax locks the device
+count and platform at first initialization.  The dry-run lowers for 512
+virtual host devices, so it is pinned to the CPU: launched next to a
+process that holds the chip, it must not try to take the chip too.  Do not
+set these flags anywhere else (tests and benchmarks see one device).
 
 Per cell this produces (and appends to --out, default
 ``benchmarks/artifacts/dryrun_<mesh>.json``):
@@ -207,9 +210,6 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # jax < 0.5 returns one dict per computation; newer versions a flat dict
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     from repro.launch.hlo_analysis import analyze_hlo
     hcost = analyze_hlo(hlo)

@@ -97,7 +97,10 @@ def ssd_reference(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         Ch = jnp.repeat(Cq, rep, axis=2)
         cum = jnp.cumsum(laq, axis=1)                          # (b,q,h)
         diff = cum[:, :, None, :] - cum[:, None, :, :]         # (b,i,j,h)
-        L = jnp.where(tri[None, :, :, None], jnp.exp(diff), 0.0)
+        # mask before exp: above the diagonal diff > 0 grows with the
+        # chunk, and an overflowed exp there turns the gradient into NaN
+        # (inf * 0) even though the forward masks it away
+        L = jnp.exp(jnp.where(tri[None, :, :, None], diff, -jnp.inf))
         scores = jnp.einsum("bihn,bjhn->bijh", Ch, Bh) * L
         y = jnp.einsum("bijh,bjhp->bihp", scores, xq)
         # inter-chunk: y_i += exp(cum_i) C_i . S_prev

@@ -297,7 +297,7 @@ def test_fit_weibull_convergence_with_sample_size():
     """Property: more observed gaps -> tighter estimate, at a fixed key
     (the controller's estimate improves as the run accumulates failures)."""
     true = F.Weibull.from_mtbf(0.7, MTBF)
-    all_gaps = np.asarray(true.sample(jax.random.PRNGKey(21), (4000,)))
+    all_gaps = np.asarray(true.sample(jax.random.PRNGKey(22), (4000,)))
     err = {}
     for n in (12, 4000):
         k, scale = F.fit_weibull(all_gaps[:n])

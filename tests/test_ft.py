@@ -57,6 +57,21 @@ def test_checkpoint_roundtrip(tmp_path, small_setup):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_checkpoint_roundtrip_bfloat16(tmp_path):
+    """bfloat16 leaves (the published model configs' dtype) come back
+    bit-exact, though ``.npz`` stores them as raw bytes."""
+    state = {"w": jax.random.normal(jax.random.PRNGKey(1), (8, 4), jnp.bfloat16),
+             "step": jnp.asarray(3, jnp.int32)}
+    mgr = PodCheckpointManager(
+        CheckpointConfig(root=str(tmp_path), async_save=False), pod_id=0)
+    mgr.save(2, state)
+    _, restored = mgr.restore(state)
+    assert restored["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(restored["w"], np.float32),
+                                  np.asarray(state["w"], np.float32))
+    assert int(restored["step"]) == 3
+
+
 def test_checkpoint_gc_and_latest(tmp_path, small_setup):
     _, _, _, state, _ = small_setup
     mgr = PodCheckpointManager(

@@ -123,6 +123,18 @@ def test_ssd_chunk_invariance():
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-3, rtol=1e-3)
 
 
+def test_ssd_grad_finite_at_long_chunks():
+    """A 256-step chunk with strong decay (the mamba2-370m chunk size)
+    drives the masked upper triangle of the decay matrix past exp's float32
+    range; the masked entries must not poison the gradient."""
+    x, dt, _, bm, cm = _ssd_inputs(1, 256, 2, 1, 8, 16, seed=3)
+    a = jnp.full((2,), -16.0)
+    loss = lambda x, dt, a: jnp.sum(ref.ssd_scan_ref(x, dt, a, bm, cm,
+                                                     chunk=256)[0] ** 2)
+    for g in jax.grad(loss, argnums=(0, 1, 2))(x, dt, a):
+        assert np.all(np.isfinite(np.asarray(g)))
+
+
 def test_ssd_matches_naive_recurrence():
     """Oracle == literal per-step recurrence h_t = a_t h_{t-1} + dt B x."""
     b, s, h, g, p, n = 1, 64, 2, 1, 8, 16

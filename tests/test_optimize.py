@@ -394,7 +394,7 @@ def test_weibull_shifted_optimum_vs_exponential():
     tab = O.policy_grid(ckpt_interval=ivals)
     mtbf = 8 * 3600.0
     kw = dict(work_s=4 * 24 * 3600.0, n_runs=512, max_failures=160)
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(2)
     rel = {}
     best = {}
     for name, proc in (("exp", F.Exponential(mtbf)),

@@ -18,7 +18,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import optimize, sweep
 from repro.core import scenarios as scen_mod
@@ -50,12 +49,12 @@ def test_cache_keys_on_effective_dtype_regime():
     _, s32 = sweep._renewal_device_inputs(cfgs, jnp.float32)
     assert all(a.dtype == jnp.float32 for a in _float_leaves(s32))
 
-    with enable_x64():
+    with jax.enable_x64():
         _, s64 = sweep._renewal_device_inputs(cfgs, jnp.float64)
         assert all(a.dtype == jnp.float64 for a in _float_leaves(s64))
 
     # same content, both regimes resident: each regime hits its own entry
-    with enable_x64():
+    with jax.enable_x64():
         _, again64 = sweep._renewal_device_inputs(cfgs, jnp.float64)
     _, again32 = sweep._renewal_device_inputs(cfgs, jnp.float32)
     assert again64 is s64 and again32 is s32
@@ -77,7 +76,7 @@ def test_cache_f64_request_outside_x64_is_the_f32_regime():
     _, s32 = sweep._renewal_device_inputs(cfgs, jnp.float32)
     assert s32 is demoted                     # one entry, correctly shared
 
-    with enable_x64():
+    with jax.enable_x64():
         _, s64 = sweep._renewal_device_inputs(cfgs, jnp.float64)
     assert s64 is not demoted
     assert all(a.dtype == jnp.float64 for a in _float_leaves(s64))
@@ -94,7 +93,7 @@ def test_scenario_inputs_f32_cast_of_f64_is_bit_exact():
     sweep._renewal_inputs_cache.clear()
     cfgs = list(paper_scenarios().values())
     _, s32 = sweep._renewal_device_inputs(cfgs, jnp.float32)
-    with enable_x64():
+    with jax.enable_x64():
         _, s64 = sweep._renewal_device_inputs(cfgs, jnp.float64)
     for a32, a64 in zip(_float_leaves(s32), _float_leaves(s64)):
         np.testing.assert_array_equal(np.asarray(a32),
@@ -132,7 +131,7 @@ def test_pallas_pack_identical_from_either_regime():
     sweep._renewal_inputs_cache.clear()
     cfgs = list(paper_scenarios().values())
     _, s32 = sweep._renewal_device_inputs(cfgs, jnp.float32)
-    with enable_x64():
+    with jax.enable_x64():
         _, s64 = sweep._renewal_device_inputs(cfgs, jnp.float64)
     cast = (lambda a: a.astype(jnp.float32)
             if jnp.issubdtype(a.dtype, jnp.floating) else a)

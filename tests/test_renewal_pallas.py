@@ -180,40 +180,48 @@ def test_compensated_ledger_beats_naive_float32():
     identical, so the comparison isolates the summation.  At 256 epochs
     the compensated totals sit within ~1 output ulp of the oracle while
     naive drifts several ulps; the difference-accumulated ``saving``
-    separates by an order of magnitude."""
+    separates by an order of magnitude.  On the balanced and intervened
+    ledgers both modes carry the same float32 geometry rounding, which is
+    itself about one output ulp, so their aggregate claim is made over
+    128 runs: over a handful of runs it is a coin flip."""
     cfgs = [paper_scenarios()["scenario2_long_reexec"]]
     key = jax.random.PRNGKey(7)
-    n_runs, max_failures, makespan = 16, 256, 3.2e6
+    max_failures, makespan = 256, 3.2e6
     proc = F.as_process(None, 4000.0)
     _, stacked = sweep._renewal_device_inputs(cfgs, jnp.float32)
-    run = lambda comp: sweep._renewal_pallas_mc_jit(
-        stacked, key, jnp.float32(makespan), proc, n_runs=n_runs,
-        max_failures=max_failures, compensated=comp)
-    comp, naive = run(True), run(False)
-    oracle = sweep.renewal_monte_carlo_device(
-        cfgs, key, stats=True, n_runs=n_runs, makespan_s=makespan,
-        mtbf_s=4000.0, max_failures=max_failures)
-    assert float(np.mean(np.asarray(oracle.n_failures))) >= 64
-    # same geometry: identical epochs, decisions, and counters
-    for field in ("n_failures", "n_points", "n_sleep", "n_min_freq"):
-        np.testing.assert_array_equal(np.asarray(comp[field]),
-                                      np.asarray(naive[field]), err_msg=field)
-    ref_mag = np.asarray(oracle.energy_ref, np.float64)[0]
 
-    def errors(field):
-        ref = np.asarray(getattr(oracle, field), np.float64)[0]
-        e_c = np.abs(np.asarray(comp[field], np.float64)[0] - ref)
-        e_n = np.abs(np.asarray(naive[field], np.float64)[0] - ref)
-        return ref, e_c, e_n
+    def errors(n_runs, fields):
+        run = lambda comp: sweep._renewal_pallas_mc_jit(
+            stacked, key, jnp.float32(makespan), proc, n_runs=n_runs,
+            max_failures=max_failures, compensated=comp)
+        comp, naive = run(True), run(False)
+        oracle = sweep.renewal_monte_carlo_device(
+            cfgs, key, stats=True, n_runs=n_runs, makespan_s=makespan,
+            mtbf_s=4000.0, max_failures=max_failures)
+        assert float(np.mean(np.asarray(oracle.n_failures))) >= 64
+        # same geometry: identical epochs, decisions, and counters
+        for field in ("n_failures", "n_points", "n_sleep", "n_min_freq"):
+            np.testing.assert_array_equal(np.asarray(comp[field]),
+                                          np.asarray(naive[field]),
+                                          err_msg=field)
+        ref_mag = np.asarray(oracle.energy_ref, np.float64)[0]
+        out = {}
+        for field in fields:
+            ref = np.asarray(getattr(oracle, field), np.float64)[0]
+            out[field] = (
+                ref, ref_mag,
+                np.abs(np.asarray(comp[field], np.float64)[0] - ref),
+                np.abs(np.asarray(naive[field], np.float64)[0] - ref))
+        return out
 
     # energy_ref and saving: compensated wins on EVERY run, and in sum
-    for field in ("energy_ref", "saving"):
-        ref, e_c, e_n = errors(field)
+    for field, (ref, ref_mag, e_c, e_n) in errors(
+            16, ("energy_ref", "saving")).items():
         assert np.all(e_c <= e_n + 1e-9 * ref_mag), field
         assert e_c.sum() < e_n.sum(), field
     # the remaining ledgers: compensated at least as accurate in aggregate
-    for field in ("energy_int", "balanced_energy"):
-        ref, e_c, e_n = errors(field)
+    for field, (ref, _, e_c, e_n) in errors(
+            128, ("energy_int", "balanced_energy")).items():
         assert e_c.sum() <= e_n.sum(), field
         np.testing.assert_array_less(e_c / np.abs(ref), 1e-4)
 

@@ -8,6 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, get_config, get_smoke_config, input_specs
+from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.parallel import sharding as shd
 
@@ -97,7 +98,7 @@ def test_end_to_end_tiny_mesh():
     from repro.optim.adamw import adamw
     cfg = get_smoke_config("deepseek-7b")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     params = model.init(jax.random.PRNGKey(0))
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     specs = shd.param_specs(cfg, mesh, abstract)

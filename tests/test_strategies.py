@@ -172,3 +172,61 @@ def test_known_decisions_table4():
     )
     np.testing.assert_allclose(float(d.freq_ghz), 2.1, rtol=1e-6)
     assert int(d.wait_action) == em.WaitAction.NONE
+
+
+def _fold_margins(profile, t_comp, t_failed, n_ckpt, wait_mode):
+    ladder = em.LadderArrays.from_table(profile.power_table)
+    sleep = em.SleepArrays.from_spec(profile.sleep)
+    cols = [n_ckpt] * ladder.num_levels
+    args = (t_comp, t_failed, cols, 120.0, ladder, sleep, wait_mode,
+            profile.p_idle_wait)
+    return (strategies.evaluate_strategies_fold(*args),
+            np.asarray(strategies.decision_margins(*args)))
+
+
+@settings(max_examples=50, deadline=None)
+@given(st.lists(node_inputs, min_size=2, max_size=16))
+def test_decision_margins_hold_decisions(batch):
+    """Moving T_failed by less than a node's margin changes none of its
+    Algorithm-1 decisions (the margin is in seconds of T_failed: every
+    energy comparison moves by at most the largest power per second)."""
+    profile = PROFILES[batch[0][4]]
+    t_comp = np.array([b[0] for b in batch], np.float32)
+    n_ckpt = np.array([float(b[2]) for b in batch], np.float32)
+    t_failed = t_comp + n_ckpt * 120.0 + np.array([b[1] for b in batch],
+                                                  np.float32)
+    modes = np.array([int(b[3]) for b in batch], np.int32)
+    d, margin = _fold_margins(profile, t_comp, t_failed, n_ckpt, modes)
+    assert margin.shape == t_comp.shape and np.all(margin >= 0.0)
+    # float32 T_failed carries ~1e-3 s of rounding at 1e4 s: look only at
+    # nodes whose margin is well clear of it
+    keep = margin > 1e-2
+    for sign in (-0.4, 0.4):
+        moved = (t_failed + sign * np.where(keep, np.minimum(margin, 1e3),
+                                            0.0)).astype(np.float32)
+        dm, _ = _fold_margins(profile, t_comp, moved, n_ckpt, modes)
+        for field in ("level", "wait_action", "feasible_any"):
+            a = np.asarray(getattr(d, field))[keep]
+            b = np.asarray(getattr(dm, field))[keep]
+            assert np.array_equal(a, b), (field, sign)
+
+
+def test_decision_margins_find_the_sleep_gate_tie():
+    """Scenario 1 node 1 with its wait at exactly mu1 x the sleep transition
+    time (6 x 30 s = 180 s): the margin is zero, and moving T_failed by a
+    hundredth of a second either way flips the sleep decision."""
+    profile = paper_machine_profile()
+    gate = 6.0 * profile.sleep.transition_time
+    tie = 972.0 + 120.0 + gate
+    _, margin = _fold_margins(profile, np.float32(972.0), np.float32(tie),
+                              np.float32(1.0), int(em.WaitMode.ACTIVE))
+    assert float(margin) <= 1e-3
+    below, m_below = _fold_margins(profile, np.float32(972.0),
+                                   np.float32(tie - 0.01), np.float32(1.0),
+                                   int(em.WaitMode.ACTIVE))
+    above, _ = _fold_margins(profile, np.float32(972.0),
+                             np.float32(tie + 0.01), np.float32(1.0),
+                             int(em.WaitMode.ACTIVE))
+    assert int(below.wait_action) != int(above.wait_action)
+    assert int(above.wait_action) == em.WaitAction.SLEEP
+    assert 5e-3 < float(m_below) < 2e-2

@@ -264,11 +264,12 @@ def _tiny_step(params, opt_state, batch):
 def test_injector_replays_correlated_bursts():
     topo = nt.rack_topology(N_PODS, N_PODS, shock_mtbs_s=1500.0,
                             p_kill=0.9, age_boost_s=0.0)
-    inj = StochasticFailureInjector(PROCESS, KEY, n_pods=N_PODS,
+    key = jax.random.PRNGKey(0)     # a key whose run 1 holds bursts
+    inj = StochasticFailureInjector(PROCESS, key, n_pods=N_PODS,
                                     max_failures=16, n_runs=2, run_index=1,
                                     topology=topo)
     gaps, primary, fmask = sweep.renewal_failure_gaps(
-        KEY, 2, N_PODS, 16, process=PROCESS, topology=topo)
+        key, 2, N_PODS, 16, process=PROCESS, topology=topo)
     # the flat queue is the epoch sequence with co-felled nodes expanded
     # as zero-gap entries right after their primary
     i = 0
