@@ -515,24 +515,25 @@ def sample_renewal_gaps(
     (failure exposure accrues over balanced execution, which is also the
     time the makespan meters).
     """
-    if isinstance(process, Exponential):
-        draws = process.sample(key, (n_runs, max_failures, n_nodes))
-        return jnp.min(draws, axis=-1), jnp.argmin(draws, axis=-1)
+    with jax.named_scope("renewal_sample"):
+        if isinstance(process, Exponential):
+            draws = process.sample(key, (n_runs, max_failures, n_nodes))
+            return jnp.min(draws, axis=-1), jnp.argmin(draws, axis=-1)
 
-    v = jax.random.uniform(
-        key, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
+        v = jax.random.uniform(
+            key, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
 
-    def step(ages, v_k):
-        t = process.residual(v_k, ages)                      # (R, N)
-        gap = jnp.min(t, axis=-1)
-        failed = jnp.argmin(t, axis=-1)
-        ages = jnp.where(jnp.arange(n_nodes) == failed[:, None],
-                         0.0, ages + gap[:, None])
-        return ages, (gap, failed)
+        def step(ages, v_k):
+            t = process.residual(v_k, ages)                      # (R, N)
+            gap = jnp.min(t, axis=-1)
+            failed = jnp.argmin(t, axis=-1)
+            ages = jnp.where(jnp.arange(n_nodes) == failed[:, None],
+                             0.0, ages + gap[:, None])
+            return ages, (gap, failed)
 
-    init = jnp.zeros((n_runs, n_nodes), jnp.float32)
-    _, (gaps, failed) = jax.lax.scan(step, init, v)
-    return gaps.T, failed.T
+        init = jnp.zeros((n_runs, n_nodes), jnp.float32)
+        _, (gaps, failed) = jax.lax.scan(step, init, v)
+        return gaps.T, failed.T
 
 
 _sample_renewal_gaps_jit = jax.jit(

@@ -74,6 +74,7 @@ Semantics notes (also in docs/sweep.md):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional, Sequence
 
@@ -1075,116 +1076,125 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
 
     init = (jnp.concatenate([f8(inp.age0), f8(inp.reexec0)[None]]),
             f8(inp.exec_rem0), f8(0.0), f8(0.0), jnp.asarray(True))
-    carry, ys = jax.lax.scan(step, init, (f8(gaps), m_all))
-    ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
-    (valid, age_all, work_all, exec_rem_k, d_eff_all), t_fail = \
-        ys[:5], (None if stats else ys[5])
+    with jax.named_scope("renewal_scan"):
+        carry, ys = jax.lax.scan(step, init, (f8(gaps), m_all))
+    with jax.named_scope("renewal_fold"):
+        ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        (valid, age_all, work_all, exec_rem_k, d_eff_all), t_fail = \
+            ys[:5], (None if stats else ys[5])
 
-    # --- per-epoch accounting, vectorized over the stacked epochs ----------
-    age_f = age_all[..., :-1]                                    # (K, N)
-    reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
-                                    exec_rem_k)                  # (K,)
-    d_eff_fail = d_eff_all[..., -1]
-    t_recover = t_dr + reexec_f                                  # (K,)
-    t_failed_k = t_recover[..., None] + exec_rem_k               # (K, N)
-    t_e = t_recover + p_star
+        # --- per-epoch accounting, vectorized over the stacked epochs ------
+        age_f = age_all[..., :-1]                                    # (K, N)
+        reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
+                                        exec_rem_k)                  # (K,)
+        d_eff_fail = d_eff_all[..., -1]
+        t_recover = t_dr + reexec_f                                  # (K,)
+        t_failed_k = t_recover[..., None] + exec_rem_k               # (K, N)
+        t_e = t_recover + p_star
 
-    # balanced span energy up to each node's (snapped) failure instant,
-    # plus the coordinated resync checkpoint closing each epoch.  At the
-    # snapped instant the span's checkpoint share is exactly the fired
-    # checkpoints, so ``work``/``d_eff - work`` from the scan's sawtooth
-    # *is* the ``balanced_span`` decomposition (both are exact multiples
-    # of ``dur`` — tests pin the identity) without recomputing it.
-    e_bal = _ordered_sum(
-        work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0, axis=-1)
-    balanced = _ordered_sum(jnp.where(
-        valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
+        # balanced span energy up to each node's (snapped) failure instant,
+        # plus the coordinated resync checkpoint closing each epoch.  At the
+        # snapped instant the span's checkpoint share is exactly the fired
+        # checkpoints, so ``work``/``d_eff - work`` from the scan's sawtooth
+        # *is* the ``balanced_span`` decomposition (both are exact multiples
+        # of ``dur`` — tests pin the identity) without recomputing it.
+        e_bal = _ordered_sum(
+            work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0, axis=-1)
+        balanced = _ordered_sum(jnp.where(
+            valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
 
-    # failed node over [failure, T_E]: down (0 W) + restart at P_ckpt +
-    # re-execution and post-recovery serving at P_comp.  Every felled slot
-    # plays the same closed-form role (identical in reference and
-    # intervened runs, so the saving is untouched); the factor is 1 for the
-    # single-failure path.
-    n_felled = 1.0 if m_all is None else 1.0 + jnp.sum(m_all, axis=-1)
-    epoch_failed = jnp.where(
-        valid,
-        n_felled * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0), 0.0)
+        # failed node over [failure, T_E]: down (0 W) + restart at P_ckpt +
+        # re-execution and post-recovery serving at P_comp.  Every felled slot
+        # plays the same closed-form role (identical in reference and
+        # intervened runs, so the saving is untouched); the factor is 1 for the
+        # single-failure path.
+        n_felled = 1.0 if m_all is None else 1.0 + jnp.sum(m_all, axis=-1)
+        epoch_failed = jnp.where(
+            valid,
+            n_felled * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0),
+            0.0)
 
-    # per-level checkpoint plan as F separate node-batch columns: the fa
-    # column comes from the shared checkpoint_plan (it also decides the
-    # move-ahead), the others from the same closed form — no (..., F)
-    # float64 array ever materializes.
-    plan0 = planning.checkpoint_plan(
-        exec_rem_k, age_f, t_failed_k,
-        interval=interval, dur=dur, beta=beta[:1], gamma=gamma[:1],
-        move_ahead=inp.move_ahead, move_frac=f8(inp.move_frac))
-    move = jnp.where(plan0.plan_move, 1.0, 0.0)
-    n_cols = [plan0.n_ckpt[..., 0]] + [
-        planning.timer_checkpoint_count(exec_rem_k, age_f, beta[f], interval)
-        + move
-        for f in range(1, beta.shape[0])
-    ]
-    decision = strategies.evaluate_strategies_fold(
-        f4(exec_rem_k), f4(t_failed_k), n_cols, f4(dur),
-        ladder32, sleep32, inp.wait_mode, f4(inp.p_idle_wait),
-        mu1=f4(inp.mu1), mu2=f4(inp.mu2))
+        # per-level checkpoint plan as F separate node-batch columns: the fa
+        # column comes from the shared checkpoint_plan (it also decides the
+        # move-ahead), the others from the same closed form — no (..., F)
+        # float64 array ever materializes.
+        plan0 = planning.checkpoint_plan(
+            exec_rem_k, age_f, t_failed_k,
+            interval=interval, dur=dur, beta=beta[:1], gamma=gamma[:1],
+            move_ahead=inp.move_ahead, move_frac=f8(inp.move_frac))
+        move = jnp.where(plan0.plan_move, 1.0, 0.0)
+        n_cols = [plan0.n_ckpt[..., 0]] + [
+            planning.timer_checkpoint_count(
+                exec_rem_k, age_f, beta[f], interval)
+            + move
+            for f in range(1, beta.shape[0])
+        ]
+        decision = strategies.evaluate_strategies_fold(
+            f4(exec_rem_k), f4(t_failed_k), n_cols, f4(dur),
+            ladder32, sleep32, inp.wait_mode, f4(inp.p_idle_wait),
+            mu1=f4(inp.mu1), mu2=f4(inp.mu2))
 
-    # per-survivor epoch energy = window energy + trailing fa span to T_E
-    ct_ref = exec_rem_k * beta0 + n_cols[0] * dur * gamma0
-    t_e2 = t_e[..., None]
-    trail_ref = jnp.maximum(t_e2 - jnp.maximum(t_failed_k, ct_ref), 0.0) * p_comp0
-    trail_int = jnp.maximum(
-        t_e2 - jnp.maximum(t_failed_k, f8(decision.comp_time)), 0.0) * p_comp0
-    # felled slots are accounted through epoch_failed's closed form, not the
-    # survivor window energies (their Algorithm-1 point is meaningless)
-    v2 = (jnp.broadcast_to(valid[..., None], exec_rem_k.shape)
-          if m_all is None else valid[..., None] & ~m_all)
-    epoch_ref = jnp.where(v2, f8(decision.energy_reference) + trail_ref, 0.0)
-    epoch_int = jnp.where(v2, f8(decision.energy_intervened) + trail_int, 0.0)
+        # per-survivor epoch energy = window energy + trailing fa span to T_E
+        ct_ref = exec_rem_k * beta0 + n_cols[0] * dur * gamma0
+        t_e2 = t_e[..., None]
+        trail_ref = jnp.maximum(
+            t_e2 - jnp.maximum(t_failed_k, ct_ref), 0.0) * p_comp0
+        trail_int = jnp.maximum(
+            t_e2 - jnp.maximum(t_failed_k, f8(decision.comp_time)),
+            0.0) * p_comp0
+        # felled slots are accounted through epoch_failed's closed form, not
+        # the survivor window energies (their Algorithm-1 point is
+        # meaningless)
+        v2 = (jnp.broadcast_to(valid[..., None], exec_rem_k.shape)
+              if m_all is None else valid[..., None] & ~m_all)
+        epoch_ref = jnp.where(
+            v2, f8(decision.energy_reference) + trail_ref, 0.0)
+        epoch_int = jnp.where(
+            v2, f8(decision.energy_intervened) + trail_int, 0.0)
 
-    # balanced tail: the rest of the failure-free work (mid-checkpoint snaps
-    # can nudge bal_elapsed slightly past the makespan; clamp)
-    span = jnp.maximum(makespan - bal_elapsed, 0.0)
-    w_t, ck_t = planning.balanced_span(ages_all, span, interval, dur)
-    balanced = balanced + _ordered_sum(w_t * p_comp0 + ck_t * p_ckpt0)
+        # balanced tail: the rest of the failure-free work (mid-checkpoint
+        # snaps can nudge bal_elapsed slightly past the makespan; clamp)
+        span = jnp.maximum(makespan - bal_elapsed, 0.0)
+        w_t, ck_t = planning.balanced_span(ages_all, span, interval, dur)
+        balanced = balanced + _ordered_sum(w_t * p_comp0 + ck_t * p_ckpt0)
 
-    e_failed = _ordered_sum(epoch_failed)
-    energy_ref = balanced + _ordered_sum(epoch_ref) + e_failed
-    energy_int = balanced + _ordered_sum(epoch_int) + e_failed
-    common = dict(
-        valid=valid,
-        n_failures=jnp.sum(valid.astype(jnp.int32)),
-        truncated=alive & (bal_elapsed < makespan),
-        end_time=t_anchor + span,
-        balanced_energy=balanced,
-        energy_ref=energy_ref,
-        energy_int=energy_int,
-        saving=energy_ref - energy_int,
-    )
-    if stats:
-        # integer action counts over valid (epoch, survivor) points — the
-        # summary rates divide by the point count on the host, so they
-        # match the oracle's np.mean over the same points exactly.
-        i32 = lambda m: jnp.sum((v2 & m).astype(jnp.int32))
+        e_failed = _ordered_sum(epoch_failed)
+        energy_ref = balanced + _ordered_sum(epoch_ref) + e_failed
+        energy_int = balanced + _ordered_sum(epoch_int) + e_failed
+        common = dict(
+            valid=valid,
+            n_failures=jnp.sum(valid.astype(jnp.int32)),
+            truncated=alive & (bal_elapsed < makespan),
+            end_time=t_anchor + span,
+            balanced_energy=balanced,
+            energy_ref=energy_ref,
+            energy_int=energy_int,
+            saving=energy_ref - energy_int,
+        )
+        if stats:
+            # integer action counts over valid (epoch, survivor) points — the
+            # summary rates divide by the point count on the host, so they
+            # match the oracle's np.mean over the same points exactly.
+            i32 = lambda m: jnp.sum((v2 & m).astype(jnp.int32))
+            return dict(
+                common,
+                n_points=jnp.sum(v2.astype(jnp.int32)),
+                n_sleep=i32(decision.wait_action == em.WaitAction.SLEEP),
+                n_min_freq=i32(decision.wait_action == em.WaitAction.MIN_FREQ),
+                n_comp_changed=i32(decision.comp_changed),
+                n_infeasible=i32(~decision.feasible_any),
+            )
         return dict(
             common,
-            n_points=jnp.sum(v2.astype(jnp.int32)),
-            n_sleep=i32(decision.wait_action == em.WaitAction.SLEEP),
-            n_min_freq=i32(decision.wait_action == em.WaitAction.MIN_FREQ),
-            n_comp_changed=i32(decision.comp_changed),
-            n_infeasible=i32(~decision.feasible_any),
+            decision=decision,
+            t_fail=t_fail,
+            exec_rem=exec_rem_k,
+            t_failed=t_failed_k,
+            t_renewal=jnp.where(valid, t_e, 0.0),
+            epoch_ref=epoch_ref,
+            epoch_int=epoch_int,
+            epoch_failed=epoch_failed,
         )
-    return dict(
-        common,
-        decision=decision,
-        t_fail=t_fail,
-        exec_rem=exec_rem_k,
-        t_failed=t_failed_k,
-        t_renewal=jnp.where(valid, t_e, 0.0),
-        epoch_ref=epoch_ref,
-        epoch_int=epoch_int,
-        epoch_failed=epoch_failed,
-    )
 
 
 def _renewal_device_core(inp: SweepInputs, gaps: jax.Array, makespan_s,
@@ -1207,14 +1217,16 @@ def _attach_failed_counts(out: dict, failed: jax.Array, n_nodes: int,
     way for scenario and policy stacks.  With a correlated sampler's
     physical-node ``fmask`` ((R, K, n_nodes)) every felled node counts, not
     just the primary."""
-    valid = out.pop("valid")
-    if fmask is None:
-        hit = valid[..., None] & (
-            failed[None, ..., None] == jnp.arange(n_nodes)[None, None, None])
-    else:
-        hit = valid[..., None] & fmask[None]
-    out["failed_counts"] = jnp.sum(hit.astype(jnp.int32), axis=(1, 2))
-    return out
+    with jax.named_scope("renewal_fold"):
+        valid = out.pop("valid")
+        if fmask is None:
+            hit = valid[..., None] & (
+                failed[None, ..., None]
+                == jnp.arange(n_nodes)[None, None, None])
+        else:
+            hit = valid[..., None] & fmask[None]
+        out["failed_counts"] = jnp.sum(hit.astype(jnp.int32), axis=(1, 2))
+        return out
 
 
 def _renewal_mc_core(inp: SweepInputs, key: jax.Array, makespan_s, process,
@@ -1699,26 +1711,34 @@ def renewal_monte_carlo_device(
     same histories, <= 1e-4 relative on whole-run energies vs the float64
     oracle (tests/test_renewal_pallas.py).
     """
-    proc = failures.as_process(process, mtbf_s)
-    if engine == "pallas":
-        if not stats:
-            raise ValueError(
-                "engine='pallas' is the stats-only hot path; use the scan "
-                "engine for per-epoch RenewalDeviceResult diagnostics")
-        cfg_list, stacked = _renewal_device_inputs(cfgs, jnp.float32)
-        out = _renewal_pallas_mc_jit(
-            stacked, key, jnp.float32(makespan_s), proc,
-            n_runs=n_runs, max_failures=max_failures, topology=topology)
-        return _wrap_device_stats(out)
-    if engine != "scan":
-        raise ValueError(
-            f"unknown engine {engine!r} (use 'scan' or 'pallas')")
-    with jax.enable_x64():
-        cfg_list, stacked = _renewal_device_inputs(cfgs)
-        out, gaps, failed = _renewal_mc_jit(
-            stacked, key, float(makespan_s), proc,
-            n_runs=n_runs, max_failures=max_failures, stats=stats,
-            topology=topology)
+    # the scan engine's x64 mode opens while staging and holds through the
+    # dispatch, so one span can cover the staging alone
+    with contextlib.ExitStack() as x64:
+        with jax.profiler.TraceAnnotation("sweep.stage"):
+            proc = failures.as_process(process, mtbf_s)
+            if engine == "pallas":
+                if not stats:
+                    raise ValueError(
+                        "engine='pallas' is the stats-only hot path; use the "
+                        "scan engine for per-epoch RenewalDeviceResult "
+                        "diagnostics")
+                _, stacked = _renewal_device_inputs(cfgs, jnp.float32)
+            elif engine == "scan":
+                x64.enter_context(jax.enable_x64())
+                _, stacked = _renewal_device_inputs(cfgs)
+            else:
+                raise ValueError(
+                    f"unknown engine {engine!r} (use 'scan' or 'pallas')")
+        with jax.profiler.TraceAnnotation("sweep.dispatch"):
+            if engine == "pallas":
+                return _wrap_device_stats(_renewal_pallas_mc_jit(
+                    stacked, key, jnp.float32(makespan_s), proc,
+                    n_runs=n_runs, max_failures=max_failures,
+                    topology=topology))
+            out, gaps, failed = _renewal_mc_jit(
+                stacked, key, float(makespan_s), proc,
+                n_runs=n_runs, max_failures=max_failures, stats=stats,
+                topology=topology)
         if stats:
             return _wrap_device_stats(out)
         return _wrap_device_result(out, gaps, failed)
@@ -1995,17 +2015,21 @@ def renewal_monte_carlo_scenarios(
     S-1 dispatches and all the host round-trips.  ``engine="pallas"``
     swaps in the float32 Kahan-ledger kernel (``kernels.renewal_scan``).
     """
-    cfg_list = list(cfgs)
-    if process is not None:
-        mtbf_s = float(np.mean(failures.as_process(process).mean_s()))
-    kw = dict(n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
-              max_failures=max_failures)
-    # one transfer for the whole stats pytree — per-field np.asarray would
-    # pay a blocking round-trip per (scenario, field)
-    res = jax.device_get(
-        renewal_monte_carlo_device(cfg_list, key, stats=True, process=process,
-                                   topology=topology, engine=engine, **kw))
-    return {
-        cfg.name: _summarize_device_scenario(res, s, **kw)
-        for s, cfg in enumerate(cfg_list)
-    }
+    with jax.profiler.TraceAnnotation("sweep.study"):
+        cfg_list = list(cfgs)
+        if process is not None:
+            mtbf_s = float(np.mean(failures.as_process(process).mean_s()))
+        kw = dict(n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
+                  max_failures=max_failures)
+        out = renewal_monte_carlo_device(
+            cfg_list, key, stats=True, process=process, topology=topology,
+            engine=engine, **kw)
+        # one transfer for the whole stats pytree — per-field np.asarray
+        # would pay a blocking round-trip per (scenario, field)
+        with jax.profiler.TraceAnnotation("sweep.fetch"):
+            res = jax.device_get(out)
+        with jax.profiler.TraceAnnotation("sweep.summarize"):
+            return {
+                cfg.name: _summarize_device_scenario(res, s, **kw)
+                for s, cfg in enumerate(cfg_list)
+            }

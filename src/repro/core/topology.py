@@ -122,7 +122,9 @@ class TopologyLevel:
         failures._check_positive("shock_mtbs_s", self.shock_mtbs_s)
         for nm, v in (("p_kill", self.p_kill),
                       ("age_boost_s", self.age_boost_s)):
-            if not isinstance(v, jax.core.Tracer):
+            # numbers are arrays by now; tracers and the placeholders that
+            # pytree unflattening passes (as in ``jax.jit(...).lower``) are not
+            if isinstance(v, np.ndarray):
                 a = np.asarray(v, np.float64)
                 if nm == "p_kill" and (np.any(a <= 0.0) or np.any(a > 1.0)):
                     raise ValueError(f"p_kill must be in (0, 1], got {a}")
@@ -226,57 +228,59 @@ def sample_correlated_renewal_gaps(
     standalone for the host oracle (``correlated_renewal_gaps``), so the
     two see bit-identical histories for the same key.
     """
-    if topology.n_nodes != n_nodes:
-        raise ValueError(f"topology has {topology.n_nodes} nodes, "
-                         f"sampler asked for {n_nodes}")
-    member = jnp.asarray(_member_matrix(topology))        # (G, N) bool
-    mtbs, pkill, boost = _group_params(topology)          # (G,) each
-    n_groups = member.shape[0]
-    k_res, k_shock, k_kill = jax.random.split(key, 3)
-    v = jax.random.uniform(
-        k_res, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
-    w = jax.random.uniform(
-        k_kill, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
-    su = jax.random.uniform(
-        k_shock, (max_failures, n_runs, n_groups), dtype=jnp.float32)
-    node_ids = jnp.arange(n_nodes)
+    with jax.named_scope("renewal_sample"):
+        if topology.n_nodes != n_nodes:
+            raise ValueError(f"topology has {topology.n_nodes} nodes, "
+                             f"sampler asked for {n_nodes}")
+        member = jnp.asarray(_member_matrix(topology))        # (G, N) bool
+        mtbs, pkill, boost = _group_params(topology)          # (G,) each
+        n_groups = member.shape[0]
+        k_res, k_shock, k_kill = jax.random.split(key, 3)
+        v = jax.random.uniform(
+            k_res, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
+        w = jax.random.uniform(
+            k_kill, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
+        su = jax.random.uniform(
+            k_shock, (max_failures, n_runs, n_groups), dtype=jnp.float32)
+        node_ids = jnp.arange(n_nodes)
 
-    def step(ages, xs):
-        v_k, w_k, su_k = xs
-        t = process.residual(v_k, ages)                   # (R, N)
-        gap_ind = jnp.min(t, axis=-1)
-        i_ind = jnp.argmin(t, axis=-1)
-        # fresh exponential shock clocks per anchor (exact: memoryless)
-        s_times = mtbs * (-jnp.log1p(-su_k))              # (R, G)
-        gap_shk = jnp.min(s_times, axis=-1)
-        g_shk = jnp.argmin(s_times, axis=-1)
-        shock = gap_shk < gap_ind                         # ties -> individual
-        gap = jnp.where(shock, gap_shk, gap_ind)
-        member_g = member[g_shk]                          # (R, N)
-        killed = member_g & (w_k < pkill[g_shk][:, None])
-        # condition on >= 1 kill: the member with the smallest kill draw
-        # falls even when every Bernoulli spares (the epoch grammar needs a
-        # failure; the bias is documented and vanishes as p_kill -> 1)
-        w_m = jnp.where(member_g, w_k, jnp.inf)
-        forced = node_ids == jnp.argmin(w_m, axis=-1)[:, None]
-        killed = jnp.where(jnp.any(killed, axis=-1, keepdims=True),
-                           killed, forced)
-        mask = jnp.where(shock[:, None],
-                         killed, node_ids == i_ind[:, None])
-        primary = jnp.where(
-            shock, jnp.argmin(jnp.where(killed, w_k, jnp.inf), axis=-1),
-            i_ind).astype(jnp.int32)
-        spared = shock[:, None] & member_g & ~killed
-        ages = jnp.where(
-            mask, 0.0,
-            ages + gap[:, None]
-            + jnp.where(spared, boost[g_shk][:, None], 0.0))
-        return ages, (gap, mask, primary)
+        def step(ages, xs):
+            v_k, w_k, su_k = xs
+            t = process.residual(v_k, ages)                   # (R, N)
+            gap_ind = jnp.min(t, axis=-1)
+            i_ind = jnp.argmin(t, axis=-1)
+            # fresh exponential shock clocks per anchor (exact: memoryless)
+            s_times = mtbs * (-jnp.log1p(-su_k))              # (R, G)
+            gap_shk = jnp.min(s_times, axis=-1)
+            g_shk = jnp.argmin(s_times, axis=-1)
+            # ties -> individual
+            shock = gap_shk < gap_ind
+            gap = jnp.where(shock, gap_shk, gap_ind)
+            member_g = member[g_shk]                          # (R, N)
+            killed = member_g & (w_k < pkill[g_shk][:, None])
+            # condition on >= 1 kill: the member with the smallest kill draw
+            # falls even when every Bernoulli spares (the epoch grammar needs a
+            # failure; the bias is documented and vanishes as p_kill -> 1)
+            w_m = jnp.where(member_g, w_k, jnp.inf)
+            forced = node_ids == jnp.argmin(w_m, axis=-1)[:, None]
+            killed = jnp.where(jnp.any(killed, axis=-1, keepdims=True),
+                               killed, forced)
+            mask = jnp.where(shock[:, None],
+                             killed, node_ids == i_ind[:, None])
+            primary = jnp.where(
+                shock, jnp.argmin(jnp.where(killed, w_k, jnp.inf), axis=-1),
+                i_ind).astype(jnp.int32)
+            spared = shock[:, None] & member_g & ~killed
+            ages = jnp.where(
+                mask, 0.0,
+                ages + gap[:, None]
+                + jnp.where(spared, boost[g_shk][:, None], 0.0))
+            return ages, (gap, mask, primary)
 
-    init = jnp.zeros((n_runs, n_nodes), jnp.float32)
-    _, (gaps, mask, primary) = jax.lax.scan(step, init, (v, w, su))
-    return (jnp.transpose(gaps), jnp.transpose(mask, (1, 0, 2)),
-            jnp.transpose(primary))
+        init = jnp.zeros((n_runs, n_nodes), jnp.float32)
+        _, (gaps, mask, primary) = jax.lax.scan(step, init, (v, w, su))
+        return (jnp.transpose(gaps), jnp.transpose(mask, (1, 0, 2)),
+                jnp.transpose(primary))
 
 
 _sample_correlated_jit = jax.jit(
@@ -312,11 +316,12 @@ def survivor_slot_mask(failed_mask, primary):
     the primary).  Works on numpy and traced jnp arrays; shapes
     ``(..., N) -> (..., N - 1)`` with ``primary`` shaped ``(...)``.
     """
-    xp = _ns(failed_mask)
-    n = failed_mask.shape[-1]
-    idx = xp.arange(n - 1)
-    phys = idx + (idx >= primary[..., None])
-    return xp.take_along_axis(failed_mask, phys, axis=-1)
+    with jax.named_scope("renewal_sample"):
+        xp = _ns(failed_mask)
+        n = failed_mask.shape[-1]
+        idx = xp.arange(n - 1)
+        phys = idx + (idx >= primary[..., None])
+        return xp.take_along_axis(failed_mask, phys, axis=-1)
 
 
 # ---------------------------------------------------------------------------

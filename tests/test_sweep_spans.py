@@ -1,0 +1,59 @@
+"""The study path's names for its layers: the device scopes inside the
+fused Monte-Carlo program, and the host spans of one study in a profiler
+trace (docs/sweep.md, "Tracing a study")."""
+import glob
+import re
+
+import jax
+import pytest
+from jax.profiler import ProfileData
+
+from repro.core import failures as F
+from repro.core import sweep
+from repro.core import topology as T
+from repro.core.scenarios import paper_scenarios
+
+SCOPES = ("renewal_sample", "renewal_scan", "renewal_fold")
+SPANS = ("sweep.study", "sweep.stage", "sweep.dispatch", "sweep.fetch",
+         "sweep.summarize")
+MTBF = 7 * 24 * 3600.0
+CFGS = list(paper_scenarios().values())
+
+
+def _processes():
+    rack = T.rack_topology(4, 3, shock_mtbs_s=10 * MTBF, p_kill=0.6,
+                           age_boost_s=3600.0)
+    return {"exponential": (F.Exponential(MTBF), None),
+            "weibull": (F.Weibull.from_mtbf(0.7, MTBF), None),
+            "rack": (F.Weibull.from_mtbf(0.7, MTBF), rack)}
+
+
+@pytest.mark.parametrize("family", sorted(_processes()))
+def test_the_fused_program_carries_the_three_scopes(family):
+    process, topology = _processes()[family]
+    with jax.enable_x64():
+        _, stacked = sweep._renewal_device_inputs(CFGS)
+        text = sweep._renewal_mc_jit.lower(
+            stacked, jax.random.PRNGKey(0), 30 * 24 * 3600.0, process,
+            n_runs=8, max_failures=4, stats=True,
+            topology=topology).as_text(debug_info=True)
+    # a scope is a component of an operation's name, perhaps inside a
+    # transform's wrapper: "jit(f)/vmap(vmap(renewal_fold))/mul"
+    for scope in SCOPES:
+        assert re.search(rf'loc\("[^"]*[/(]{scope}[)/][^"]*"', text), scope
+
+
+def test_a_traced_study_shows_its_host_spans(tmp_path):
+    process, topology = _processes()["rack"]
+    kw = dict(n_runs=8, max_failures=4, process=process, topology=topology)
+    want = sweep.renewal_monte_carlo_scenarios(CFGS[:2], jax.random.PRNGKey(1),
+                                               **kw)
+    jax.profiler.start_trace(str(tmp_path))
+    got = sweep.renewal_monte_carlo_scenarios(CFGS[:2], jax.random.PRNGKey(1),
+                                              **kw)
+    jax.profiler.stop_trace()
+    assert got == want
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert set(SPANS) <= names
