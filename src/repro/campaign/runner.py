@@ -21,8 +21,10 @@ The runner turns a validated ``CampaignSpec`` into stored results:
    random numbers), so a cell's stored record is bit-identical whatever
    chunk it lands in (pinned in tests/test_campaign.py).
 4. **Scatter** — each lane's whole-run statistics reduce to the same
-   ``RenewalMonteCarloSummary`` fields the scenario path emits
-   (``sweep._summarize_device_scenario``), serialized as the record's
+   ``RenewalMonteCarloSummary`` fields the scenario path emits, through
+   the study's own reduction over runs on the device
+   (``sweep._study_reduce``) and its host assembly
+   (``sweep._study_summary``), serialized as the record's
    deterministic ``result`` payload and written cell-at-a-time, so an
    interrupted run keeps every finished cell.
 """
@@ -132,16 +134,23 @@ def _dispatch_chunk(chunk: list, progress) -> list:
         # content-memoized float64 stacking (sweep's own input cache), with
         # the renewal preconditions checked per config
         _, stacked = sweep._renewal_device_inputs(cfgs)
-    stats = jax.device_get(sweep.renewal_monte_carlo_policies(
+    stats = sweep.renewal_monte_carlo_policies(
         stacked, jax.random.PRNGKey(exp0.seed), makespan_s=makespans,
         n_runs=exp0.n_runs, max_failures=exp0.max_failures,
-        process=proc, topology=exp0.topology, stats=True))
-    end_time = np.asarray(stats.end_time, np.float64)
+        process=proc, topology=exp0.topology, stats=True)
+    # the study's own reduction over runs, so that a lane's summary equals
+    # the scenario path's bit for bit
+    with jax.enable_x64():
+        study = sweep._study_reduce_jit(vars(stats),
+                                        max_failures=exp0.max_failures)
+    (totals, moments), end_time = jax.device_get((study, stats.end_time))
+    end_time = np.asarray(end_time, np.float64)
     out = []
     for i, r in enumerate(chunk):
-        summ = sweep._summarize_device_scenario(
-            stats, i, n_runs=exp0.n_runs, makespan_s=float(makespans[i]),
-            mtbf_s=mtbf, max_failures=exp0.max_failures)
+        summ = sweep._study_summary(
+            totals[i], moments[i], n_runs=exp0.n_runs,
+            makespan_s=float(makespans[i]), mtbf_s=mtbf,
+            max_failures=exp0.max_failures)
         result = summary_to_result(summ)
         # realized mean wall makespan (failures stretch the run past the
         # failure-free makespan_s input) — the optimizer's second objective

@@ -1422,6 +1422,124 @@ _renewal_pallas_mc_jit = jax.jit(
     static_argnames=("n_runs", "max_failures", "compensated"))
 
 
+# ---------------------------------------------------------------------------
+# a study: the fused Monte-Carlo reduced over runs inside its own program
+# ---------------------------------------------------------------------------
+
+# integer columns of a study's reduction: these totals over runs, then the
+# n_failures histogram over 0..max_failures, then failed_counts per node
+_STUDY_TOTALS = ("n_points", "n_sleep", "n_min_freq", "n_comp_changed",
+                 "n_infeasible")
+# float64 columns of a study's reduction, named as the summary's fields
+_STUDY_MOMENTS = ("mean_failures", "truncated_rate", "mean_energy_ref_j",
+                  "mean_energy_int_j", "mean_saving_j", "p5_saving_j",
+                  "p95_saving_j")
+_PERCENTILES = (5.0, 95.0)
+
+
+def _rank_counts(x, block: int = 256):
+    """How many values of its row (last axis) each value of ``x`` is at
+    least, by comparing every pair: ``block`` values at a time, so that no
+    more than ``block`` x ``n`` comparisons are held at once."""
+    cols = jnp.moveaxis(x, -1, 0)
+    le = jax.lax.map(
+        lambda c: jnp.sum(x <= c[..., None], axis=-1, dtype=jnp.int32),
+        cols, batch_size=block)
+    return jnp.moveaxis(le, 0, -1)
+
+
+def _percentiles(x):
+    """``np.percentile(x, q, axis=-1)`` at each of ``_PERCENTILES``, as
+    numpy's default (``linear``) method computes it: the values at the
+    ranks below and above the virtual index ``(n - 1) q / 100`` of the
+    sorted row, interpolated as numpy's ``_lerp`` does, and NaN on a row
+    holding a NaN.  The ``k``-th smallest value (from 0) is the least value
+    with more than ``k`` values at most itself (``_rank_counts``): the
+    entry of ``jnp.sort(x)`` at ``k`` exactly, without a sort, whose
+    compile for a TPU v5e takes about two minutes at a study's (6, 4096)
+    float64 (the counts: under a second)."""
+    n = x.shape[-1]
+    le = _rank_counts(x)
+    kth = lambda k: jnp.min(jnp.where(le > min(k, n - 1), x, jnp.inf),
+                            axis=-1)
+    has_nan = jnp.any(jnp.isnan(x), axis=-1)
+    out = []
+    for q in _PERCENTILES:
+        vi = (n - 1) * (q / 100)
+        lo, g = int(vi), vi - int(vi)
+        a, b = kth(lo), kth(lo + 1)
+        diff = b - a
+        p = b - diff * (1.0 - g) if g >= 0.5 else a + diff * g
+        out.append(jnp.where(has_nan, jnp.nan, p))
+    return out
+
+
+def _study_reduce(out: dict, max_failures: int):
+    """A study's reduction over runs: the per-run stats of the fused
+    Monte-Carlo (``out``: ``RenewalDeviceStats`` fields, (S, R) per
+    scenario and run, and ``failed_counts`` (S, n_nodes)) to what
+    ``RenewalMonteCarloSummary`` reads, per scenario:
+
+    * ``totals`` (S, 5 + max_failures + 1 + n_nodes) int32: the
+      ``_STUDY_TOTALS`` summed over runs, the histogram of ``n_failures``
+      over ``0..max_failures``, and ``failed_counts``;
+    * ``moments`` (S, 7) float64, the ``_STUDY_MOMENTS``: means over runs
+      (energies as ``_ordered_sum`` trees, the same wherever the lane is
+      computed, so a campaign lane equals the scenario path bit for bit)
+      and the saving's percentiles as ``np.percentile`` gives them.
+
+    Float64 whatever the caller's mode: the float32 Pallas stats are cast
+    first, as the host summary cast them."""
+    n_runs = out["saving"].shape[-1]
+    n_survivors = out["failed_counts"].shape[-1] - 1
+    if n_runs * max_failures * max(n_survivors, 1) >= 2 ** 31:
+        raise ValueError(
+            f"{n_runs} runs x {max_failures} failures x {n_survivors} "
+            "survivors overflow a study's int32 decision-point counts")
+    count = lambda a: jnp.sum(a, axis=-1, dtype=jnp.int32)
+    with jax.named_scope("renewal_fold"), jax.enable_x64(True):
+        f8 = lambda a: jnp.asarray(a, jnp.float64)
+        mean = lambda a: _ordered_sum(f8(a), axis=-1) / n_runs
+        hist = count(out["n_failures"][..., None, :]
+                     == jnp.arange(max_failures + 1, dtype=jnp.int32)[:, None])
+        totals = jnp.concatenate(
+            [jnp.stack([count(out[k]) for k in _STUDY_TOTALS], -1), hist,
+             out["failed_counts"].astype(jnp.int32)], -1)
+        saving = f8(out["saving"])
+        moments = jnp.stack(
+            [f8(count(out["n_failures"])) / n_runs,
+             f8(count(out["truncated"])) / n_runs,
+             mean(out["energy_ref"]), mean(out["energy_int"]), mean(saving),
+             *_percentiles(saving)], -1)
+    return totals, moments
+
+
+_study_reduce_jit = jax.jit(_study_reduce, static_argnames=("max_failures",))
+
+
+def _renewal_study_core(stacked: SweepInputs, key: jax.Array, makespan_s,
+                        process, n_runs: int, max_failures: int,
+                        topology=None, engine: str = "scan"):
+    """One study as one program: the engine's fused Monte-Carlo in stats
+    mode (``_renewal_mc_core`` under x64, or ``_renewal_pallas_mc_core`` in
+    float32), then ``_study_reduce``.  Only the reduction leaves the
+    device, so the compiler drops what no summary reads: the scan's wall
+    clock (``end_time``) and the balanced energy."""
+    if engine == "pallas":
+        out = _renewal_pallas_mc_core(stacked, key, makespan_s, process,
+                                      n_runs, max_failures, topology=topology)
+    else:
+        out, _, _ = _renewal_mc_core(stacked, key, makespan_s, process,
+                                     n_runs, max_failures, stats=True,
+                                     topology=topology)
+    return _study_reduce(out, max_failures)
+
+
+_renewal_study_jit = jax.jit(
+    _renewal_study_core,
+    static_argnames=("n_runs", "max_failures", "engine"))
+
+
 def renewal_compose_policies(stacked: SweepInputs, gaps, makespan_s,
                              felled=None):
     """Compose explicit failure histories for a policy-stacked scenario.
@@ -1710,25 +1828,17 @@ def renewal_monte_carlo_device(
     belongs to the cross-validating engines), same sampler, same keys,
     same histories, <= 1e-4 relative on whole-run energies vs the float64
     oracle (tests/test_renewal_pallas.py).
+
+    This is the per-run view, for callers that read runs one by one (the
+    policy grid, ``FleetAdvisor``, the FT controller read ``end_time``).
+    A study's summaries come from ``renewal_monte_carlo_scenarios``, whose
+    program reduces over runs on the device.
     """
-    # the scan engine's x64 mode opens while staging and holds through the
-    # dispatch, so one span can cover the staging alone
-    with contextlib.ExitStack() as x64:
-        with jax.profiler.TraceAnnotation("sweep.stage"):
-            proc = failures.as_process(process, mtbf_s)
-            if engine == "pallas":
-                if not stats:
-                    raise ValueError(
-                        "engine='pallas' is the stats-only hot path; use the "
-                        "scan engine for per-epoch RenewalDeviceResult "
-                        "diagnostics")
-                _, stacked = _renewal_device_inputs(cfgs, jnp.float32)
-            elif engine == "scan":
-                x64.enter_context(jax.enable_x64())
-                _, stacked = _renewal_device_inputs(cfgs)
-            else:
-                raise ValueError(
-                    f"unknown engine {engine!r} (use 'scan' or 'pallas')")
+    if engine == "pallas" and not stats:
+        raise ValueError(
+            "engine='pallas' is the stats-only hot path; use the scan "
+            "engine for per-epoch RenewalDeviceResult diagnostics")
+    with _staged(cfgs, process, mtbf_s, engine) as (stacked, proc):
         with jax.profiler.TraceAnnotation("sweep.dispatch"):
             if engine == "pallas":
                 return _wrap_device_stats(_renewal_pallas_mc_jit(
@@ -1742,6 +1852,42 @@ def renewal_monte_carlo_device(
         if stats:
             return _wrap_device_stats(out)
         return _wrap_device_result(out, gaps, failed)
+
+
+@contextlib.contextmanager
+def _staged(cfgs, process, mtbf_s, engine: str):
+    """Stage a Monte-Carlo dispatch under the ``sweep.stage`` span: yields
+    the stacked scenarios in the engine's dtype and the failure process.
+    The scan engine's x64 mode opens while staging and holds until the
+    block ends, so the dispatch inside it runs in x64 and one span covers
+    the staging alone."""
+    with contextlib.ExitStack() as x64:
+        with jax.profiler.TraceAnnotation("sweep.stage"):
+            proc = failures.as_process(process, mtbf_s)
+            if engine == "pallas":
+                _, stacked = _renewal_device_inputs(cfgs, jnp.float32)
+            elif engine == "scan":
+                x64.enter_context(jax.enable_x64())
+                _, stacked = _renewal_device_inputs(cfgs)
+            else:
+                raise ValueError(
+                    f"unknown engine {engine!r} (use 'scan' or 'pallas')")
+        yield stacked, proc
+
+
+def _renewal_study_device(cfgs, key, *, n_runs: int, makespan_s: float,
+                          mtbf_s: float, max_failures: int, process,
+                          topology, engine: str):
+    """Stage and dispatch one study's program (``_renewal_study_core``);
+    returns its reduction over runs, ``(totals, moments)``, on the
+    device."""
+    with _staged(cfgs, process, mtbf_s, engine) as (stacked, proc):
+        with jax.profiler.TraceAnnotation("sweep.dispatch"):
+            makespan = (jnp.float32(makespan_s) if engine == "pallas"
+                        else float(makespan_s))
+            return _renewal_study_jit(
+                stacked, key, makespan, proc, n_runs=n_runs,
+                max_failures=max_failures, topology=topology, engine=engine)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1775,55 +1921,50 @@ class RenewalMonteCarloSummary:
 
 def _assemble_summary(
     *,
-    counts,
     per_node,
-    truncated,
-    energy_ref,
-    energy_int,
-    saving,
-    sleep_occupancy,
-    min_freq_rate,
-    comp_change_rate,
-    infeasible_rate,
     n_runs: int,
     makespan_s: float,
     mtbf_s: float,
     max_failures: int,
+    **fields,
 ) -> RenewalMonteCarloSummary:
-    """The single ``RenewalMonteCarloSummary`` construction behind both
-    engines: every derived formula (histogram, percentiles, saving pct,
-    annual scaling) exists once, so host and device summaries can only
-    differ where their inputs do — which the determinism test pins to
-    ~float64 round-off.  The engines differ only in how they derive the
-    action-occupancy *rates* (host: means over valid decision points;
-    device: on-device integer counts over the same points — identical
-    values by construction)."""
-    counts = np.asarray(counts)
-    energy_ref = np.asarray(energy_ref, np.float64)
-    saving = np.asarray(saving, np.float64)
-    mean_ref = float(energy_ref.mean())
-    mean_saving = float(saving.mean())
+    """The single ``RenewalMonteCarloSummary`` construction behind every
+    engine, from one scenario's reduction over runs: the ``_STUDY_MOMENTS``,
+    ``failure_count_hist`` and the four action rates in ``fields``, and
+    the mean failures per node.  The derived formulas (saving pct, annual
+    scaling) exist once, so the engines' summaries can only differ where
+    their reductions do.  The host oracle reduces with numpy
+    (``_run_moments``), a study on the device (``_study_reduce``, read by
+    ``_study_summary``)."""
+    mean_ref, mean_saving = fields["mean_energy_ref_j"], fields["mean_saving_j"]
     return RenewalMonteCarloSummary(
         n_runs=n_runs,
         makespan_s=float(makespan_s),
         mtbf_s=float(mtbf_s),
         max_failures=max_failures,
+        per_node_failures=tuple(per_node),
+        mean_saving_pct=float(100.0 * mean_saving / max(mean_ref, 1e-9)),
+        annual_saving_j=mean_saving * SECONDS_PER_YEAR / float(makespan_s),
+        **fields,
+    )
+
+
+def _run_moments(n_failures, truncated, energy_ref, energy_int,
+                 saving) -> dict:
+    """numpy's reduction over one scenario's runs, the host oracle's: the
+    ``_STUDY_MOMENTS`` and the failure-count histogram."""
+    counts = np.asarray(n_failures)
+    saving = np.asarray(saving, np.float64)
+    return dict(
         mean_failures=float(counts.mean()),
         failure_count_hist={
             int(c): float(np.mean(counts == c)) for c in np.unique(counts)},
-        per_node_failures=tuple(per_node),
         truncated_rate=float(np.mean(np.asarray(truncated, bool))),
-        mean_energy_ref_j=mean_ref,
+        mean_energy_ref_j=float(np.asarray(energy_ref, np.float64).mean()),
         mean_energy_int_j=float(np.asarray(energy_int, np.float64).mean()),
-        mean_saving_j=mean_saving,
+        mean_saving_j=float(saving.mean()),
         p5_saving_j=float(np.percentile(saving, 5)),
         p95_saving_j=float(np.percentile(saving, 95)),
-        mean_saving_pct=float(100.0 * mean_saving / max(mean_ref, 1e-9)),
-        sleep_occupancy=sleep_occupancy,
-        min_freq_rate=min_freq_rate,
-        comp_change_rate=comp_change_rate,
-        infeasible_rate=infeasible_rate,
-        annual_saving_j=mean_saving * SECONDS_PER_YEAR / float(makespan_s),
     )
 
 
@@ -1853,7 +1994,6 @@ def _renewal_summary(
     (physical-node mask) attributes every felled node in ``per_node`` —
     both mirror what the device path's integer counts do."""
     valid = np.asarray(valid, bool)
-    counts = valid.sum(axis=1)
     failed_node = np.asarray(failed_node)
     if fmask is None:
         per_node = tuple(
@@ -1870,12 +2010,9 @@ def _renewal_summary(
     actions = np.asarray(wait_action)[v.nonzero()] if v.any() else np.array([])
     pick = lambda a: np.asarray(a)[v.nonzero()]
     return _assemble_summary(
-        counts=counts,
+        **_run_moments(valid.sum(axis=1), truncated, energy_ref, energy_int,
+                       saving),
         per_node=per_node,
-        truncated=truncated,
-        energy_ref=energy_ref,
-        energy_int=energy_int,
-        saving=saving,
         sleep_occupancy=float(np.mean(actions == em.WaitAction.SLEEP))
         if actions.size else 0.0,
         min_freq_rate=float(np.mean(actions == em.WaitAction.MIN_FREQ))
@@ -1889,27 +2026,29 @@ def _renewal_summary(
     )
 
 
-def _summarize_device_scenario(
-    stats: RenewalDeviceStats, s: int,
+def _study_summary(
+    totals, moments, *,
     n_runs: int, makespan_s: float, mtbf_s: float, max_failures: int,
 ) -> RenewalMonteCarloSummary:
-    """Summary from the lean device stats — rates rebuilt from the integer
-    counts (exactly ``np.mean`` over the oracle's valid points); assembly
-    shared with the host path via ``_assemble_summary``."""
-    n_pts = int(np.asarray(stats.n_points)[s].sum())
-    rate = (lambda c: float(np.int64(np.asarray(c)[s].sum()) / n_pts)) \
-        if n_pts else (lambda c: 0.0)
+    """One scenario's summary from its row of a study's reduction
+    (``_study_reduce``): the rates are integer ratios over the valid
+    decision points and the histogram the non-zero bins over ``n_runs``,
+    exactly the oracle's ``np.mean`` over the same points and runs;
+    assembly shared with the host path via ``_assemble_summary``."""
+    totals = np.asarray(totals).tolist()
+    k = len(_STUDY_TOTALS)
+    total = dict(zip(_STUDY_TOTALS, totals[:k]))
+    hist = totals[k:k + max_failures + 1]
+    n_pts = total["n_points"]
+    rate = lambda name: total[name] / n_pts if n_pts else 0.0
     return _assemble_summary(
-        counts=np.asarray(stats.n_failures)[s],
-        per_node=(float(c) / n_runs for c in np.asarray(stats.failed_counts)[s]),
-        truncated=np.asarray(stats.truncated, bool)[s],
-        energy_ref=np.asarray(stats.energy_ref, np.float64)[s],
-        energy_int=np.asarray(stats.energy_int, np.float64)[s],
-        saving=np.asarray(stats.saving, np.float64)[s],
-        sleep_occupancy=rate(stats.n_sleep),
-        min_freq_rate=rate(stats.n_min_freq),
-        comp_change_rate=rate(stats.n_comp_changed),
-        infeasible_rate=rate(stats.n_infeasible),
+        **dict(zip(_STUDY_MOMENTS, np.asarray(moments, np.float64).tolist())),
+        failure_count_hist={c: h / n_runs for c, h in enumerate(hist) if h},
+        per_node=[c / n_runs for c in totals[k + max_failures + 1:]],
+        sleep_occupancy=rate("n_sleep"),
+        min_freq_rate=rate("n_min_freq"),
+        comp_change_rate=rate("n_comp_changed"),
+        infeasible_rate=rate("n_infeasible"),
         n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
         max_failures=max_failures,
     )
@@ -1958,10 +2097,10 @@ def renewal_monte_carlo(
     kw = dict(n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
               max_failures=max_failures)
     if engine in ("device", "pallas"):
-        res = renewal_monte_carlo_device(
-            cfg, key, stats=True, process=process, topology=topology,
-            engine="pallas" if engine == "pallas" else "scan", **kw)
-        return _summarize_device_scenario(jax.device_get(res), 0, **kw)
+        totals, moments = jax.device_get(_renewal_study_device(
+            cfg, key, process=process, topology=topology,
+            engine="pallas" if engine == "pallas" else "scan", **kw))
+        return _study_summary(totals[0], moments[0], **kw)
     if engine != "host":
         raise ValueError(
             f"unknown engine {engine!r} (use 'device', 'pallas' or 'host')")
@@ -2007,13 +2146,16 @@ def renewal_monte_carlo_scenarios(
     engine: str = "scan",
 ) -> dict:
     """name -> ``RenewalMonteCarloSummary`` for stacked scenarios from ONE
-    fused device dispatch (sampling + scan + Algorithm 1 + reduction).
+    fused device dispatch (sampling + scan + Algorithm 1 + the reduction
+    over runs, ``_renewal_study_core``).
 
     Every scenario sees the same sampled failure histories — exactly what
     calling ``renewal_monte_carlo`` per scenario with the same key (and
     ``process``, and ``topology`` for the correlated family) yields, minus
-    S-1 dispatches and all the host round-trips.  ``engine="pallas"``
-    swaps in the float32 Kahan-ledger kernel (``kernels.renewal_scan``).
+    S-1 dispatches and all the host round-trips.  Only the summaries'
+    numbers leave the device, a few KB a study whatever the run count.
+    ``engine="pallas"`` swaps in the float32 Kahan-ledger kernel
+    (``kernels.renewal_scan``).
     """
     with jax.profiler.TraceAnnotation("sweep.study"):
         cfg_list = list(cfgs)
@@ -2021,15 +2163,15 @@ def renewal_monte_carlo_scenarios(
             mtbf_s = float(np.mean(failures.as_process(process).mean_s()))
         kw = dict(n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
                   max_failures=max_failures)
-        out = renewal_monte_carlo_device(
-            cfg_list, key, stats=True, process=process, topology=topology,
+        study = _renewal_study_device(
+            cfg_list, key, process=process, topology=topology,
             engine=engine, **kw)
-        # one transfer for the whole stats pytree — per-field np.asarray
-        # would pay a blocking round-trip per (scenario, field)
-        with jax.profiler.TraceAnnotation("sweep.fetch"):
-            res = jax.device_get(out)
+        n_bytes = sum(a.nbytes for a in study)
+        # one transfer a reduction array for the whole study
+        with jax.profiler.TraceAnnotation("sweep.fetch", bytes=n_bytes):
+            totals, moments = jax.device_get(study)
         with jax.profiler.TraceAnnotation("sweep.summarize"):
             return {
-                cfg.name: _summarize_device_scenario(res, s, **kw)
+                cfg.name: _study_summary(totals[s], moments[s], **kw)
                 for s, cfg in enumerate(cfg_list)
             }

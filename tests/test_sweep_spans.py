@@ -28,15 +28,20 @@ def _processes():
             "rack": (F.Weibull.from_mtbf(0.7, MTBF), rack)}
 
 
-@pytest.mark.parametrize("family", sorted(_processes()))
-def test_the_fused_program_carries_the_three_scopes(family):
+@pytest.mark.parametrize("family,program", [
+    *(pytest.param(f, "mc", id=f) for f in sorted(_processes())),
+    *(pytest.param(f, "study", id=f"{f}-study") for f in sorted(_processes())),
+])
+def test_the_fused_program_carries_the_three_scopes(family, program):
     process, topology = _processes()[family]
     with jax.enable_x64():
         _, stacked = sweep._renewal_device_inputs(CFGS)
-        text = sweep._renewal_mc_jit.lower(
-            stacked, jax.random.PRNGKey(0), 30 * 24 * 3600.0, process,
-            n_runs=8, max_failures=4, stats=True,
-            topology=topology).as_text(debug_info=True)
+        args = (stacked, jax.random.PRNGKey(0), 30 * 24 * 3600.0, process)
+        kw = dict(n_runs=8, max_failures=4, topology=topology)
+        lowered = (sweep._renewal_mc_jit.lower(*args, stats=True, **kw)
+                   if program == "mc"
+                   else sweep._renewal_study_jit.lower(*args, **kw))
+        text = lowered.as_text(debug_info=True)
     # a scope is a component of an operation's name, perhaps inside a
     # transform's wrapper: "jit(f)/vmap(vmap(renewal_fold))/mul"
     for scope in SCOPES:

@@ -8,7 +8,8 @@ at the sizes ``chip_smoke.py`` runs:
 
   * the float32 renewal kernel at 6 scenarios x 4096 runs x 64 epochs, and
     at the advisor's policy grid (42 lanes x 128 runs x 32 epochs);
-  * the x64 scan engine at the same shape;
+  * the x64 scan engine at the same shape, and a study's program (either
+    engine's Monte-Carlo reduced over runs on the device);
   * the fleet core at a 64-cluster bucket;
   * the SSD kernel at mamba2-370m widths;
   * the flash-attention kernel at a GQA shape (8 query heads over 4 kv
@@ -97,6 +98,27 @@ def test_x64_scan_engine_compiles(one_chip):
             _abstract(failures.Exponential(7 * 24 * 3600.0), one_chip),
             n_runs=N_RUNS, max_failures=MAX_FAILURES, stats=True).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("engine", ["scan", "pallas"])
+def test_study_program_compiles(one_chip, engine, monkeypatch):
+    """A study's one program, Monte-Carlo and reduction over runs, for the
+    engine's stacked inputs: what leaves the chip holds no run axis.  The
+    kernel is compiled, not interpreted, as on the chip."""
+    monkeypatch.setattr(sweep, "_pallas_interpret", lambda: False)
+    process = failures.Exponential(7 * 24 * 3600.0)
+    with sweep._staged(_scenarios(), process, None, engine) as (stacked,
+                                                                proc):
+        makespan = (jnp.float32 if engine == "pallas" else jnp.float64)(
+            30 * 24 * 3600.0)
+        compiled = sweep._renewal_study_jit.lower(
+            _abstract(stacked, one_chip),
+            _abstract(jax.random.PRNGKey(0), one_chip),
+            _abstract(makespan, one_chip), _abstract(proc, one_chip),
+            n_runs=N_RUNS, max_failures=MAX_FAILURES,
+            engine=engine).compile()
+    assert compiled.memory_analysis().output_size_in_bytes < 16e3
+    assert ("tpu_custom_call" in compiled.as_text()) == (engine == "pallas")
 
 
 def test_fleet_core_compiles(one_chip):
