@@ -977,8 +977,37 @@ def _ordered_sum(x, axis=None):
     return x[..., 0]
 
 
+# Elements of a (lane, run, epoch, survivor) array that a stats-mode fold
+# may hold at once.  At 8-12 float64 arrays live in the fold this bounds its
+# temporaries to a few GB.  Both Table-4 studies (4.7M elements) fold their
+# stacked epochs whole; a whole machine's study (201M) folds inside the
+# epoch scan (``_survivor_tile``).
+_SURVIVOR_TILE_ELEMENTS = 1 << 25
+
+
+def _survivor_tile(n_lanes: int, n_runs: int, n_epochs: int,
+                   n_survivors: int) -> Optional[int]:
+    """How a stats-mode fold takes the survivor axis: None where the whole
+    ``n_lanes x n_runs x n_epochs x n_survivors`` grid fits
+    ``_SURVIVOR_TILE_ELEMENTS`` (one fold over the stacked epochs), else
+    the survivors of one tile of the fold inside the epoch scan: all of
+    them where lanes x runs x survivors fit, else as many as fit, in whole
+    multiples of 128 (the TPU's lane width) or, below that, of 8."""
+    lane_runs = n_lanes * n_runs
+    if lane_runs * n_epochs * n_survivors <= _SURVIVOR_TILE_ELEMENTS:
+        return None
+    tile = max(1, _SURVIVOR_TILE_ELEMENTS // lane_runs)
+    if tile >= n_survivors:
+        return n_survivors
+    for align in (128, 8):
+        if tile >= align:
+            return tile // align * align
+    return tile
+
+
 def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
-                  stats: bool = False, felled=None):
+                  stats: bool = False, felled=None,
+                  survivor_tile: Optional[int] = None):
     """Whole-run renewal recursion for ONE scenario x ONE run as a
     ``lax.scan`` over failure epochs.
 
@@ -1012,9 +1041,19 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     ``None`` (or an all-False mask — the formulas reduce through exact
     neutral elements) is the single-failure path, bit-identical to the
     pre-correlation engine.
+
+    ``survivor_tile`` (stats mode only; ``_survivor_tile`` chooses it from
+    the shapes) moves the fold into the scan's step: each epoch folds its
+    survivors in tiles of that many, and the scan stacks per-epoch scalars
+    only (occurrence, the re-execution race, ``P*``, the balanced-span
+    energy, the tiles' summed energies and action counts).  No (epoch,
+    survivor) array is then held.  The sums over survivors and epochs
+    change order, so energies agree with the stacked fold to float64
+    round-off; every count is the same.
     """
     n = inp.period.shape[0]
     n_nodes = n + 1
+    per_epoch = stats and survivor_tile is not None
     f8 = lambda x: jnp.asarray(x, jnp.float64)
     f4 = lambda x: jnp.asarray(x, jnp.float32)
     interval, dur = f8(inp.interval), f8(inp.dur)
@@ -1036,7 +1075,8 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # (span energies, trailing spans) is evaluated AFTER the scan over the
     # stacked (K, ...) epoch states, where XLA vectorizes it across the
     # whole epochs x runs x scenarios grid instead of re-issuing it inside
-    # a 32-step sequential loop.
+    # a 32-step sequential loop — unless that grid outgrows the fold's
+    # element budget, when the step folds its own epoch (``survivor_tile``).
     # None on the single-failure path: an all-False mask is a compile-time
     # constant that XLA folds through every reduction over it, which made
     # the TPU compile of the fleet core 141 s against 25 s without it, and
@@ -1070,50 +1110,21 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
             jnp.where(occurs, t_anchor + d_eff_fail + t_e + dur_fa, t_anchor),
             alive & occurs,
         )
+        if per_epoch:
+            e_bal = _ordered_sum(
+                work * p_comp0 + (d_eff_all - work) * p_ckpt0, axis=-1)
+            with jax.named_scope("survivor_tile"):
+                sums = fold_tiles(occurs, age_all[:-1], exec_rem,
+                                  t_dr + reexec, t_e, m)
+            return new_carry, (occurs, reexec, p_star, e_bal, sums)
         ys = (occurs, age_all, work, exec_rem, d_eff_all) + (
             () if stats else (jnp.where(occurs, t_anchor + d_eff_fail, 0.0),))
         return new_carry, ys
 
-    init = (jnp.concatenate([f8(inp.age0), f8(inp.reexec0)[None]]),
-            f8(inp.exec_rem0), f8(0.0), f8(0.0), jnp.asarray(True))
-    with jax.named_scope("renewal_scan"):
-        carry, ys = jax.lax.scan(step, init, (f8(gaps), m_all))
-    with jax.named_scope("renewal_fold"):
-        ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
-        (valid, age_all, work_all, exec_rem_k, d_eff_all), t_fail = \
-            ys[:5], (None if stats else ys[5])
-
-        # --- per-epoch accounting, vectorized over the stacked epochs ------
-        age_f = age_all[..., :-1]                                    # (K, N)
-        reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
-                                        exec_rem_k)                  # (K,)
-        d_eff_fail = d_eff_all[..., -1]
-        t_recover = t_dr + reexec_f                                  # (K,)
-        t_failed_k = t_recover[..., None] + exec_rem_k               # (K, N)
-        t_e = t_recover + p_star
-
-        # balanced span energy up to each node's (snapped) failure instant,
-        # plus the coordinated resync checkpoint closing each epoch.  At the
-        # snapped instant the span's checkpoint share is exactly the fired
-        # checkpoints, so ``work``/``d_eff - work`` from the scan's sawtooth
-        # *is* the ``balanced_span`` decomposition (both are exact multiples
-        # of ``dur`` — tests pin the identity) without recomputing it.
-        e_bal = _ordered_sum(
-            work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0, axis=-1)
-        balanced = _ordered_sum(jnp.where(
-            valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
-
-        # failed node over [failure, T_E]: down (0 W) + restart at P_ckpt +
-        # re-execution and post-recovery serving at P_comp.  Every felled slot
-        # plays the same closed-form role (identical in reference and
-        # intervened runs, so the saving is untouched); the factor is 1 for the
-        # single-failure path.
-        n_felled = 1.0 if m_all is None else 1.0 + jnp.sum(m_all, axis=-1)
-        epoch_failed = jnp.where(
-            valid,
-            n_felled * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0),
-            0.0)
-
+    def survivor_fold(exec_rem_k, age_f, t_failed_k, t_e, valid, m):
+        """The checkpoint plan, Algorithm 1 and the survivors' epoch
+        energies: over the stacked epochs (K, N), or over one epoch's tile
+        of survivors."""
         # per-level checkpoint plan as F separate node-batch columns: the fa
         # column comes from the shared checkpoint_plan (it also decides the
         # move-ahead), the others from the same closed form — no (..., F)
@@ -1146,11 +1157,102 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         # the survivor window energies (their Algorithm-1 point is
         # meaningless)
         v2 = (jnp.broadcast_to(valid[..., None], exec_rem_k.shape)
-              if m_all is None else valid[..., None] & ~m_all)
+              if m is None else valid[..., None] & ~m)
         epoch_ref = jnp.where(
             v2, f8(decision.energy_reference) + trail_ref, 0.0)
         epoch_int = jnp.where(
             v2, f8(decision.energy_intervened) + trail_int, 0.0)
+        return decision, v2, epoch_ref, epoch_int
+
+    def action_counts(decision, v2):
+        # integer action counts over valid (epoch, survivor) points — the
+        # summary rates divide by the point count on the host, so they
+        # match the oracle's np.mean over the same points exactly.
+        i32 = lambda m: jnp.sum((v2 & m).astype(jnp.int32))
+        return dict(
+            n_points=jnp.sum(v2.astype(jnp.int32)),
+            n_sleep=i32(decision.wait_action == em.WaitAction.SLEEP),
+            n_min_freq=i32(decision.wait_action == em.WaitAction.MIN_FREQ),
+            n_comp_changed=i32(decision.comp_changed),
+            n_infeasible=i32(~decision.feasible_any),
+        )
+
+    def fold_tiles(occurs, age, exec_rem, t_recover, t_e, m):
+        """One epoch's survivor fold, ``survivor_tile`` survivors at a time
+        (the last tile takes the rest): the epoch's summed energies and
+        action counts."""
+        sums = None
+        for lo in range(0, n, survivor_tile):
+            tile = slice(lo, lo + survivor_tile)
+            decision, v2, epoch_ref, epoch_int = survivor_fold(
+                exec_rem[tile], age[tile], t_recover + exec_rem[tile], t_e,
+                occurs, None if m is None else m[tile])
+            part = dict(epoch_ref=_ordered_sum(epoch_ref),
+                        epoch_int=_ordered_sum(epoch_int),
+                        **action_counts(decision, v2))
+            sums = part if sums is None else jax.tree.map(jnp.add, sums,
+                                                          part)
+        return sums
+
+    init = (jnp.concatenate([f8(inp.age0), f8(inp.reexec0)[None]]),
+            f8(inp.exec_rem0), f8(0.0), f8(0.0), jnp.asarray(True))
+    if per_epoch:
+        # the carry takes the run's axis from its first gap (a select of
+        # equal values, which the compiler drops), so that vmap batches the
+        # step, fold and all, in one pass rather than two
+        first = f8(gaps)[0] >= 0.0
+        init = jax.tree.map(lambda a: jnp.where(first, a, a), init)
+    with jax.named_scope("renewal_scan"):
+        carry, ys = jax.lax.scan(step, init, (f8(gaps), m_all))
+    with jax.named_scope("renewal_fold"):
+        ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        if per_epoch:
+            valid, reexec_f, p_star, e_bal, epoch_sums = ys
+            t_recover = t_dr + reexec_f                              # (K,)
+            t_e = t_recover + p_star
+        else:
+            (valid, age_all, work_all, exec_rem_k, d_eff_all), t_fail = \
+                ys[:5], (None if stats else ys[5])
+
+            # --- per-epoch accounting, vectorized over the stacked epochs --
+            age_f = age_all[..., :-1]                                # (K, N)
+            reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
+                                            exec_rem_k)              # (K,)
+            d_eff_fail = d_eff_all[..., -1]
+            t_recover = t_dr + reexec_f                              # (K,)
+            t_failed_k = t_recover[..., None] + exec_rem_k           # (K, N)
+            t_e = t_recover + p_star
+
+            # balanced span energy up to each node's (snapped) failure
+            # instant, plus the coordinated resync checkpoint closing each
+            # epoch.  At the snapped instant the span's checkpoint share is
+            # exactly the fired checkpoints, so ``work``/``d_eff - work``
+            # from the scan's sawtooth *is* the ``balanced_span``
+            # decomposition (both are exact multiples of ``dur`` — tests pin
+            # the identity) without recomputing it.
+            e_bal = _ordered_sum(
+                work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0,
+                axis=-1)
+        balanced = _ordered_sum(jnp.where(
+            valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
+
+        # failed node over [failure, T_E]: down (0 W) + restart at P_ckpt +
+        # re-execution and post-recovery serving at P_comp.  Every felled slot
+        # plays the same closed-form role (identical in reference and
+        # intervened runs, so the saving is untouched); the factor is 1 for the
+        # single-failure path.
+        n_felled = 1.0 if m_all is None else 1.0 + jnp.sum(m_all, axis=-1)
+        epoch_failed = jnp.where(
+            valid,
+            n_felled * (t_restart * p_ckpt0 + (reexec_f + p_star) * p_comp0),
+            0.0)
+
+        if per_epoch:
+            sums = {k: _ordered_sum(v) if v.dtype == jnp.float64
+                    else jnp.sum(v) for k, v in epoch_sums.items()}
+        else:
+            decision, v2, epoch_ref, epoch_int = survivor_fold(
+                exec_rem_k, age_f, t_failed_k, t_e, valid, m_all)
 
         # balanced tail: the rest of the failure-free work (mid-checkpoint
         # snaps can nudge bal_elapsed slightly past the makespan; clamp)
@@ -1159,8 +1261,12 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         balanced = balanced + _ordered_sum(w_t * p_comp0 + ck_t * p_ckpt0)
 
         e_failed = _ordered_sum(epoch_failed)
-        energy_ref = balanced + _ordered_sum(epoch_ref) + e_failed
-        energy_int = balanced + _ordered_sum(epoch_int) + e_failed
+        if per_epoch:
+            energy_ref = balanced + sums.pop("epoch_ref") + e_failed
+            energy_int = balanced + sums.pop("epoch_int") + e_failed
+        else:
+            energy_ref = balanced + _ordered_sum(epoch_ref) + e_failed
+            energy_int = balanced + _ordered_sum(epoch_int) + e_failed
         common = dict(
             valid=valid,
             n_failures=jnp.sum(valid.astype(jnp.int32)),
@@ -1171,19 +1277,10 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
             energy_int=energy_int,
             saving=energy_ref - energy_int,
         )
+        if per_epoch:
+            return dict(common, **sums)
         if stats:
-            # integer action counts over valid (epoch, survivor) points — the
-            # summary rates divide by the point count on the host, so they
-            # match the oracle's np.mean over the same points exactly.
-            i32 = lambda m: jnp.sum((v2 & m).astype(jnp.int32))
-            return dict(
-                common,
-                n_points=jnp.sum(v2.astype(jnp.int32)),
-                n_sleep=i32(decision.wait_action == em.WaitAction.SLEEP),
-                n_min_freq=i32(decision.wait_action == em.WaitAction.MIN_FREQ),
-                n_comp_changed=i32(decision.comp_changed),
-                n_infeasible=i32(~decision.feasible_any),
-            )
+            return dict(common, **action_counts(decision, v2))
         return dict(
             common,
             decision=decision,
@@ -1198,12 +1295,18 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
 
 
 def _renewal_device_core(inp: SweepInputs, gaps: jax.Array, makespan_s,
-                         stats: bool = False, felled=None):
+                         stats: bool = False, felled=None,
+                         survivor_tile: Optional[int] = None):
     """vmap the per-run scan over runs (gaps axis 0) and stacked scenarios
     (inputs axis 0): the whole epochs x runs x scenarios composition is one
     XLA program.  ``felled`` ((R, K, N) survivor-slot mask or None) rides
-    the run axis."""
-    scan = lambda i, g, m, f: _renewal_scan(i, g, m, stats=stats, felled=f)
+    the run axis.  In stats mode the shapes choose how the fold takes the
+    survivor axis (``_survivor_tile``), unless ``survivor_tile`` says."""
+    if stats and survivor_tile is None:
+        survivor_tile = _survivor_tile(inp.period.shape[0], *gaps.shape,
+                                       inp.period.shape[-1])
+    scan = lambda i, g, m, f: _renewal_scan(
+        i, g, m, stats=stats, felled=f, survivor_tile=survivor_tile)
     over_runs = jax.vmap(scan, in_axes=(None, 0, None, 0))
     return jax.vmap(over_runs, in_axes=(0, None, None, None))(
         inp, gaps, makespan_s, felled)
@@ -1231,7 +1334,7 @@ def _attach_failed_counts(out: dict, failed: jax.Array, n_nodes: int,
 
 def _renewal_mc_core(inp: SweepInputs, key: jax.Array, makespan_s, process,
                      n_runs: int, max_failures: int, stats: bool = False,
-                     topology=None):
+                     topology=None, survivor_tile: Optional[int] = None):
     """Fused Monte-Carlo entry: gap sampling (``renewal_failure_gaps``
     semantics — float32 draws and inverse-CDF transforms via
     ``failures.sample_renewal_gaps``, so histories are bit-identical to the
@@ -1251,7 +1354,7 @@ def _renewal_mc_core(inp: SweepInputs, key: jax.Array, makespan_s, process,
         felled = node_topology.survivor_slot_mask(fmask, failed)
     gaps = gaps32.astype(jnp.float64)
     out = _renewal_device_core(inp, gaps, makespan_s, stats=stats,
-                               felled=felled)
+                               felled=felled, survivor_tile=survivor_tile)
     if stats:
         out = _attach_failed_counts(out, failed, n_nodes, fmask=fmask)
     return out, gaps, failed
@@ -1519,25 +1622,29 @@ _study_reduce_jit = jax.jit(_study_reduce, static_argnames=("max_failures",))
 
 def _renewal_study_core(stacked: SweepInputs, key: jax.Array, makespan_s,
                         process, n_runs: int, max_failures: int,
-                        topology=None, engine: str = "scan"):
+                        topology=None, engine: str = "scan",
+                        survivor_tile: Optional[int] = None):
     """One study as one program: the engine's fused Monte-Carlo in stats
     mode (``_renewal_mc_core`` under x64, or ``_renewal_pallas_mc_core`` in
     float32), then ``_study_reduce``.  Only the reduction leaves the
     device, so the compiler drops what no summary reads: the scan's wall
-    clock (``end_time``) and the balanced energy."""
+    clock (``end_time``) and the balanced energy.  ``survivor_tile``
+    overrides how the scan engine's fold takes the survivor axis
+    (``_survivor_tile``), for tests."""
     if engine == "pallas":
         out = _renewal_pallas_mc_core(stacked, key, makespan_s, process,
                                       n_runs, max_failures, topology=topology)
     else:
         out, _, _ = _renewal_mc_core(stacked, key, makespan_s, process,
                                      n_runs, max_failures, stats=True,
-                                     topology=topology)
+                                     topology=topology,
+                                     survivor_tile=survivor_tile)
     return _study_reduce(out, max_failures)
 
 
 _renewal_study_jit = jax.jit(
     _renewal_study_core,
-    static_argnames=("n_runs", "max_failures", "engine"))
+    static_argnames=("n_runs", "max_failures", "engine", "survivor_tile"))
 
 
 def renewal_compose_policies(stacked: SweepInputs, gaps, makespan_s,
@@ -1882,7 +1989,14 @@ def _renewal_study_device(cfgs, key, *, n_runs: int, makespan_s: float,
     returns its reduction over runs, ``(totals, moments)``, on the
     device."""
     with _staged(cfgs, process, mtbf_s, engine) as (stacked, proc):
-        with jax.profiler.TraceAnnotation("sweep.dispatch"):
+        n_lanes, n = stacked.period.shape
+        tile = (None if engine == "pallas"
+                else _survivor_tile(n_lanes, n_runs, max_failures, n))
+        # a run's fold in one piece, or per epoch in tiles of survivors
+        tiles = 1 if tile is None else max_failures * -(-n // tile)
+        with jax.profiler.TraceAnnotation(
+                "sweep.dispatch", survivors=n, survivor_tile=tile or n,
+                tiles=tiles):
             makespan = (jnp.float32(makespan_s) if engine == "pallas"
                         else float(makespan_s))
             return _renewal_study_jit(

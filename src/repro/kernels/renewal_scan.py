@@ -110,6 +110,12 @@ STAT_FIELDS = (
     ("n_infeasible", jnp.int32),
 )
 
+# VMEM the double-buffered felled block (K, N, block_r) float32 may take.
+# Ahead-of-time compiles for a TPU v5e at 32-128 epochs pass at 12 MiB and
+# run out of VMEM at 14 MiB and above (the survivors' (N, block_r) state and
+# fold take the rest), so a block past this is refused before compiling.
+FELLED_BLOCK_VMEM_BYTES = 12 << 20
+
 
 def _kadd(s, c, x, compensated: bool):
     """One compensated-summation step: add ``x`` into the Kahan pair
@@ -371,6 +377,14 @@ def renewal_scan_pallas(params, nodes, ladder, gaps, felled=None, *,
         felled = jnp.asarray(felled, jnp.float32)
 
     rb = block_r or (n_runs if n_runs <= 128 else 128)
+    block_bytes = 2 * n_epochs * (-(-n // 8) * 8) * rb * 4
+    if block_bytes > FELLED_BLOCK_VMEM_BYTES:
+        raise ValueError(
+            f"the renewal kernel's blocks of {n} survivors x {rb} runs over "
+            f"{n_epochs} epochs need {block_bytes} B of VMEM for the felled "
+            f"mask alone, over the {FELLED_BLOCK_VMEM_BYTES} B it may take; "
+            "compose this many survivors with the scan engine "
+            "(engine='scan'), whose fold tiles the survivor axis")
     r_pad = -(-n_runs // rb) * rb
     if r_pad != n_runs:
         # inf gap sentinel: occurs is False from epoch 0 on padded lanes and

@@ -9,7 +9,9 @@ at the sizes ``chip_smoke.py`` runs:
   * the float32 renewal kernel at 6 scenarios x 4096 runs x 64 epochs, and
     at the advisor's policy grid (42 lanes x 128 runs x 32 epochs);
   * the x64 scan engine at the same shape, and a study's program (either
-    engine's Monte-Carlo reduced over runs on the device);
+    engine's Monte-Carlo reduced over runs on the device), also at a
+    whole-machine job's shape (1,023 survivors, folded inside the epoch
+    scan);
   * the fleet core at a 64-cluster bucket;
   * the SSD kernel at mamba2-370m widths;
   * the flash-attention kernel at a GQA shape (8 query heads over 4 kv
@@ -20,6 +22,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file).  The persistent compilation cache is off around these
 compiles, since an entry written for a described chip cannot be read back.
 """
+import dataclasses
 import functools
 
 import jax
@@ -31,6 +34,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import fleet
 from repro.core import failures, optimize, sweep
 from repro.core.scenarios import paper_scenarios
+from repro.core.simulator import NodeStart
 
 N_RUNS, MAX_FAILURES = 4096, 64
 
@@ -119,6 +123,26 @@ def test_study_program_compiles(one_chip, engine, monkeypatch):
             engine=engine).compile()
     assert compiled.memory_analysis().output_size_in_bytes < 16e3
     assert ("tpu_custom_call" in compiled.as_text()) == (engine == "pallas")
+
+
+def test_whole_machine_study_compiles_in_tiles(one_chip):
+    """The study of one job over a 1,024-node machine (1,023 survivors,
+    4,096 runs x 48 epochs, Weibull nodes): folded inside the epoch scan,
+    one epoch's survivors at a time, its temporaries fit half the chip;
+    folded over the stacked epochs they need 18.3 GB."""
+    cfg = dataclasses.replace(
+        paper_scenarios()["scenario1_short_reexec"], t_reexec=0.0,
+        survivors=tuple(NodeStart(3600.0 * (i + 1) / 1024, 3600.0, 0.0)
+                        for i in range(1023)))
+    process = failures.Weibull.from_mtbf(0.7, 365 * 24 * 3600.0)
+    with sweep._staged([cfg], process, None, "scan") as (stacked, proc):
+        compiled = sweep._renewal_study_jit.lower(
+            _abstract(stacked, one_chip),
+            _abstract(jax.random.PRNGKey(0), one_chip),
+            _abstract(jnp.float64(24 * 3600.0), one_chip),
+            _abstract(proc, one_chip), n_runs=N_RUNS, max_failures=48,
+            engine="scan").compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
 
 
 def test_fleet_core_compiles(one_chip):
