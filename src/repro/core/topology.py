@@ -181,14 +181,16 @@ def rack_topology(n_nodes: int, rack_size: int, *, shock_mtbs_s,
                       age_boost_s=age_boost_s),))
 
 
-def _member_matrix(topo: Topology) -> np.ndarray:
-    """Static (G_total, n_nodes) bool membership over all levels' groups,
-    levels concatenated in order."""
-    rows = []
+def _group_ids(topo: Topology) -> np.ndarray:
+    """Static (L, n_nodes) int32 table: row ``l`` holds each node's group
+    at level ``l`` as a global group id (levels' groups concatenated in
+    order, so a global id names one level's group).  Node ``n`` is a member
+    of global group ``g`` iff some row holds ``g`` at ``n``."""
+    rows, offset = [], 0
     for lv in topo.levels:
-        g = np.asarray(lv.group_of)
-        rows.append(np.arange(lv.n_groups)[:, None] == g[None, :])
-    return np.concatenate(rows, axis=0)
+        rows.append(offset + np.asarray(lv.group_of, np.int32))
+        offset += lv.n_groups
+    return np.stack(rows)
 
 
 def _group_params(topo: Topology):
@@ -232,9 +234,10 @@ def sample_correlated_renewal_gaps(
         if topology.n_nodes != n_nodes:
             raise ValueError(f"topology has {topology.n_nodes} nodes, "
                              f"sampler asked for {n_nodes}")
-        member = jnp.asarray(_member_matrix(topology))        # (G, N) bool
+        gid = jnp.asarray(_group_ids(topology))               # (L, N) int32
         mtbs, pkill, boost = _group_params(topology)          # (G,) each
-        n_groups = member.shape[0]
+        n_groups = mtbs.shape[0]
+        group_ids = jnp.arange(n_groups)
         k_res, k_shock, k_kill = jax.random.split(key, 3)
         v = jax.random.uniform(
             k_res, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
@@ -256,8 +259,15 @@ def sample_correlated_renewal_gaps(
             # ties -> individual
             shock = gap_shk < gap_ind
             gap = jnp.where(shock, gap_shk, gap_ind)
-            member_g = member[g_shk]                          # (R, N)
-            killed = member_g & (w_k < pkill[g_shk][:, None])
+            # the struck group's members and parameters by compare-and-select
+            # over the static level and group axes: a gather with computed
+            # indices runs element by element on the TPU
+            member_g = jnp.any(gid == g_shk[:, None, None], axis=1)  # (R, N)
+            struck = group_ids == g_shk[:, None]              # (R, G)
+            # one term of each sum is nonzero, so both are exact
+            pkill_g = jnp.where(struck, pkill, 0.0).sum(-1)
+            boost_g = jnp.where(struck, boost, 0.0).sum(-1)
+            killed = member_g & (w_k < pkill_g[:, None])
             # condition on >= 1 kill: the member with the smallest kill draw
             # falls even when every Bernoulli spares (the epoch grammar needs a
             # failure; the bias is documented and vanishes as p_kill -> 1)
@@ -274,7 +284,7 @@ def sample_correlated_renewal_gaps(
             ages = jnp.where(
                 mask, 0.0,
                 ages + gap[:, None]
-                + jnp.where(spared, boost[g_shk][:, None], 0.0))
+                + jnp.where(spared, boost_g[:, None], 0.0))
             return ages, (gap, mask, primary)
 
         init = jnp.zeros((n_runs, n_nodes), jnp.float32)
@@ -313,15 +323,16 @@ def survivor_slot_mask(failed_mask, primary):
     The renewal engines describe an epoch as one primary failed node (the
     re-execution role) plus ``n_nodes - 1`` survivor slots; slot ``i``
     is physical node ``i + (i >= primary)`` (the nodes in order, skipping
-    the primary).  Works on numpy and traced jnp arrays; shapes
-    ``(..., N) -> (..., N - 1)`` with ``primary`` shaped ``(...)``.
+    the primary), so it is a select between the mask shifted by one and
+    the mask itself: two static slices, no gather.  Works on numpy and
+    traced jnp arrays; shapes ``(..., N) -> (..., N - 1)`` with
+    ``primary`` shaped ``(...)``.
     """
     with jax.named_scope("renewal_sample"):
         xp = _ns(failed_mask)
-        n = failed_mask.shape[-1]
-        idx = xp.arange(n - 1)
-        phys = idx + (idx >= primary[..., None])
-        return xp.take_along_axis(failed_mask, phys, axis=-1)
+        idx = xp.arange(failed_mask.shape[-1] - 1)
+        return xp.where(idx >= primary[..., None],
+                        failed_mask[..., 1:], failed_mask[..., :-1])
 
 
 # ---------------------------------------------------------------------------

@@ -186,6 +186,155 @@ def test_simulator_topology_sampling_path():
 
 
 # ---------------------------------------------------------------------------
+# the sampler's lookups: compare-and-select, pinned to the gather form
+# ---------------------------------------------------------------------------
+
+def _slot_mask_by_gather(failed_mask, primary):
+    # the reference formulation: slot i is node i + (i >= primary), picked
+    # with take_along_axis
+    xp = jnp if isinstance(failed_mask, jax.Array) else np
+    idx = xp.arange(failed_mask.shape[-1] - 1)
+    phys = idx + (idx >= primary[..., None])
+    return xp.take_along_axis(failed_mask, phys, axis=-1)
+
+
+def _sample_by_gather(topology, process, key, n_runs, max_failures,
+                      n_nodes):
+    # the correlated sampler with its rack lookups as gathers: a (G, N)
+    # membership matrix and the per-group parameters indexed by the struck
+    # group; draws, argmins and ties as in ``sample_correlated_renewal_gaps``
+    member = jnp.asarray(np.concatenate(
+        [np.arange(lv.n_groups)[:, None] == np.asarray(lv.group_of)[None, :]
+         for lv in topology.levels]))
+    mtbs, pkill, boost = nt._group_params(topology)
+    n_groups = member.shape[0]
+    k_res, k_shock, k_kill = jax.random.split(key, 3)
+    v = jax.random.uniform(
+        k_res, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
+    w = jax.random.uniform(
+        k_kill, (max_failures, n_runs, n_nodes), dtype=jnp.float32)
+    su = jax.random.uniform(
+        k_shock, (max_failures, n_runs, n_groups), dtype=jnp.float32)
+    node_ids = jnp.arange(n_nodes)
+
+    def step(ages, xs):
+        v_k, w_k, su_k = xs
+        t = process.residual(v_k, ages)
+        gap_ind = jnp.min(t, axis=-1)
+        i_ind = jnp.argmin(t, axis=-1)
+        s_times = mtbs * (-jnp.log1p(-su_k))
+        gap_shk = jnp.min(s_times, axis=-1)
+        g_shk = jnp.argmin(s_times, axis=-1)
+        shock = gap_shk < gap_ind
+        gap = jnp.where(shock, gap_shk, gap_ind)
+        member_g = member[g_shk]
+        killed = member_g & (w_k < pkill[g_shk][:, None])
+        w_m = jnp.where(member_g, w_k, jnp.inf)
+        forced = node_ids == jnp.argmin(w_m, axis=-1)[:, None]
+        killed = jnp.where(jnp.any(killed, axis=-1, keepdims=True),
+                           killed, forced)
+        mask = jnp.where(shock[:, None], killed, node_ids == i_ind[:, None])
+        primary = jnp.where(
+            shock, jnp.argmin(jnp.where(killed, w_k, jnp.inf), axis=-1),
+            i_ind).astype(jnp.int32)
+        spared = shock[:, None] & member_g & ~killed
+        ages = jnp.where(
+            mask, 0.0,
+            ages + gap[:, None]
+            + jnp.where(spared, boost[g_shk][:, None], 0.0))
+        return ages, (gap, mask, primary)
+
+    init = jnp.zeros((n_runs, n_nodes), jnp.float32)
+    _, (gaps, mask, primary) = jax.lax.scan(step, init, (v, w, su))
+    return (jnp.transpose(gaps), jnp.transpose(mask, (1, 0, 2)),
+            jnp.transpose(primary))
+
+
+def _rack_psu_topology(n_nodes):
+    # racks of 2 under PSUs of 4: two levels, so the sampler's (L, N) table
+    # of global group ids has two rows and a PSU shock can fell nodes of
+    # two racks at once
+    return nt.Topology(n_nodes=n_nodes, levels=(
+        nt.TopologyLevel(name="rack", group_of=[i // 2 for i in range(n_nodes)],
+                         shock_mtbs_s=6 * 24 * 3600.0, p_kill=0.7,
+                         age_boost_s=1800.0),
+        nt.TopologyLevel(name="psu", group_of=[i // 4 for i in range(n_nodes)],
+                         shock_mtbs_s=[4 * 24 * 3600.0, 9 * 24 * 3600.0],
+                         p_kill=[0.5, 0.8], age_boost_s=[3600.0, 600.0]),
+    ))
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jnp"])
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("n_nodes", [2, 4, 1024])
+def test_survivor_slot_mask_matches_gather(n_nodes, where, xp):
+    primary_at = {"first": 0, "middle": n_nodes // 2,
+                  "last": n_nodes - 1}[where]
+    rng = np.random.default_rng(n_nodes)
+    fmask = rng.random((3, 5, n_nodes)) < 0.5
+    primary = np.full((3, 5), primary_at, np.int32)
+    # a row of mixed primaries besides the pinned ones
+    primary[0] = rng.integers(0, n_nodes, size=5)
+    fmask, primary = xp.asarray(fmask), xp.asarray(primary)
+    got = nt.survivor_slot_mask(fmask, primary)
+    assert type(got) is type(fmask)
+    assert got.shape == (3, 5, n_nodes - 1) and got.dtype == fmask.dtype
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_slot_mask_by_gather(fmask,
+                                                                  primary)))
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "x64"])
+@pytest.mark.parametrize("topo_name", ["rack", "rack_psu", "aggressive"])
+def test_correlated_sampler_bit_identical_to_gather_form(topo_name, x64):
+    n_nodes = {"rack": 4, "rack_psu": 8, "aggressive": 4}[topo_name]
+    topo = {"rack": lambda n: nt.rack_topology(
+                n, 3, shock_mtbs_s=10 * 24 * 3600.0, p_kill=0.6,
+                age_boost_s=3600.0),
+            "rack_psu": _rack_psu_topology,
+            "aggressive": _aggressive_topology}[topo_name](n_nodes)
+    proc = failures.Weibull.from_mtbf(0.7, MTBF_S)
+    shape = dict(n_runs=64, max_failures=24, n_nodes=n_nodes)
+    with jax.enable_x64(x64):
+        got = jax.jit(nt.sample_correlated_renewal_gaps,
+                      static_argnames=tuple(shape))(topo, proc, KEY, **shape)
+        ref = jax.jit(_sample_by_gather,
+                      static_argnames=tuple(shape))(topo, proc, KEY, **shape)
+    for a, b in zip(got, ref):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    fmask = np.asarray(got[1])
+    # shocks fired and spared members, so every branch of the step ran
+    assert int(np.sum(fmask.sum(-1) > 1)) > 0
+    assert int(np.sum(fmask.sum(-1) < n_nodes)) > 0
+    if topo_name == "rack_psu":
+        # a PSU shock felled nodes of two racks in one epoch
+        racks = fmask.reshape(fmask.shape[:2] + (n_nodes // 2, 2)).any(-1)
+        assert int(np.sum(racks.sum(-1) > 1)) > 0
+
+
+def test_correlated_sampler_lowers_without_gather():
+    # the rack cell's sampler shape: 4096 runs x 64 epochs x 4 nodes in
+    # racks of 3, traced as the study traces it (x64 on)
+    proc = failures.Weibull.from_mtbf(0.7, MTBF_S)
+    topo = nt.rack_topology(4, 3, shock_mtbs_s=10 * 24 * 3600.0,
+                            p_kill=0.6, age_boost_s=3600.0)
+
+    def lowered(sample, slots):
+        def fn(topo, proc, key):
+            gaps, fmask, primary = sample(topo, proc, key, 4096, 64, 4)
+            return gaps, slots(fmask, primary)
+        with jax.enable_x64(True):
+            return jax.jit(fn).lower(topo, proc, KEY).as_text()
+
+    assert "stablehlo.gather" not in lowered(
+        nt.sample_correlated_renewal_gaps, nt.survivor_slot_mask)
+    # the check sees a gather where one is: the reference form has four
+    assert lowered(_sample_by_gather,
+                   _slot_mask_by_gather).count('"stablehlo.gather"') == 4
+
+
+# ---------------------------------------------------------------------------
 # trace ingestion
 # ---------------------------------------------------------------------------
 
