@@ -9,7 +9,7 @@ The runner turns a validated ``CampaignSpec`` into stored results:
    program: same survivor count, ladder size, blocking topology, failure
    process, (n_runs, max_failures), and seed.  Within a group, arbitrary
    scenario/policy variation rides the *policy axis* of the fused engine:
-   ``sweep._renewal_policy_core`` vmaps over the full ``SweepInputs``
+   ``sweep._renewal_lanes`` vmaps over the full ``SweepInputs``
    pytree with a per-lane makespan, so heterogeneous resolved configs
    stack as lanes of ONE ``sweep.renewal_monte_carlo_policies`` dispatch.
 3. **Chunk to a memory budget** — lanes multiply the scan's working set

@@ -44,23 +44,32 @@ Algorithm-1 dispatch across every (run, epoch, survivor) point.
 Cross-validated pointwise against ``simulator.simulate_run`` in
 tests/test_renewal.py; semantics in docs/sweep.md.
 
-The renewal composition comes in two implementations:
+The renewal composition comes in three engines (``engine=``):
 
-  * ``renewal_compose`` — the float64 *host oracle*: a Python loop over
-    failure epochs (numpy geometry) plus one jitted Algorithm-1 dispatch.
-    Slow but transparent; the cross-validation anchor.
-  * ``renewal_compose_device`` / ``renewal_monte_carlo_device`` — the
-    *device engine*: the same recursion as a ``jax.lax.scan`` over epochs
-    whose carry is the re-anchored state, ``vmap``ped over runs and over
-    stacked Table-4 scenarios, fused with the Algorithm-1 dispatch, the
-    balanced-span energy, the trailing-span accounting, and (in the
-    ``_device`` Monte-Carlo entry) the on-device gap sampling into **one
-    jitted program** — no per-epoch host round-trips, no per-scenario
-    re-dispatch.  Geometry is traced under ``jax.enable_x64``
-    so wall-clock times stay float64-exact against the oracle while the
-    Algorithm-1 energy math stays float32, exactly as on the host path.
-    ``tests/test_renewal_device.py`` pins the two paths together at
-    <= 1e-4 relative (observed ~1e-9) on whole-run energies.
+  * ``"host"`` (``renewal_compose``, and ``renewal_monte_carlo`` only) —
+    the float64 *host oracle*: a Python loop over failure epochs (numpy
+    geometry) plus one jitted Algorithm-1 dispatch.  Slow but
+    transparent; the cross-validation anchor.
+  * ``"scan"`` (the default of every device entry) — the same recursion
+    as a ``jax.lax.scan`` over epochs whose carry is the re-anchored
+    state, ``vmap``ped over runs and over stacked lanes (scenarios or
+    policies, one lane core: ``_renewal_lanes``), fused with the
+    Algorithm-1 dispatch, the balanced-span energy, the trailing-span
+    accounting, and in the Monte-Carlo entries the on-device gap sampling
+    (one sampler: ``_sample_histories``) into **one jitted program** — no
+    per-epoch host round-trips, no per-lane re-dispatch.  Geometry is
+    traced under ``jax.enable_x64`` so wall-clock times stay float64-exact
+    against the oracle while the Algorithm-1 energy math stays float32,
+    exactly as on the host path.  ``tests/test_renewal_device.py`` pins
+    the two paths together at <= 1e-4 relative (observed ~1e-9) on
+    whole-run energies.
+  * ``"pallas"`` — the float32 Kahan-ledger kernel
+    (``kernels.renewal_scan``) behind the same sampler, stats only.
+
+A study (``renewal_monte_carlo_scenarios``, and ``renewal_monte_carlo`` of
+one scenario) reduces over runs inside its program; the per-run entries
+(``renewal_monte_carlo_device``, ``renewal_monte_carlo_policies``) share
+one staged dispatch (``_renewal_mc_runs``).
 
 Semantics notes (also in docs/sweep.md):
   * failure instants landing inside a node's checkpoint snap forward to the
@@ -1023,7 +1032,7 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     makespan and would lose ~0.5 s to float32 over month-long runs, while
     Algorithm 1 is dispatched on float32 casts of the float64 geometry —
     the very same values the host oracle feeds it.
-    ``_renewal_device_core`` vmaps this over runs and stacked scenarios.
+    ``_renewal_lanes`` vmaps this over runs and stacked lanes.
 
     ``stats=True`` is the hot-path mode: per-epoch diagnostic arrays are
     never materialized; only whole-run energies and integer action counts
@@ -1294,32 +1303,53 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         )
 
 
-def _renewal_device_core(inp: SweepInputs, gaps: jax.Array, makespan_s,
-                         stats: bool = False, felled=None,
-                         survivor_tile: Optional[int] = None):
-    """vmap the per-run scan over runs (gaps axis 0) and stacked scenarios
-    (inputs axis 0): the whole epochs x runs x scenarios composition is one
-    XLA program.  ``felled`` ((R, K, N) survivor-slot mask or None) rides
-    the run axis.  In stats mode the shapes choose how the fold takes the
-    survivor axis (``_survivor_tile``), unless ``survivor_tile`` says."""
+def _renewal_lanes(inp: SweepInputs, gaps: jax.Array, makespan_s,
+                   stats: bool = False, felled=None,
+                   survivor_tile: Optional[int] = None):
+    """vmap the per-run scan over runs (gaps axis 0) and stacked lanes
+    (inputs axis 0), scenarios or the policies of one scenario
+    (``core.optimize.policy_inputs``): one XLA program.  ``makespan_s`` is
+    one number for every lane or (L,), one a lane (per-policy wall
+    makespans, ``core.optimize.wall_makespan``).  ``gaps`` and ``felled``
+    ((R, K, N) survivor-slot mask or None) ride the run axis, shared by
+    every lane (common random numbers: a lane equals the same lane
+    composed alone, tests/test_optimize.py).  In stats mode the shapes
+    choose how the fold takes the survivor axis (``_survivor_tile``),
+    unless ``survivor_tile`` says."""
     if stats and survivor_tile is None:
         survivor_tile = _survivor_tile(inp.period.shape[0], *gaps.shape,
                                        inp.period.shape[-1])
     scan = lambda i, g, m, f: _renewal_scan(
         i, g, m, stats=stats, felled=f, survivor_tile=survivor_tile)
     over_runs = jax.vmap(scan, in_axes=(None, 0, None, 0))
-    return jax.vmap(over_runs, in_axes=(0, None, None, None))(
+    per_lane = 0 if jnp.ndim(makespan_s) else None
+    return jax.vmap(over_runs, in_axes=(0, None, per_lane, None))(
         inp, gaps, makespan_s, felled)
+
+
+def _sample_histories(process, key: jax.Array, n_runs: int,
+                      max_failures: int, n_nodes: int, topology=None):
+    """The device sampler of every Monte-Carlo core: the float32 gaps
+    (R, K), the failed node, and with a ``core.topology.Topology`` the
+    correlated shock scan's felled survivor slots (R, K, N) and physical
+    nodes (R, K, n_nodes), else None.  Histories are bit-identical to
+    ``renewal_failure_gaps``'s at the same key, with or without x64."""
+    if topology is None:
+        gaps32, failed = failures.sample_renewal_gaps(
+            process, key, n_runs, max_failures, n_nodes)
+        return gaps32, failed, None, None
+    gaps32, fmask, failed = node_topology.sample_correlated_renewal_gaps(
+        topology, process, key, n_runs, max_failures, n_nodes)
+    return (gaps32, failed, node_topology.survivor_slot_mask(fmask, failed),
+            fmask)
 
 
 def _attach_failed_counts(out: dict, failed: jax.Array, n_nodes: int,
                           fmask=None) -> dict:
-    """stats-mode epilogue shared by the scenario- and policy-stacked MC
-    cores: per-node failure counts over valid epochs, reduced over runs.
-    ``out['valid']`` is (S|P, R, K); the leading axis broadcasts the same
-    way for scenario and policy stacks.  With a correlated sampler's
-    physical-node ``fmask`` ((R, K, n_nodes)) every felled node counts, not
-    just the primary."""
+    """stats-mode epilogue of the Monte-Carlo cores: per-node failure
+    counts over valid epochs (``out['valid']``: (L, R, K)), reduced over
+    runs.  With a correlated sampler's physical-node ``fmask``
+    ((R, K, n_nodes)) every felled node counts, not just the primary."""
     with jax.named_scope("renewal_fold"):
         valid = out.pop("valid")
         if fmask is None:
@@ -1335,116 +1365,43 @@ def _attach_failed_counts(out: dict, failed: jax.Array, n_nodes: int,
 def _renewal_mc_core(inp: SweepInputs, key: jax.Array, makespan_s, process,
                      n_runs: int, max_failures: int, stats: bool = False,
                      topology=None, survivor_tile: Optional[int] = None):
-    """Fused Monte-Carlo entry: gap sampling (``renewal_failure_gaps``
-    semantics — float32 draws and inverse-CDF transforms via
-    ``failures.sample_renewal_gaps``, so histories are bit-identical to the
-    host sampler; non-exponential processes run the conditional-residual
-    scan) + the full composition, one jitted program.  With a
-    ``core.topology.Topology`` the sampler is the correlated shock scan
-    (``topology.sample_correlated_renewal_gaps`` — same bit-identity
-    contract) and the felled slots thread into the composition."""
+    """Fused Monte-Carlo of stacked lanes, scenarios or policies: ONE
+    sampling pass (``_sample_histories``) that never sees the lane axis,
+    so the histories cannot depend on the knobs being tuned, then the
+    composition (``_renewal_lanes``), one jitted program."""
     n_nodes = inp.period.shape[-1] + 1
-    if topology is None:
-        gaps32, failed = failures.sample_renewal_gaps(
-            process, key, n_runs, max_failures, n_nodes)
-        felled = fmask = None
-    else:
-        gaps32, fmask, failed = node_topology.sample_correlated_renewal_gaps(
-            topology, process, key, n_runs, max_failures, n_nodes)
-        felled = node_topology.survivor_slot_mask(fmask, failed)
+    gaps32, failed, felled, fmask = _sample_histories(
+        process, key, n_runs, max_failures, n_nodes, topology)
     gaps = gaps32.astype(jnp.float64)
-    out = _renewal_device_core(inp, gaps, makespan_s, stats=stats,
-                               felled=felled, survivor_tile=survivor_tile)
+    out = _renewal_lanes(inp, gaps, makespan_s, stats=stats, felled=felled,
+                         survivor_tile=survivor_tile)
     if stats:
         out = _attach_failed_counts(out, failed, n_nodes, fmask=fmask)
     return out, gaps, failed
 
 
-def _renewal_policy_core(inp: SweepInputs, gaps: jax.Array, makespan_s,
-                         stats: bool = False, felled=None):
-    """The policy-axis analog of ``_renewal_device_core``: vmap the per-run
-    scan over runs and over a *policy-stacked* ``SweepInputs`` whose leading
-    axis varies the knobs (``interval``, ``mu1``, ``mu2``, ``wait_mode``,
-    ``move_frac``, ...) of ONE scenario, with a per-policy ``makespan_s``
-    (axis 0) so checkpoint intervals compare at equal useful *work* rather
-    than equal wall time (``core.optimize.wall_makespan``).  ``gaps`` stays
-    unbatched — every policy lane sees the *same* failure histories (common
-    random numbers), so cross-policy differences carry no sampling variance
-    and per-policy outputs are bit-identical to a standalone
-    ``_renewal_device_core`` call on that policy alone (tests/test_optimize.py
-    pins this)."""
-    scan = lambda i, g, m, f: _renewal_scan(i, g, m, stats=stats, felled=f)
-    over_runs = jax.vmap(scan, in_axes=(None, 0, None, 0))
-    return jax.vmap(over_runs, in_axes=(0, None, 0, None))(
-        inp, gaps, makespan_s, felled)
-
-
-def _renewal_policy_mc_core(inp: SweepInputs, key: jax.Array, makespan_s,
-                            process, n_runs: int, max_failures: int,
-                            stats: bool = False, topology=None):
-    """Fused policy-grid Monte-Carlo: ONE gap-sampling pass (identical to
-    ``_renewal_mc_core``'s — same key, same draws) shared across every
-    policy lane, then the policy-vmapped composition.  This is the common-
-    random-numbers plumbing: the sampler never sees the policy axis, so the
-    histories cannot depend on the knobs being tuned.  A
-    ``core.topology.Topology`` swaps in the correlated shock sampler; the
-    shared histories (and felled masks) stay policy-independent."""
-    n_nodes = inp.period.shape[-1] + 1
-    if topology is None:
-        gaps32, failed = failures.sample_renewal_gaps(
-            process, key, n_runs, max_failures, n_nodes)
-        felled = fmask = None
-    else:
-        gaps32, fmask, failed = node_topology.sample_correlated_renewal_gaps(
-            topology, process, key, n_runs, max_failures, n_nodes)
-        felled = node_topology.survivor_slot_mask(fmask, failed)
-    gaps = gaps32.astype(jnp.float64)
-    out = _renewal_policy_core(inp, gaps, makespan_s, stats=stats,
-                               felled=felled)
-    if stats:
-        out = _attach_failed_counts(out, failed, n_nodes, fmask=fmask)
-    return out, gaps, failed
-
-
-_renewal_device_jit = jax.jit(
-    _renewal_device_core, static_argnames=("stats",))
-_renewal_mc_jit = jax.jit(
-    _renewal_mc_core, static_argnames=("n_runs", "max_failures", "stats"))
 def _renewal_fleet_mc_core(inp: SweepInputs, key: jax.Array, makespan_s,
                            process, n_runs: int, max_failures: int):
-    """The cluster-axis analog of ``_renewal_policy_mc_core``: ``inp``
-    carries leading ``(C, P)`` axes (clusters x policies — build with
-    ``core.optimize.fleet_policy_inputs``), ``makespan_s`` is ``(C, P)``,
-    and ``process`` is a same-family stack with leading ``(C,)`` parameter
-    leaves (``failures.stack_processes``).
-
-    Each cluster lane re-samples its OWN failure histories at the SAME key
-    through its own process parameters — exactly the draws a standalone
-    ``_renewal_policy_mc_core`` call on that cluster would make — then runs
-    the policy-vmapped composition on them.  That is the fleet CRN
-    contract: per-cluster rows of the fused dispatch are bit-identical to
-    standalone per-cluster calls at the same key, so fleet answers are
-    independent of which other clusters share the batch and batch padding
-    is provably inert (tests/test_fleet.py pins both).  Stats-only: this
-    is the advisory hot path, and the per-epoch diagnostic view belongs to
-    the single-cluster engines it cross-validates against.
-    """
-    n_nodes = inp.period.shape[-1] + 1
-
+    """``_renewal_mc_core`` in stats mode vmapped over clusters: ``inp``
+    carries leading ``(C, P)`` axes (``core.optimize.fleet_policy_inputs``),
+    ``makespan_s`` is ``(C, P)`` and ``process`` a same-family stack with
+    leading ``(C,)`` leaves (``failures.stack_processes``).  Each cluster
+    samples its OWN histories at the SAME key, exactly as a standalone
+    policy-stacked call on it would (its shapes choose its survivor
+    tiles), so per-cluster rows are bit-identical to standalone calls and
+    batch padding is inert (tests/test_fleet.py pins both)."""
     def one_cluster(inp_c, makespan_c, proc_c):
-        gaps32, failed = failures.sample_renewal_gaps(
-            proc_c, key, n_runs, max_failures, n_nodes)
-        out = _renewal_policy_core(inp_c, gaps32.astype(jnp.float64),
-                                   makespan_c, stats=True, felled=None)
-        return _attach_failed_counts(out, failed, n_nodes)
+        out, _, _ = _renewal_mc_core(inp_c, key, makespan_c, proc_c, n_runs,
+                                     max_failures, stats=True)
+        return out
 
     return jax.vmap(one_cluster)(inp, makespan_s, process)
 
 
-_renewal_policy_jit = jax.jit(
-    _renewal_policy_core, static_argnames=("stats",))
-_renewal_policy_mc_jit = jax.jit(
-    _renewal_policy_mc_core, static_argnames=("n_runs", "max_failures", "stats"))
+_renewal_lanes_jit = jax.jit(
+    _renewal_lanes, static_argnames=("stats", "survivor_tile"))
+_renewal_mc_jit = jax.jit(
+    _renewal_mc_core, static_argnames=("n_runs", "max_failures", "stats"))
 _renewal_fleet_mc_jit = jax.jit(
     _renewal_fleet_mc_core, static_argnames=("n_runs", "max_failures"))
 
@@ -1492,22 +1449,16 @@ def _renewal_pallas_mc_core(stacked: SweepInputs, key: jax.Array, makespan_s,
                             process, n_runs: int, max_failures: int,
                             topology=None, compensated: bool = True):
     """Fused Monte-Carlo through the Pallas kernel: the SAME gap sampler as
-    the x64 scan engine (``failures.sample_renewal_gaps`` draws identical
-    float32 bits with or without x64 — the CRN contract carries over
-    unchanged), then the packed f32 composition.  ``makespan_s`` is per
-    lane, so one core serves both the scenario stack (scalar broadcast) and
-    the policy stack (per-policy wall makespans)."""
+    the x64 scan engine (``_sample_histories`` draws identical float32 bits
+    with or without x64 — the CRN contract carries over unchanged), then
+    the packed f32 composition.  ``makespan_s`` is per lane, so one core
+    serves both the scenario stack (scalar broadcast) and the policy stack
+    (per-policy wall makespans)."""
     from repro.kernels import renewal_scan as _rs
 
     n_nodes = stacked.period.shape[-1] + 1
-    if topology is None:
-        gaps32, failed = failures.sample_renewal_gaps(
-            process, key, n_runs, max_failures, n_nodes)
-        felled = fmask = None
-    else:
-        gaps32, fmask, failed = node_topology.sample_correlated_renewal_gaps(
-            topology, process, key, n_runs, max_failures, n_nodes)
-        felled = node_topology.survivor_slot_mask(fmask, failed)
+    gaps32, failed, felled, fmask = _sample_histories(
+        process, key, n_runs, max_failures, n_nodes, topology)
     params, nodes, ladder = _pack_pallas_inputs(stacked, makespan_s)
     gaps_t = jnp.asarray(gaps32, jnp.float32).T                  # (K, R)
     felled_t = (None if felled is None
@@ -1647,6 +1598,18 @@ _renewal_study_jit = jax.jit(
     static_argnames=("n_runs", "max_failures", "engine", "survivor_tile"))
 
 
+def _compose_lanes(stacked: SweepInputs, gaps, makespan_s, failed_node,
+                   felled) -> RenewalDeviceResult:
+    """The explicit-history composition of stacked lanes, one jitted
+    dispatch (call under x64)."""
+    gaps = jnp.atleast_2d(jnp.asarray(np.asarray(gaps, np.float64)))
+    if felled is not None:
+        felled = jnp.asarray(np.asarray(felled, bool))
+    out = _renewal_lanes_jit(stacked, gaps, _makespan_operand(
+        makespan_s, "scan"), felled=felled)
+    return _wrap_device_result(out, gaps, failed_node)
+
+
 def renewal_compose_policies(stacked: SweepInputs, gaps, makespan_s,
                              felled=None):
     """Compose explicit failure histories for a policy-stacked scenario.
@@ -1660,12 +1623,7 @@ def renewal_compose_policies(stacked: SweepInputs, gaps, makespan_s,
     ``RenewalDeviceResult`` whose leading axis is the policy axis.
     """
     with jax.enable_x64():
-        gaps = jnp.atleast_2d(jnp.asarray(np.asarray(gaps, np.float64)))
-        makespan = jnp.asarray(np.asarray(makespan_s, np.float64))
-        if felled is not None:
-            felled = jnp.asarray(np.asarray(felled, bool))
-        out = _renewal_policy_jit(stacked, gaps, makespan, felled=felled)
-        return _wrap_device_result(out, gaps, None)
+        return _compose_lanes(stacked, gaps, makespan_s, None, felled)
 
 
 def renewal_monte_carlo_policies(
@@ -1683,29 +1641,18 @@ def renewal_monte_carlo_policies(
 ):
     """Whole-run Monte-Carlo over a policy grid — one fused dispatch.
 
-    The policy analog of ``renewal_monte_carlo_device``: sampling (shared
-    across policies — common random numbers), the scan-over-epochs
-    composition for every policy lane, Algorithm 1, and the whole-run
-    reduction execute as one jitted program.  ``stacked`` is a
-    policy-stacked float64 ``SweepInputs`` (``core.optimize.policy_inputs``)
-    and ``makespan_s`` is per-policy, (P,).  For a fixed ``key`` each
+    ``renewal_monte_carlo_device`` over a policy-stacked float64
+    ``SweepInputs`` (``core.optimize.policy_inputs``) with a per-policy
+    ``makespan_s``, (P,): the same program, staging and options
+    (``stats``, ``topology``, ``engine``; the kernel takes float32 casts of
+    the float64 leaves, which are bit-exact).  Every policy sees the same
+    histories (common random numbers), so for a fixed ``key`` each
     policy's per-run energies are bit-identical to a standalone
     ``renewal_monte_carlo_device`` call on that policy's config with that
-    policy's makespan — the property ``tests/test_optimize.py``
-    cross-validates and the optimizer's low-variance comparisons rest on.
-
-    ``stats=True`` (default — the optimizer's hot path) returns the lean
-    ``RenewalDeviceStats``; ``stats=False`` the full per-epoch
-    ``RenewalDeviceResult``.  Leading axis of every field is the policy
-    axis.  ``topology`` (a ``core.topology.Topology``) swaps in the
-    correlated shock sampler — histories and felled masks stay shared
-    across policies (CRN holds for the correlated family too).
-
-    ``engine="pallas"`` dispatches the float32 Kahan-ledger kernel
-    (``kernels.renewal_scan``) instead of the x64 scan — stats-only, same
-    sampler and therefore the same CRN property (the float32 casts of the
-    float64 policy-stacked leaves are bit-exact).  See docs/sweep.md
-    ("Precision strategy").
+    policy's makespan (tests/test_optimize.py): the optimizer's
+    low-variance comparisons rest on it.  ``stats=True`` is the default
+    here (the optimizer's hot path); every field leads with the policy
+    axis.
 
     **Cluster axis (fleet dispatch).**  A ``stacked`` whose knob leaves
     carry TWO leading axes ``(C, P)`` (``core.optimize.
@@ -1721,8 +1668,8 @@ def renewal_monte_carlo_policies(
     scan-engine, stats-only, iid-sampler territory for now (``engine=
     "pallas"``, ``stats=False``, and ``topology`` all raise).
     """
-    proc = failures.as_process(process, mtbf_s)
     if stacked.interval.ndim == 2:
+        proc = failures.as_process(process, mtbf_s)
         if engine != "scan":
             raise ValueError(
                 "the cluster axis runs on the scan engine only (the Pallas "
@@ -1752,30 +1699,10 @@ def renewal_monte_carlo_policies(
                 stacked, key, makespan, proc,
                 n_runs=n_runs, max_failures=max_failures)
             return _wrap_device_stats(out)
-    if engine == "pallas":
-        if not stats:
-            raise ValueError(
-                "engine='pallas' is the stats-only hot path; use the scan "
-                "engine for per-epoch RenewalDeviceResult diagnostics")
-        cast = (lambda a: a.astype(jnp.float32)
-                if jnp.issubdtype(a.dtype, jnp.floating) else a)
-        out = _renewal_pallas_mc_jit(
-            jax.tree.map(cast, stacked), key,
-            jnp.asarray(np.asarray(makespan_s, np.float32)), proc,
-            n_runs=n_runs, max_failures=max_failures, topology=topology)
-        return _wrap_device_stats(out)
-    if engine != "scan":
-        raise ValueError(
-            f"unknown engine {engine!r} (use 'scan' or 'pallas')")
-    with jax.enable_x64():
-        makespan = jnp.asarray(np.asarray(makespan_s, np.float64))
-        out, gaps, failed = _renewal_policy_mc_jit(
-            stacked, key, makespan, proc,
-            n_runs=n_runs, max_failures=max_failures, stats=stats,
-            topology=topology)
-        if stats:
-            return _wrap_device_stats(out)
-        return _wrap_device_result(out, gaps, failed)
+    return _renewal_mc_runs(
+        stacked, key, makespan_s, process, mtbf_s, n_runs=n_runs,
+        max_failures=max_failures, stats=stats, topology=topology,
+        engine=engine)
 
 
 def _check_renewal_config(cfg: ScenarioConfig) -> None:
@@ -1883,13 +1810,8 @@ def renewal_compose_device(cfgs, gaps, makespan_s: float,
     oracle at ~1e-9 relative (tests/test_renewal_device.py).
     """
     with jax.enable_x64():
-        cfg_list, stacked = _renewal_device_inputs(cfgs)
-        gaps = jnp.atleast_2d(jnp.asarray(np.asarray(gaps, np.float64)))
-        if felled is not None:
-            felled = jnp.asarray(np.asarray(felled, bool))
-        out = _renewal_device_jit(stacked, gaps, float(makespan_s),
-                                  felled=felled)
-        return _wrap_device_result(out, gaps, failed_node)
+        _, stacked = _renewal_device_inputs(cfgs)
+        return _compose_lanes(stacked, gaps, makespan_s, failed_node, felled)
 
 
 def renewal_monte_carlo_device(
@@ -1909,77 +1831,93 @@ def renewal_monte_carlo_device(
 
     Per-node failure sequences (``renewal_failure_gaps`` semantics and
     bit-identical histories for the same key — exponential by default,
-    any ``failures.FailureProcess`` via ``process``, with conditional-
-    residual sampling for the non-memoryless ones) are drawn *inside* the
-    jitted program, then composed by the same scan as
-    ``renewal_compose_device`` — sampling, geometry, Algorithm 1, and
-    whole-run reduction execute as one dispatch per
-    (scenario-batch, run-batch).
+    any ``failures.FailureProcess`` via ``process``; ``topology``, a
+    ``core.topology.Topology``, swaps in the correlated shock scan) are
+    drawn *inside* the jitted program, then composed by the same scan as
+    ``renewal_compose_device``: one dispatch.
 
     ``stats=False`` returns the full ``RenewalDeviceResult`` (per-epoch
     decisions and energies — the cross-validation view); ``stats=True``
-    returns the lean ``RenewalDeviceStats`` (whole-run energies + integer
-    action counts), the production hot path: at the benchmark's default
-    shape the diagnostic arrays are most of the wall time.
-
-    ``topology`` (a ``core.topology.Topology`` over the scenario's
-    ``n_nodes``) swaps the sampler for the correlated shock scan and
-    threads the felled slots through the composition — still one fused
-    program, bit-identical histories to the host oracle's
-    ``renewal_failure_gaps(..., topology=...)``.
-
-    ``engine="scan"`` (default) is the x64 ``lax.scan`` engine described
-    above; ``engine="pallas"`` dispatches the float32 Pallas kernel with
-    the Kahan-compensated energy ledger (``kernels.renewal_scan``) —
-    stats-only (``stats=False`` raises: the per-epoch diagnostic view
-    belongs to the cross-validating engines), same sampler, same keys,
-    same histories, <= 1e-4 relative on whole-run energies vs the float64
-    oracle (tests/test_renewal_pallas.py).
+    the lean ``RenewalDeviceStats`` (whole-run energies + integer action
+    counts), the hot path.  ``engine="pallas"`` dispatches the float32
+    Kahan-ledger kernel (``kernels.renewal_scan``), stats only, on the
+    same histories (<= 1e-4 relative to the float64 oracle,
+    tests/test_renewal_pallas.py).
 
     This is the per-run view, for callers that read runs one by one (the
     policy grid, ``FleetAdvisor``, the FT controller read ``end_time``).
     A study's summaries come from ``renewal_monte_carlo_scenarios``, whose
     program reduces over runs on the device.
     """
+    return _renewal_mc_runs(
+        cfgs, key, makespan_s, process, mtbf_s, n_runs=n_runs,
+        max_failures=max_failures, stats=stats, topology=topology,
+        engine=engine)
+
+
+def _makespan_operand(makespan_s, engine: str):
+    """The makespan as the engine's program takes it: float32 for the
+    kernel; for the scan a Python float where it is one number for every
+    lane, else the lanes' float64 array (call under x64)."""
+    if engine == "pallas":
+        return jnp.asarray(np.asarray(makespan_s, np.float32))
+    if np.ndim(makespan_s) == 0:
+        return float(makespan_s)
+    return jnp.asarray(np.asarray(makespan_s, np.float64))
+
+
+@contextlib.contextmanager
+def _staged(lanes, process, mtbf_s, engine: str):
+    """Stage a Monte-Carlo dispatch under the ``sweep.stage`` span: yields
+    the stacked lanes in the engine's dtype and the failure process.
+    Configs are validated and stacked in it (``_renewal_device_inputs``); a
+    float64 policy stack (``core.optimize.policy_inputs``) is cast to
+    float32 for the kernel, bit-exact for every value the configs carry
+    (tests/test_precision.py).  The scan engine's x64 mode opens while
+    staging and holds until the block ends, so the dispatch inside it runs
+    in x64 and one span covers the staging alone."""
+    with contextlib.ExitStack() as x64:
+        with jax.profiler.TraceAnnotation("sweep.stage"):
+            proc = failures.as_process(process, mtbf_s)
+            if engine not in ("scan", "pallas"):
+                raise ValueError(
+                    f"unknown engine {engine!r} (use 'scan' or 'pallas')")
+            if engine == "scan":
+                x64.enter_context(jax.enable_x64())
+            if not isinstance(lanes, SweepInputs):
+                _, lanes = _renewal_device_inputs(
+                    lanes, jnp.float32 if engine == "pallas" else jnp.float64)
+            elif engine == "pallas":
+                lanes = jax.tree.map(
+                    lambda a: a.astype(jnp.float32)
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a, lanes)
+        yield lanes, proc
+
+
+def _renewal_mc_runs(lanes, key, makespan_s, process, mtbf_s, *,
+                     n_runs: int, max_failures: int, stats: bool, topology,
+                     engine: str):
+    """The per-run Monte-Carlo of stacked lanes, scenario configs or a
+    policy stack, as one staged dispatch of ``engine``'s program
+    (``_renewal_mc_jit`` or ``_renewal_pallas_mc_jit``): a
+    ``RenewalDeviceStats`` in stats mode, else a ``RenewalDeviceResult``."""
     if engine == "pallas" and not stats:
         raise ValueError(
             "engine='pallas' is the stats-only hot path; use the scan "
             "engine for per-epoch RenewalDeviceResult diagnostics")
-    with _staged(cfgs, process, mtbf_s, engine) as (stacked, proc):
+    with _staged(lanes, process, mtbf_s, engine) as (stacked, proc):
         with jax.profiler.TraceAnnotation("sweep.dispatch"):
+            makespan = _makespan_operand(makespan_s, engine)
             if engine == "pallas":
                 return _wrap_device_stats(_renewal_pallas_mc_jit(
-                    stacked, key, jnp.float32(makespan_s), proc,
-                    n_runs=n_runs, max_failures=max_failures,
-                    topology=topology))
+                    stacked, key, makespan, proc, n_runs=n_runs,
+                    max_failures=max_failures, topology=topology))
             out, gaps, failed = _renewal_mc_jit(
-                stacked, key, float(makespan_s), proc,
-                n_runs=n_runs, max_failures=max_failures, stats=stats,
-                topology=topology)
+                stacked, key, makespan, proc, n_runs=n_runs,
+                max_failures=max_failures, stats=stats, topology=topology)
         if stats:
             return _wrap_device_stats(out)
         return _wrap_device_result(out, gaps, failed)
-
-
-@contextlib.contextmanager
-def _staged(cfgs, process, mtbf_s, engine: str):
-    """Stage a Monte-Carlo dispatch under the ``sweep.stage`` span: yields
-    the stacked scenarios in the engine's dtype and the failure process.
-    The scan engine's x64 mode opens while staging and holds until the
-    block ends, so the dispatch inside it runs in x64 and one span covers
-    the staging alone."""
-    with contextlib.ExitStack() as x64:
-        with jax.profiler.TraceAnnotation("sweep.stage"):
-            proc = failures.as_process(process, mtbf_s)
-            if engine == "pallas":
-                _, stacked = _renewal_device_inputs(cfgs, jnp.float32)
-            elif engine == "scan":
-                x64.enter_context(jax.enable_x64())
-                _, stacked = _renewal_device_inputs(cfgs)
-            else:
-                raise ValueError(
-                    f"unknown engine {engine!r} (use 'scan' or 'pallas')")
-        yield stacked, proc
 
 
 def _renewal_study_device(cfgs, key, *, n_runs: int, makespan_s: float,
@@ -1997,11 +1935,10 @@ def _renewal_study_device(cfgs, key, *, n_runs: int, makespan_s: float,
         with jax.profiler.TraceAnnotation(
                 "sweep.dispatch", survivors=n, survivor_tile=tile or n,
                 tiles=tiles):
-            makespan = (jnp.float32(makespan_s) if engine == "pallas"
-                        else float(makespan_s))
             return _renewal_study_jit(
-                stacked, key, makespan, proc, n_runs=n_runs,
-                max_failures=max_failures, topology=topology, engine=engine)
+                stacked, key, _makespan_operand(makespan_s, engine), proc,
+                n_runs=n_runs, max_failures=max_failures, topology=topology,
+                engine=engine)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -2175,7 +2112,7 @@ def renewal_monte_carlo(
     makespan_s: float = 30 * 24 * 3600.0,
     mtbf_s: float = 14 * 24 * 3600.0,
     max_failures: int = 64,
-    engine: str = "device",
+    engine: str = "scan",
     process: Optional[failures.FailureProcess] = None,
     topology=None,
 ) -> RenewalMonteCarloSummary:
@@ -2191,33 +2128,32 @@ def renewal_monte_carlo(
     ``process`` the summary's ``mtbf_s`` reports the process's mean gap
     (averaged over heterogeneous nodes).
 
-    ``engine="device"`` (default) runs the fused jitted program
-    (``renewal_monte_carlo_device``); ``engine="pallas"`` the float32
-    Kahan-ledger kernel behind the same entry
-    (``kernels.renewal_scan`` — see docs/sweep.md "Precision strategy");
-    ``engine="host"`` runs the float64 oracle (``renewal_compose``) — same
-    histories, same summary reduction, pinned together by
-    tests/test_renewal_device.py and tests/test_renewal_pallas.py.  For
-    several scenarios at once use ``renewal_monte_carlo_scenarios`` (one
-    device dispatch).
+    ``engine="scan"`` (default) and ``engine="pallas"`` run the study of
+    ``renewal_monte_carlo_scenarios`` on this one scenario: the x64 scan
+    engine or the float32 Kahan-ledger kernel (``kernels.renewal_scan`` —
+    see docs/sweep.md "Precision strategy").  ``engine="host"`` runs the
+    float64 oracle (``renewal_compose``) — same histories, same summary
+    fields, pinned together by tests/test_renewal_device.py and
+    tests/test_renewal_pallas.py.  For several scenarios at once call
+    ``renewal_monte_carlo_scenarios`` (one device dispatch).
 
     ``topology`` (a ``core.topology.Topology`` over the scenario's node
     count) swaps in the correlated shock sampler on either engine — shock
     epochs fell several nodes at once; the bit-identity contract between
     the engines carries over to the correlated histories.
     """
+    if engine not in ("scan", "pallas", "host"):
+        raise ValueError(
+            f"unknown engine {engine!r} (use 'scan', 'pallas' or 'host')")
+    if engine != "host":
+        return renewal_monte_carlo_scenarios(
+            [cfg], key, n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
+            max_failures=max_failures, process=process, topology=topology,
+            engine=engine)[cfg.name]
     if process is not None:
         mtbf_s = float(np.mean(failures.as_process(process).mean_s()))
     kw = dict(n_runs=n_runs, makespan_s=makespan_s, mtbf_s=mtbf_s,
               max_failures=max_failures)
-    if engine in ("device", "pallas"):
-        totals, moments = jax.device_get(_renewal_study_device(
-            cfg, key, process=process, topology=topology,
-            engine="pallas" if engine == "pallas" else "scan", **kw))
-        return _study_summary(totals[0], moments[0], **kw)
-    if engine != "host":
-        raise ValueError(
-            f"unknown engine {engine!r} (use 'device', 'pallas' or 'host')")
     n_nodes = len(cfg.survivors) + 1
     if topology is None:
         gaps, failed = renewal_failure_gaps(

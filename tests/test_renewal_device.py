@@ -222,7 +222,7 @@ def test_renewal_monte_carlo_engines_pinned_nonexponential(process):
     cfg = paper_scenarios()["scenario2_long_reexec"]
     kw = dict(n_runs=32, makespan_s=200000.0, max_failures=16)
     dev = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
-                                    engine="device", process=process, **kw)
+                                    engine="scan", process=process, **kw)
     host = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
                                      engine="host", process=process, **kw)
     for field in dev.__dataclass_fields__:
@@ -235,7 +235,7 @@ def test_renewal_monte_carlo_engines_pinned_nonexponential(process):
     np.testing.assert_allclose(
         dev.mtbf_s, float(np.mean(process.mean_s())), rtol=1e-6)
     again = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
-                                      engine="device", process=process, **kw)
+                                      engine="scan", process=process, **kw)
     assert again == dev
 
 
@@ -249,7 +249,7 @@ def test_renewal_scenarios_process_matches_per_scenario_device():
         list(cfgs.values()), jax.random.PRNGKey(5), process=w, **kw)
     name = SCENARIOS[2]
     single = sweep.renewal_monte_carlo(
-        cfgs[name], jax.random.PRNGKey(5), engine="device", process=w, **kw)
+        cfgs[name], jax.random.PRNGKey(5), engine="scan", process=w, **kw)
     for field in single.__dataclass_fields__:
         a, b = getattr(stacked[name], field), getattr(single, field)
         if isinstance(a, float):
@@ -270,7 +270,7 @@ def test_renewal_monte_carlo_engines_pinned():
     kw = dict(n_runs=64, makespan_s=10 * 24 * 3600.0,
               mtbf_s=3 * 24 * 3600.0, max_failures=32)
     dev = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
-                                    engine="device", **kw)
+                                    engine="scan", **kw)
     host = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
                                      engine="host", **kw)
     for field in dev.__dataclass_fields__:
@@ -281,10 +281,10 @@ def test_renewal_monte_carlo_engines_pinned():
             assert a == b, (field, a, b)
     # deterministic under the same key; sensitive to the key
     again = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
-                                      engine="device", **kw)
+                                      engine="scan", **kw)
     assert again == dev
     other = sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(4),
-                                      engine="device", **kw)
+                                      engine="scan", **kw)
     assert other.mean_saving_j != dev.mean_saving_j
     with pytest.raises(ValueError, match="engine"):
         sweep.renewal_monte_carlo(cfg, jax.random.PRNGKey(3),
@@ -301,7 +301,7 @@ def test_renewal_monte_carlo_scenarios_one_dispatch_matches_per_scenario():
     assert sorted(stacked) == SCENARIOS
     for name in (SCENARIOS[0], SCENARIOS[3]):
         single = sweep.renewal_monte_carlo(
-            cfgs[name], jax.random.PRNGKey(5), engine="device", **kw)
+            cfgs[name], jax.random.PRNGKey(5), engine="scan", **kw)
         for field in single.__dataclass_fields__:
             a, b = getattr(stacked[name], field), getattr(single, field)
             if isinstance(a, float):

@@ -1,9 +1,10 @@
-"""The survivor fold of a study in tiles (``sweep._survivor_tile``): a study
-whose (lane, run, epoch, survivor) grid exceeds the fold's element budget
-folds inside the epoch scan, each epoch's survivors a tile at a time, and
-gives the summaries the fold over the stacked epochs gives.  The sums over
-survivors and epochs are taken in another order, so the energies agree to
-float64 round-off (1e-12 relative) and every count is equal."""
+"""The survivor fold in tiles (``sweep._survivor_tile``): a stack of lanes,
+scenarios or policies, whose (lane, run, epoch, survivor) grid exceeds the
+fold's element budget folds inside the epoch scan, each epoch's survivors a
+tile at a time, and gives the summaries the fold over the stacked epochs
+gives.  The sums over survivors and epochs are taken in another order, so
+the energies agree to float64 round-off (1e-12 relative) and every count is
+equal."""
 import dataclasses
 import glob
 import json
@@ -11,6 +12,7 @@ import pathlib
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
@@ -25,6 +27,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 N_SURVIVORS = 39
 MTBF = 90 * 24 * 3600.0            # ~13 failures in a 30-day run of 40 nodes
 MAKESPAN = 30 * 24 * 3600.0
+MAKESPANS = MAKESPAN * np.array([1.0, 1.1])     # a policy stack's, one a lane
 KW = dict(n_runs=64, max_failures=16)
 # the tile scope in a compiled operation's name:
 # "vmap(vmap(renewal_scan))/while/body/closed_call/survivor_tile/add"
@@ -61,14 +64,61 @@ def _study(family, tile, key=jax.random.PRNGKey(5)):
             engine="scan", survivor_tile=tile, **KW))
 
 
-@pytest.mark.parametrize("tile", [8, N_SURVIVORS],
-                         ids=["tiles_of_8", "one_tile"])  # 8: the last holds 7
-@pytest.mark.parametrize("family", ["exponential", "weibull", "rack"])
-def test_tiles_give_the_stacked_fold_summaries(family, tile):
+def _policy_study(family, key=jax.random.PRNGKey(5)):
+    """A policy stack's per-run Monte-Carlo (``_renewal_mc_jit`` with one
+    makespan a lane) under the fold budget in force, reduced as a study's:
+    ``(totals, moments)`` and the program's compiled text."""
+    process, topology = _family(family)
+    with jax.enable_x64():
+        _, stacked = sweep._renewal_device_inputs(CFGS)
+        args = (stacked, key, jnp.asarray(MAKESPANS), process)
+        compiled = sweep._renewal_mc_jit.lower(
+            *args, stats=True, topology=topology, **KW).compile()
+        out, _, _ = compiled(*args, topology=topology)
+        study = sweep._study_reduce_jit(out, max_failures=KW["max_failures"])
+    return jax.device_get(study), compiled.as_text()
+
+
+@pytest.fixture
+def fold_budget(monkeypatch):
+    """Sets ``sweep._SURVIVOR_TILE_ELEMENTS``.  A program reads it when it
+    is traced, so JAX's caches of traced programs are cleared with each
+    change and at the end of a test that made one."""
+    changed = []
+
+    def set_budget(elements):
+        monkeypatch.setattr(sweep, "_SURVIVOR_TILE_ELEMENTS", elements)
+        jax.clear_caches()
+        changed.append(elements)
+    yield set_budget
+    if changed:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+
+# 8: the last tile holds 7; the policy stack's ids add "-policies"
+@pytest.mark.parametrize("family,tile,stack", [
+    pytest.param(family, tile, stack,
+                 id="-".join([family, tile_id]
+                             + ([stack] if stack == "policies" else [])))
+    for stack in ("scenarios", "policies")
+    for family in ("exponential", "weibull", "rack")
+    for tile, tile_id in ((8, "tiles_of_8"), (N_SURVIVORS, "one_tile"))])
+def test_tiles_give_the_stacked_fold_summaries(family, tile, stack,
+                                               fold_budget):
     assert sweep._survivor_tile(len(CFGS), KW["n_runs"], KW["max_failures"],
                                 N_SURVIVORS) is None
-    whole_totals, whole_moments = _study(family, None)
-    totals, moments = _study(family, tile)
+    if stack == "scenarios":
+        whole_totals, whole_moments = _study(family, None)
+        totals, moments = _study(family, tile)
+    else:
+        # the policy stack decides its tiles from its shapes, as a study
+        # does: over a budget of ``tile`` survivors of every lane's runs
+        (whole_totals, whole_moments), text = _policy_study(family)
+        assert not TILE_SCOPE.search(text)
+        fold_budget(tile * len(CFGS) * KW["n_runs"])
+        (totals, moments), text = _policy_study(family)
+        assert TILE_SCOPE.search(text)
     np.testing.assert_array_equal(totals, whole_totals)
     np.testing.assert_allclose(moments, whole_moments, rtol=1e-12, atol=0)
     # the histories did fail, and the fold took every kind of action
