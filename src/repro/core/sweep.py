@@ -956,10 +956,12 @@ def _felled_race(m, age_surv, age_fail, exec_rem):
     survivors' lost work joins the re-execution race and ``P*`` is the
     furthest *non-felled* survivor; ``m=None`` is the single-failure epoch
     (the failed node's age, the furthest survivor) — the same values an
-    all-False mask gives, without the mask."""
+    all-False mask gives, without the mask.  ``age_surv=None`` says every
+    survivor's age is the failed node's (the run-wide sawtooth of
+    ``_renewal_scan``), so the race is that age whatever the mask."""
     if m is None:
         return age_fail, jnp.maximum(jnp.max(exec_rem, axis=-1), 0.0)
-    reexec = jnp.maximum(
+    reexec = age_fail if age_surv is None else jnp.maximum(
         age_fail, jnp.max(jnp.where(m, age_surv, -jnp.inf), axis=-1))
     p_star = jnp.maximum(
         jnp.max(jnp.where(m, -jnp.inf, exec_rem), axis=-1), 0.0)
@@ -984,6 +986,22 @@ def _ordered_sum(x, axis=None):
         half = x.shape[-1] // 2
         x = x[..., :half] + x[..., half:]
     return x[..., 0]
+
+
+def _ordered_sum_of_copies(x, count: int):
+    """``_ordered_sum`` over a last axis of ``count`` copies of ``x``, bit
+    for bit, without the axis.  The tree's padded row holds the copies and
+    then zeros, so each level holds few distinct entries: each distinct
+    pair is added once, O(log count) adds in all."""
+    width = 1 << (count - 1).bit_length()
+    row = [x] * count + [jnp.zeros_like(x)] * (width - count)
+    while len(row) > 1:
+        half, sums = len(row) // 2, {}
+        for a, b in zip(row[:half], row[half:]):
+            if (id(a), id(b)) not in sums:
+                sums[id(a), id(b)] = a + b
+        row = [sums[id(a), id(b)] for a, b in zip(row[:half], row[half:])]
+    return row[0]
 
 
 # Elements of a (lane, run, epoch, survivor) array that a stats-mode fold
@@ -1020,18 +1038,20 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     """Whole-run renewal recursion for ONE scenario x ONE run as a
     ``lax.scan`` over failure epochs.
 
-    The carry is the re-anchored state ``(ages, exec_anchor, reexec_age,
-    bal_elapsed, t_anchor, alive)``; each step advances the
-    checkpoint/rendezvous sawtooths to the failure instant and re-anchors —
-    the exact recursion of ``renewal_compose``, but traced once and
-    compiled.  The balanced-span energy, checkpoint plan, Algorithm-1
-    dispatch, and trailing-span accounting run *after* the scan over the
-    stacked per-epoch states (still the same jitted program), where XLA
-    vectorizes them across the whole grid.  Must be traced under
-    ``enable_x64`` with float64 inputs: wall-clock anchors grow to the
-    makespan and would lose ~0.5 s to float32 over month-long runs, while
-    Algorithm 1 is dispatched on float32 casts of the float64 geometry —
-    the very same values the host oracle feeds it.
+    The carry is the re-anchored state ``(ages, exec_anchor, bal_elapsed,
+    t_anchor, alive)``; each step advances the checkpoint/rendezvous
+    sawtooths to the failure instant and re-anchors — the exact recursion
+    of ``renewal_compose``, but traced once and compiled.  Epoch 0 runs
+    before the scan and advances every node's own age; the scan carries
+    one age a run for the later epochs, whose nodes the resync checkpoint
+    starts all at 0 (docs/sweep.md, "The resync invariant").  The
+    checkpoint plan, Algorithm-1 dispatch, and trailing-span accounting
+    run *after* the scan over the stacked per-epoch states (still the same
+    jitted program), where XLA vectorizes them across the whole grid.
+    Must be traced under ``enable_x64`` with float64 inputs: wall-clock
+    anchors grow to the makespan and would lose ~0.5 s to float32 over
+    month-long runs, while Algorithm 1 is dispatched on float32 casts of
+    the float64 geometry — the very same values the host oracle feeds it.
     ``_renewal_lanes`` vmaps this over runs and stacked lanes.
 
     ``stats=True`` is the hot-path mode: per-epoch diagnostic arrays are
@@ -1081,7 +1101,7 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     # The scan body carries ONLY the re-anchor recursion — the part with a
     # true epoch-to-epoch dependency.  Everything with a ladder axis
     # (checkpoint plan, Algorithm 1) or that is pure per-epoch arithmetic
-    # (span energies, trailing spans) is evaluated AFTER the scan over the
+    # (window energies, trailing spans) is evaluated AFTER the scan over the
     # stacked (K, ...) epoch states, where XLA vectorizes it across the
     # whole epochs x runs x scenarios grid instead of re-issuing it inside
     # a 32-step sequential loop — unless that grid outgrows the fold's
@@ -1095,23 +1115,42 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
     m_all = None if felled is None else jnp.asarray(felled, bool)
 
     def step(carry, xs):
-        # ages_all stacks the survivors' checkpoint ages with the failed
-        # node's lost-work age (the same sawtooth governs both), so one
-        # closed-form advance serves all N+1 nodes per step.
+        # The sawtooth state ``ages`` stacks the survivors' checkpoint ages
+        # with the failed node's lost-work age (the same sawtooth governs
+        # both), (N+1,), so one closed-form advance serves all N+1 nodes.
+        # Shaped () it is the one age all N+1 nodes share (the run-wide
+        # state after the first epoch, below), advanced once.
         delta, m = xs
-        ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        ages, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        node_wide = jnp.ndim(ages) > 0
         occurs = alive & (bal_elapsed + delta <= makespan)
         age_all, work, _, d_eff_all = planning.advance_checkpoint_sawtooth(
-            ages_all, delta, interval, dur)                      # (N+1,)
-        rem = jnp.mod(exec_anchor - work[:-1], period)
+            ages, delta, interval, dur)                    # (N+1,) or ()
+        if node_wide:            # the survivors, then the failed node
+            age_surv, age_fail = age_all[:-1], age_all[-1]
+            work_surv, d_eff_fail = work[:-1], d_eff_all[-1]
+        else:                    # one value for all N+1 nodes
+            age_surv = age_fail = age_all
+            work_surv, d_eff_fail = work, d_eff_all
+        rem = jnp.mod(exec_anchor - work_surv, period)
         exec_rem = jnp.where(rem == 0.0, period, rem)
-        d_eff_fail = d_eff_all[-1]
-        reexec, p_star = _felled_race(m, age_all[:-1], age_all[-1], exec_rem)
+        reexec, p_star = _felled_race(
+            m, age_surv if node_wide else None, age_fail, exec_rem)
         t_e = t_dr + reexec + p_star                             # epoch span T_E
+
+        # balanced span energy up to each node's (snapped) failure instant.
+        # At the snapped instant the span's checkpoint share is exactly the
+        # fired checkpoints, so ``work``/``d_eff - work`` from the sawtooth
+        # *is* the ``balanced_span`` decomposition (both are exact multiples
+        # of ``dur`` — tests pin the identity) without recomputing it.  Over
+        # one age, the tree of N+1 equal terms without the axis.
+        bal = work * p_comp0 + (d_eff_all - work) * p_ckpt0
+        e_bal = (_ordered_sum(bal, axis=-1) if node_wide
+                 else _ordered_sum_of_copies(bal, n_nodes))
 
         # re-anchor: coordinated resync checkpoint -> ages 0, progress P*
         new_carry = (
-            jnp.where(occurs, 0.0, ages_all),
+            jnp.where(occurs, 0.0, ages),
             jnp.where(occurs,
                       post_recovery_anchor(exec_rem, period, p_star=p_star),
                       exec_anchor),
@@ -1119,16 +1158,14 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
             jnp.where(occurs, t_anchor + d_eff_fail + t_e + dur_fa, t_anchor),
             alive & occurs,
         )
+        ys = (occurs, reexec, p_star, e_bal)
         if per_epoch:
-            e_bal = _ordered_sum(
-                work * p_comp0 + (d_eff_all - work) * p_ckpt0, axis=-1)
             with jax.named_scope("survivor_tile"):
-                sums = fold_tiles(occurs, age_all[:-1], exec_rem,
-                                  t_dr + reexec, t_e, m)
-            return new_carry, (occurs, reexec, p_star, e_bal, sums)
-        ys = (occurs, age_all, work, exec_rem, d_eff_all) + (
+                sums = fold_tiles(occurs, age_surv, exec_rem, t_dr + reexec,
+                                  t_e, m)
+            return new_carry, ys + (sums,)
+        return new_carry, ys + (age_surv, exec_rem) + (
             () if stats else (jnp.where(occurs, t_anchor + d_eff_fail, 0.0),))
-        return new_carry, ys
 
     def survivor_fold(exec_rem_k, age_f, t_failed_k, t_e, valid, m):
         """The checkpoint plan, Algorithm 1 and the survivors' epoch
@@ -1194,7 +1231,8 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
         for lo in range(0, n, survivor_tile):
             tile = slice(lo, lo + survivor_tile)
             decision, v2, epoch_ref, epoch_int = survivor_fold(
-                exec_rem[tile], age[tile], t_recover + exec_rem[tile], t_e,
+                exec_rem[tile], age[tile] if jnp.ndim(age) else age,
+                t_recover + exec_rem[tile], t_e,
                 occurs, None if m is None else m[tile])
             part = dict(epoch_ref=_ordered_sum(epoch_ref),
                         epoch_int=_ordered_sum(epoch_int),
@@ -1203,45 +1241,46 @@ def _renewal_scan(inp: SweepInputs, gaps: jax.Array, makespan_s,
                                                           part)
         return sums
 
+    def stack(first, rest):
+        """Epoch 0's value over the later epochs' stacked values, a run-wide
+        value broadcast over the survivors: a pad and a select, which fuse
+        into what reads them (a concatenation is copied whole)."""
+        rest = rest.reshape(rest.shape + (1,) * (first.ndim + 1 - rest.ndim))
+        rest = jnp.pad(rest, [(1, 0)] + [(0, 0)] * first.ndim)
+        is_first = jnp.arange(rest.shape[0]).reshape(
+            rest.shape[:1] + (1,) * first.ndim) == 0
+        return jnp.where(is_first, first[None], rest)
+
     init = (jnp.concatenate([f8(inp.age0), f8(inp.reexec0)[None]]),
             f8(inp.exec_rem0), f8(0.0), f8(0.0), jnp.asarray(True))
-    if per_epoch:
-        # the carry takes the run's axis from its first gap (a select of
-        # equal values, which the compiler drops), so that vmap batches the
-        # step, fold and all, in one pass rather than two
-        first = f8(gaps)[0] >= 0.0
-        init = jax.tree.map(lambda a: jnp.where(first, a, a), init)
+    gaps = f8(gaps)
+    m_rest = None if m_all is None else m_all[1:]
     with jax.named_scope("renewal_scan"):
-        carry, ys = jax.lax.scan(step, init, (f8(gaps), m_all))
+        # Epoch 0 advances each node's own age (the configuration's age0
+        # and reexec0).  Every occurring epoch ends in the resync
+        # checkpoint, which sets all N+1 ages to 0, and the first epoch that
+        # does not occur ends the run: from epoch 1 on, every epoch that
+        # counts starts with all nodes at one age, so the later epochs carry
+        # the sawtooth as that one age a run.  The carry after epoch 0 is
+        # what the balanced tail starts from (age0 and reexec0 in a run
+        # with no failure, else zeros; later epochs leave it so).
+        carry, ys0 = step(init, (gaps[0], None if m_all is None else m_all[0]))
+        ages_all = carry[0]
+        carry, ys = jax.lax.scan(step, (ages_all[-1],) + carry[1:],
+                                 (gaps[1:], m_rest))
+        ys = jax.tree.map(stack, ys0, ys)
     with jax.named_scope("renewal_fold"):
-        ages_all, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        _, exec_anchor, bal_elapsed, t_anchor, alive = carry
+        valid, reexec_f, p_star, e_bal = ys[:4]                      # (K,)
+        t_recover = t_dr + reexec_f
+        t_e = t_recover + p_star
         if per_epoch:
-            valid, reexec_f, p_star, e_bal, epoch_sums = ys
-            t_recover = t_dr + reexec_f                              # (K,)
-            t_e = t_recover + p_star
+            epoch_sums = ys[4]
         else:
-            (valid, age_all, work_all, exec_rem_k, d_eff_all), t_fail = \
-                ys[:5], (None if stats else ys[5])
-
-            # --- per-epoch accounting, vectorized over the stacked epochs --
-            age_f = age_all[..., :-1]                                # (K, N)
-            reexec_f, p_star = _felled_race(m_all, age_f, age_all[..., -1],
-                                            exec_rem_k)              # (K,)
-            d_eff_fail = d_eff_all[..., -1]
-            t_recover = t_dr + reexec_f                              # (K,)
+            # per-epoch accounting, vectorized over the stacked epochs
+            (age_f, exec_rem_k), t_fail = ys[4:6], (None if stats else ys[6])
             t_failed_k = t_recover[..., None] + exec_rem_k           # (K, N)
-            t_e = t_recover + p_star
-
-            # balanced span energy up to each node's (snapped) failure
-            # instant, plus the coordinated resync checkpoint closing each
-            # epoch.  At the snapped instant the span's checkpoint share is
-            # exactly the fired checkpoints, so ``work``/``d_eff - work``
-            # from the scan's sawtooth *is* the ``balanced_span``
-            # decomposition (both are exact multiples of ``dur`` — tests pin
-            # the identity) without recomputing it.
-            e_bal = _ordered_sum(
-                work_all * p_comp0 + (d_eff_all - work_all) * p_ckpt0,
-                axis=-1)
+        # the balanced spans plus the resync checkpoint closing each epoch
         balanced = _ordered_sum(jnp.where(
             valid, e_bal + n_nodes * dur_fa * p_ckpt0, 0.0))
 
@@ -1932,9 +1971,13 @@ def _renewal_study_device(cfgs, key, *, n_runs: int, makespan_s: float,
                 else _survivor_tile(n_lanes, n_runs, max_failures, n))
         # a run's fold in one piece, or per epoch in tiles of survivors
         tiles = 1 if tile is None else max_failures * -(-n // tile)
+        # the scan advances the sawtooth per node in epoch 0 and per run
+        # after it (``_renewal_scan``); the kernel, per node in every epoch
+        node_epochs = max_failures if engine == "pallas" else 1
         with jax.profiler.TraceAnnotation(
                 "sweep.dispatch", survivors=n, survivor_tile=tile or n,
-                tiles=tiles):
+                tiles=tiles, node_epochs=node_epochs,
+                run_epochs=max_failures - node_epochs):
             return _renewal_study_jit(
                 stacked, key, _makespan_operand(makespan_s, engine), proc,
                 n_runs=n_runs, max_failures=max_failures, topology=topology,

@@ -19,9 +19,9 @@ from hypothesis import strategies as st
 
 from repro.core import energy_model as em
 from repro.core import failures as F
-from repro.core import strategies, sweep
+from repro.core import planning, strategies, sweep
 from repro.core.scenarios import paper_scenarios
-from repro.core.simulator import simulate_run
+from repro.core.simulator import NodeStart, simulate_run
 
 GAPS = np.array([5000.0, 9000.0, 4000.0, 2500.0])
 MAKESPAN = 60000.0
@@ -98,6 +98,140 @@ def test_device_first_epoch_equals_single_failure_sweep():
     np.testing.assert_allclose(
         np.asarray(res.decision.saving)[0, 0, 0],
         np.asarray(single.decision.saving)[0], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the run-wide sawtooth after epoch 0 == the host oracle's per-node one
+# ---------------------------------------------------------------------------
+
+def _varied(name, n):
+    """Paper scenario ``name`` with ``n`` survivors of varied phases, ages
+    and periods."""
+    cfg = paper_scenarios()[name]
+    return dataclasses.replace(cfg, survivors=tuple(
+        NodeStart(exec_to_rendezvous=(97.0 * i) % period + 1.0,
+                  rendezvous_period=period,
+                  ckpt_age=(53.0 * i + 60.0) % cfg.ckpt_interval)
+        for i, period in enumerate([3600.0, 1800.0, 2700.0] * n) if i < n))
+
+
+def _histories():
+    """Runs with 0, 1 and many failures (one truncated), and gaps that
+    land inside a checkpoint after a resync (epochs 1 and 2: at interval
+    3600 and at interval 1800, dur 120)."""
+    rng = np.random.default_rng(19)
+    return np.stack([
+        [1e9, 500.0, 500.0, 500.0, 500.0, 500.0],            # no failure
+        [5000.0, 1e9, 500.0, 500.0, 500.0, 500.0],           # one
+        [5000.0, 3660.0, 3780.0, 4000.0, 2500.0, 1e9],       # mid-checkpoint
+        [2000.0, 3000.0, 2000.0, 3000.0, 2000.0, 3000.0],    # truncated
+        rng.exponential(6000.0, 6),
+    ])
+
+
+RUN_WIDE_CASES = {
+    # N+1 = 4, the Table-4 scenarios' own per-node age0 and reexec0
+    "table4": ([paper_scenarios()[n] for n in SCENARIOS], False),
+    "table4-felled": ([paper_scenarios()[n] for n in SCENARIOS], True),
+    "5_survivors": ([_varied("scenario1_short_reexec", 5),
+                     _varied("scenario5_short_idle_waits", 5)], False),
+    "6_survivors-felled": ([_varied("scenario1_short_reexec", 6),
+                            _varied("scenario2_long_reexec", 6)], True),
+    "7_survivors-felled": ([_varied("scenario4_short_active_waits", 7)], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RUN_WIDE_CASES))
+def test_the_run_wide_sawtooth_matches_the_host_oracle(case):
+    """After epoch 0 the scan advances one age a run, not one a node
+    (every occurring epoch ends in the resync checkpoint): its per-epoch
+    outputs, its stats in both fold forms (over the stacked epochs, and in
+    tiles of 2 survivors inside the scan) and the balanced tail that reads
+    the carry agree with ``renewal_compose``'s per-node recursion.  Counts
+    equal, geometry to 1e-12, energies to this file's 1e-4."""
+    cfgs, with_felled = RUN_WIDE_CASES[case]
+    gaps = _histories()
+    n = len(cfgs[0].survivors)
+    felled = (np.random.default_rng(5).uniform(size=gaps.shape + (n,)) < 0.3
+              if with_felled else None)
+    dev = sweep.renewal_compose_device(cfgs, gaps, MAKESPAN, felled=felled)
+    with jax.enable_x64():
+        _, stacked = sweep._renewal_device_inputs(cfgs)
+        stats = {tile: jax.device_get(sweep._renewal_lanes_jit(
+            stacked, jax.numpy.asarray(gaps), MAKESPAN, stats=True,
+            felled=felled, survivor_tile=tile)) for tile in (None, 2)}
+    geometry = ("t_fail", "t_renewal", "end_time")
+    energies = ("epoch_ref", "epoch_int", "epoch_failed", "balanced_energy",
+                "energy_ref", "energy_int")
+    for s, cfg in enumerate(cfgs):
+        host = sweep.renewal_compose(cfg, gaps, MAKESPAN, felled=felled)
+        d = _device_slice(dev, s)
+        np.testing.assert_array_equal(host.n_failures[:4], [0, 1, 5, 6])
+        # a gap after a resync snapped to a checkpoint's end
+        snapped = [planning.advance_checkpoint_sawtooth(
+            0.0, gaps[r, k], cfg.ckpt_interval, cfg.ckpt_duration)[3]
+            > gaps[r, k] for r, k in zip(*np.nonzero(host.valid))
+            if k > 0]
+        assert any(snapped), cfg.name
+        np.testing.assert_array_equal(d.valid, host.valid, err_msg=cfg.name)
+        np.testing.assert_array_equal(d.n_failures, host.n_failures)
+        np.testing.assert_array_equal(d.truncated, host.truncated)
+        v = host.valid
+        for field in ("exec_rem", "t_failed"):
+            np.testing.assert_allclose(
+                getattr(d, field)[v], getattr(host, field)[v], rtol=1e-12,
+                err_msg=f"{case} {cfg.name} {field}")
+        for field in geometry:
+            np.testing.assert_allclose(
+                getattr(d, field), getattr(host, field), rtol=1e-12,
+                err_msg=f"{case} {cfg.name} {field}")
+        for field in energies:
+            np.testing.assert_allclose(
+                getattr(d, field), getattr(host, field), rtol=1e-4,
+                atol=1e-6, err_msg=f"{case} {cfg.name} {field}")
+        denom = np.maximum(np.abs(host.saving), 1e-4 * host.energy_ref)
+        np.testing.assert_array_less(np.abs(d.saving - host.saving) / denom,
+                                     1e-4)
+        points = v[..., None] & ~np.broadcast_to(
+            False if felled is None else felled, gaps.shape + (n,))
+        for field in ("level", "wait_action"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(d.decision, field))[points],
+                np.asarray(getattr(host.decision, field))[points],
+                err_msg=f"{case} {cfg.name} {field}")
+        dec = host.decision
+        counts = dict(
+            n_points=points,
+            n_sleep=np.asarray(dec.wait_action) == em.WaitAction.SLEEP,
+            n_min_freq=np.asarray(dec.wait_action) == em.WaitAction.MIN_FREQ,
+            n_comp_changed=np.asarray(dec.comp_changed),
+            n_infeasible=~np.asarray(dec.feasible_any))
+        for tile, got in stats.items():
+            for field, hit in counts.items():
+                np.testing.assert_array_equal(
+                    got[field][s], (hit & points).sum(axis=(1, 2)),
+                    err_msg=f"{case} {cfg.name} tile={tile} {field}")
+            np.testing.assert_array_equal(got["n_failures"][s],
+                                          host.n_failures)
+            np.testing.assert_array_equal(got["truncated"][s], host.truncated)
+            for field in ("balanced_energy", "energy_ref", "energy_int"):
+                np.testing.assert_allclose(
+                    got[field][s], getattr(host, field), rtol=1e-4,
+                    err_msg=f"{case} {cfg.name} tile={tile} {field}")
+
+
+@pytest.mark.parametrize("count", [1, 2, 3, 4, 5, 6, 7, 8, 9, 1023, 1024,
+                                   1025])
+def test_ordered_sum_of_copies_is_the_tree_bit_for_bit(count):
+    """The run-wide balanced energy sums N+1 equal terms: the tree over
+    them without the axis gives ``_ordered_sum``'s bits."""
+    x = np.random.default_rng(count).uniform(0.0, 1e7, 64)
+    with jax.enable_x64():
+        tree = sweep._ordered_sum(np.broadcast_to(x[:, None], (64, count)),
+                                  axis=-1)
+        copies = sweep._ordered_sum_of_copies(jax.numpy.asarray(x), count)
+    np.testing.assert_array_equal(np.asarray(copies).view(np.int64),
+                                  np.asarray(tree).view(np.int64))
 
 
 # ---------------------------------------------------------------------------

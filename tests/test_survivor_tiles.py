@@ -157,6 +157,77 @@ def test_a_whole_machine_study_folds_in_the_epoch_scan():
                                        survivor_tile=8))
 
 
+def _hlo_computations(text):
+    """Computation name -> its instruction lines, of an HLO module's text,
+    and the name of its entry computation."""
+    comps, name, entry = {}, None, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            words = line.split()
+            name = words[1] if words[0] == "ENTRY" else words[0]
+            entry = name if words[0] == "ENTRY" else entry
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name:
+            comps[name].append(line)
+    return comps, entry
+
+
+_CALLED = re.compile(r"(?:body|condition|to_apply|calls)=([\w.-]+)"
+                     r"|branch_computations=\{([^}]*)\}")
+
+
+def _reached(comps, roots):
+    """The computations ``roots`` call, themselves included."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            for line in comps[name]:
+                for one, many in _CALLED.findall(line):
+                    todo.extend([one] if one else
+                                [c.strip() for c in many.split(",")])
+    return seen
+
+
+def _f64_of_width(comps, names, width, scope=""):
+    """Instructions of ``names`` with a float64 value whose trailing
+    dimension is ``width`` (those of ``scope``, if given)."""
+    return [line.strip() for name in names for line in comps[name]
+            if scope in line and any(
+                dims.split(",")[-1] == str(width) for dims in re.findall(
+                    r"f64\[([\d,]+)\]", line.split(", metadata=")[0]))]
+
+
+def test_a_whole_machine_scan_advances_one_age_a_run_after_epoch_0():
+    """Every occurring epoch ends in the resync checkpoint (all N+1 ages
+    0) and one that does not ends the run, so the epoch scan carries the
+    sawtooth as one age a run: no float64 value of its loop is N+1 wide.
+    Epoch 0, before the loop, advances each node's own age.  Tiles of 512
+    keep the fold's own trees (over 512 and 511 survivors) narrower than
+    N+1 = 1,024."""
+    cfg = dataclasses.replace(
+        paper_scenarios()["scenario1_short_reexec"], t_reexec=0.0,
+        survivors=tuple(NodeStart(3600.0 * (i + 1) / 1024, 3600.0, 0.0)
+                        for i in range(1023)))
+    process = F.Weibull.from_mtbf(0.7, 365 * 24 * 3600.0)
+    with sweep._staged([cfg], process, None, "scan") as (stacked, proc):
+        text = sweep._renewal_study_jit.lower(
+            stacked, jax.random.PRNGKey(0), 24 * 3600.0, proc, n_runs=8,
+            max_failures=6, engine="scan", survivor_tile=512).as_text(
+            dialect="hlo", debug_info=True)
+    comps, entry = _hlo_computations(text)
+    scan, = [line for lines in comps.values() for line in lines
+             if " while(" in line and "renewal_scan" in line]
+    loop = _reached(comps, [re.search(r"body=([\w.-]+)", scan).group(1)])
+    assert _f64_of_width(comps, loop, 1023)       # the survivors' own state
+    assert not _f64_of_width(comps, loop, 1024)
+    outside = _reached(comps, [entry]) - loop
+    assert _f64_of_width(comps, outside, 1024, scope="renewal_scan")
+
+
 def _dispatch_args(trace_dir, n_runs):
     """``(survivors, survivor_tile, tiles)`` of every ``sweep.dispatch``
     span in the trace of one warm Weibull study."""

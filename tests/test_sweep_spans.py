@@ -62,3 +62,28 @@ def test_a_traced_study_shows_its_host_spans(tmp_path):
     names = {ev.name for plane in ProfileData.from_file(path).planes
              for line in plane.lines for ev in line.events}
     assert set(SPANS) <= names
+
+
+@pytest.mark.parametrize("fold", ["stacked", "tiled"])
+def test_the_dispatch_span_counts_the_epochs_of_each_sawtooth(
+        fold, tmp_path, monkeypatch):
+    """``sweep.dispatch`` says over how many epochs the scan advanced the
+    checkpoint sawtooth per node (epoch 0) and per run (the rest), whether
+    a run's fold takes the stacked epochs or survivor tiles in the scan."""
+    process, _ = _processes()["weibull"]
+    n_runs = 8
+    if fold == "tiled":
+        monkeypatch.setattr(sweep, "_SURVIVOR_TILE_ELEMENTS", 64)
+        n_runs = 16           # a shape of its own, traced under this budget
+    kw = dict(n_runs=n_runs, max_failures=6, process=process,
+              makespan_s=30 * 24 * 3600.0)
+    sweep.renewal_monte_carlo_scenarios(CFGS, jax.random.PRNGKey(1), **kw)
+    jax.profiler.start_trace(str(tmp_path))
+    sweep.renewal_monte_carlo_scenarios(CFGS, jax.random.PRNGKey(1), **kw)
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    dispatch, = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name == "sweep.dispatch"]
+    assert (dispatch["node_epochs"], dispatch["run_epochs"]) == (1, 5)
+    assert (dispatch["tiles"] > 1) == (fold == "tiled")
