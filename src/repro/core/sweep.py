@@ -85,7 +85,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1419,6 +1419,20 @@ def _renewal_mc_core(inp: SweepInputs, key: jax.Array, makespan_s, process,
     return out, gaps, failed
 
 
+def _fleet_fold(n_policies: int, n_runs: int, n_epochs: int,
+                n_survivors: int) -> Tuple[Optional[int], int]:
+    """How the fleet core folds one cluster of ``n_policies`` lanes, and
+    how many such clusters one dispatch may hold.  The survivor tile is
+    the cluster's own (``_survivor_tile`` over its ``P`` lanes, as a
+    standalone policy-stacked call on it decides); the clusters are as
+    many as keep the dispatch's fold, all clusters' lanes together, within
+    ``_SURVIVOR_TILE_ELEMENTS``, and at least one."""
+    tile = _survivor_tile(n_policies, n_runs, n_epochs, n_survivors)
+    held = n_epochs * n_survivors if tile is None else tile
+    return tile, max(1, _SURVIVOR_TILE_ELEMENTS // (n_policies * n_runs
+                                                    * held))
+
+
 def _renewal_fleet_mc_core(inp: SweepInputs, key: jax.Array, makespan_s,
                            process, n_runs: int, max_failures: int):
     """``_renewal_mc_core`` in stats mode vmapped over clusters: ``inp``
@@ -1427,11 +1441,18 @@ def _renewal_fleet_mc_core(inp: SweepInputs, key: jax.Array, makespan_s,
     leading ``(C,)`` leaves (``failures.stack_processes``).  Each cluster
     samples its OWN histories at the SAME key, exactly as a standalone
     policy-stacked call on it would (its shapes choose its survivor
-    tiles), so per-cluster rows are bit-identical to standalone calls and
-    batch padding is inert (tests/test_fleet.py pins both)."""
+    tiles, ``_fleet_fold``), so per-cluster rows are bit-identical to
+    standalone calls and batch padding is inert (tests/test_fleet.py pins
+    both).  The fold of ``C`` clusters holds ``C`` times one cluster's:
+    the caller keeps ``C`` within ``_fleet_fold``'s count (docs/fleet.md,
+    "The dispatch bound")."""
+    _, n_policies, n_survivors = inp.period.shape
+    tile, _ = _fleet_fold(n_policies, n_runs, max_failures, n_survivors)
+
     def one_cluster(inp_c, makespan_c, proc_c):
         out, _, _ = _renewal_mc_core(inp_c, key, makespan_c, proc_c, n_runs,
-                                     max_failures, stats=True)
+                                     max_failures, stats=True,
+                                     survivor_tile=tile)
         return out
 
     return jax.vmap(one_cluster)(inp, makespan_s, process)
@@ -1703,9 +1724,11 @@ def renewal_monte_carlo_policies(
     so per-cluster rows are bit-identical to standalone per-cluster calls
     (the fleet CRN contract, tests/test_fleet.py) and answers are
     independent of the batch they shipped in — which is what makes
-    request-batch padding inert (docs/fleet.md).  The cluster axis is
-    scan-engine, stats-only, iid-sampler territory for now (``engine=
-    "pallas"``, ``stats=False``, and ``topology`` all raise).
+    request-batch padding inert (docs/fleet.md).  Clusters go out in as
+    many dispatches as keep each one's survivor fold within the budget
+    (``_fleet_fold``).  The cluster axis is scan-engine, stats-only,
+    iid-sampler territory for now (``engine="pallas"``, ``stats=False``,
+    and ``topology`` all raise).
     """
     if stacked.interval.ndim == 2:
         proc = failures.as_process(process, mtbf_s)
@@ -1734,10 +1757,17 @@ def renewal_monte_carlo_policies(
                 raise ValueError(
                     f"fleet makespan_s must be (C, P) = "
                     f"{stacked.interval.shape}, got {makespan.shape}")
-            out = _renewal_fleet_mc_jit(
-                stacked, key, makespan, proc,
+            _, per = _fleet_fold(stacked.interval.shape[1], n_runs,
+                                 max_failures, stacked.period.shape[-1])
+            part = lambda lo: lambda a: a[lo:lo + per]
+            outs = [_renewal_fleet_mc_jit(
+                jax.tree.map(part(lo), stacked), key, makespan[lo:lo + per],
+                jax.tree.map(part(lo), proc),
                 n_runs=n_runs, max_failures=max_failures)
-            return _wrap_device_stats(out)
+                for lo in range(0, n_clusters, per)]
+            return _wrap_device_stats(jax.tree.map(
+                lambda *a: jnp.concatenate(a), *outs) if len(outs) > 1
+                else outs[0])
     return _renewal_mc_runs(
         stacked, key, makespan_s, process, mtbf_s, n_runs=n_runs,
         max_failures=max_failures, stats=stats, topology=topology,

@@ -9,12 +9,13 @@ with inert lanes, answered by one fused compiled program per bucket, and
 bit-identical to standalone per-cluster ``optimize_policy`` calls at the
 same key.  See docs/fleet.md.
 """
-from repro.fleet.advisor import Advisory, FleetAdvisor
+from repro.fleet.advisor import Advisory, AdvisorStats, FleetAdvisor
 from repro.fleet.cache import CacheStats, DispatchCache
 from repro.fleet.profiles import ClusterProfile, cluster_scenario, synthetic_fleet
 
 __all__ = [
     "Advisory",
+    "AdvisorStats",
     "FleetAdvisor",
     "CacheStats",
     "DispatchCache",

@@ -11,12 +11,20 @@ Serving protocol (the ``launch/serve`` recipe applied to policy tuning):
      last request (inert: vmap cluster lanes are independent, so padded
      lanes cannot perturb real answers — property-tested);
   4. **dispatch** — one fused ``(C, P)`` program per bucket, compiled at
-     most once per bucket key (``DispatchCache``);
+     most once per bucket key (``DispatchCache``); a bucket whose
+     clusters' survivor folds together outgrow the budget goes out in as
+     many dispatches as keep each within it (``sweep._fleet_fold``);
   5. **scatter** — per-cluster optima return in original submit order.
 
 Every answer is bit-identical (CRN, the advisor's fixed key) to a
 standalone ``optimize_policy`` call for that cluster alone — batching is
-a throughput decision, never an accuracy one (tests/test_fleet.py).
+a throughput decision, never an accuracy one (tests/test_fleet.py,
+docs/fleet.md).
+
+A flush names its stages in a profiler trace (``fleet.flush``, then per
+dispatch ``fleet.stage``, ``fleet.dispatch``, ``fleet.fetch`` and
+``fleet.evaluate``), and ``stats()`` counts what was served
+(docs/fleet.md, "Tracing an advisory flush").
 
 ``shard=True`` additionally splits the cluster axis across the host's
 JAX devices with ``jax.pmap`` — pair with
@@ -45,7 +53,7 @@ from repro.launch.batching import (
     scatter,
 )
 
-__all__ = ["Advisory", "FleetAdvisor"]
+__all__ = ["Advisory", "AdvisorStats", "FleetAdvisor"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +73,20 @@ class Advisory:
     @property
     def knee(self) -> dict:
         return self.optimum.knee
+
+
+@dataclasses.dataclass(frozen=True)
+class AdvisorStats:
+    """Counters snapshot of what an advisor has served: requests answered,
+    cluster lanes dispatched (padding included) and of them the padded
+    ones, and the (lane, policy, run, epoch, survivor) decision points
+    dispatched and, in real lanes, answered."""
+
+    requests: int = 0
+    lanes: int = 0
+    padded_lanes: int = 0
+    decisions_dispatched: int = 0
+    decisions_answered: int = 0
 
 
 class FleetAdvisor:
@@ -93,6 +115,8 @@ class FleetAdvisor:
         self.buckets = tuple(buckets)
         self.shard = bool(shard)
         self._pending: List[ClusterProfile] = []
+        self._counts = dict.fromkeys(
+            (f.name for f in dataclasses.fields(AdvisorStats)), 0)
 
         def fleet_core(inp, key, makespan, proc):
             return sweep._renewal_fleet_mc_core(
@@ -122,11 +146,14 @@ class FleetAdvisor:
         if not profiles:
             return []
         groups = group_indices([p.bucket_key() for p in profiles])
-        results = {
-            bkey: self._dispatch_bucket([profiles[i] for i in idx])
-            for bkey, idx in groups.items()
-        }
-        optima = scatter(groups, results)
+        with jax.profiler.TraceAnnotation(
+                "fleet.flush", requests=len(profiles), buckets=len(groups)):
+            results = {
+                bkey: self._dispatch_bucket([profiles[i] for i in idx])
+                for bkey, idx in groups.items()
+            }
+            optima = scatter(groups, results)
+        self._counts["requests"] += len(profiles)
         return [Advisory(request_id=i, profile=p, optimum=o)
                 for i, (p, o) in enumerate(zip(profiles, optima))]
 
@@ -135,6 +162,10 @@ class FleetAdvisor:
         for p in profiles:
             self.submit(p)
         return self.flush()
+
+    def stats(self) -> AdvisorStats:
+        """What the advisor has served so far (``AdvisorStats``)."""
+        return AdvisorStats(**self._counts)
 
     def cache_stats(self) -> CacheStats:
         """Aggregated compiled-program cache counters (jit + pmap paths)."""
@@ -147,24 +178,45 @@ class FleetAdvisor:
     # -- one bucket ---------------------------------------------------------
 
     def _dispatch_bucket(self, profiles: List[ClusterProfile]) -> list:
-        n_real = len(profiles)
+        """One bucket's answers, in as many dispatches as keep each one's
+        survivor fold within the budget (``sweep._fleet_fold``): each
+        cluster folds as it would alone, so splitting moves no answer."""
         n_dev = jax.local_device_count() if self.shard else 1
-        padded = pad_rows(profiles, bucket_size(
-            n_real, self.buckets, multiple_of=n_dev))
-        specs = [p.spec() for p in padded]
-        procs = [s.process for s in specs]
-        stacked_proc = failures.stack_processes(procs)
-        with jax.enable_x64():
-            stacked = optimize.fleet_policy_inputs(
-                [s.cfg for s in specs], self.table)
+        n_surv = profiles[0].n_nodes - 1
+        tile, per_dev = sweep._fleet_fold(len(self.table), self.n_runs,
+                                          self.max_failures, n_surv)
+        cap = per_dev * n_dev
+        starts = range(0, len(profiles), cap)
+        return [answer for lo in starts for answer in self._dispatch(
+            profiles[lo:lo + cap], cap, tile, len(starts), n_dev)]
+
+    def _dispatch(self, profiles: List[ClusterProfile], cap: int,
+                  tile: Optional[int], dispatches: int, n_dev: int) -> list:
+        n_real = len(profiles)
+        with jax.profiler.TraceAnnotation("fleet.stage"):
+            # the dispatch's cap stands in for every larger batch bucket
+            padded = pad_rows(profiles, bucket_size(
+                n_real, [b for b in self.buckets if b <= cap],
+                multiple_of=n_dev))
+            specs = [p.spec() for p in padded]
+            procs = [s.process for s in specs]
+            stacked_proc = failures.stack_processes(procs)
+            with jax.enable_x64():
+                stacked = optimize.fleet_policy_inputs(
+                    [s.cfg for s in specs], self.table)
             makespans = np.stack([
                 optimize.wall_makespan(s.work_s, self.table.ckpt_interval,
                                        s.cfg.ckpt_duration)
                 for s in specs])                               # (C, P)
-            c = len(specs)
-            n_surv = len(specs[0].cfg.survivors)
-            bkey = (c, n_surv, padded[0].family, len(self.table),
-                    self.n_runs, self.max_failures)
+        c, n_policies = makespans.shape
+        n_surv = len(specs[0].cfg.survivors)
+        bkey = (c, n_surv, padded[0].family, n_policies, self.n_runs,
+                self.max_failures)
+        tiles = 1 if tile is None else self.max_failures * -(-n_surv // tile)
+        with jax.profiler.TraceAnnotation(
+                "fleet.dispatch", clusters=n_real, padded=c - n_real,
+                survivors=n_surv, survivor_tile=tile or n_surv, tiles=tiles,
+                dispatches=dispatches), jax.enable_x64():
             if self.shard:
                 fn = self._pmap_cache.get(bkey + ("pmap", n_dev))
                 shard = lambda a: jnp.asarray(a).reshape(
@@ -176,11 +228,29 @@ class FleetAdvisor:
             else:
                 out = self._cache.get(bkey)(
                     stacked, self.key, jnp.asarray(makespans), stacked_proc)
-            stats = jax.device_get(sweep._wrap_device_stats(out))
+        n_bytes = sum(a.nbytes for a in out.values())
+        with jax.profiler.TraceAnnotation("fleet.fetch", bytes=n_bytes):
+            out = jax.device_get(out)
+        # host numpy only: the device is idle here, under this span alone
+        with jax.profiler.TraceAnnotation("fleet.evaluate"):
+            optima = self._evaluate(out, specs, makespans, n_real)
+        per_lane = n_policies * self.n_runs * self.max_failures * n_surv
+        counts = self._counts
+        counts["lanes"] += c
+        counts["padded_lanes"] += c - n_real
+        counts["decisions_dispatched"] += c * per_lane
+        counts["decisions_answered"] += n_real * per_lane
+        return optima
+
+    def _evaluate(self, out: dict, specs: list, makespans: np.ndarray,
+                  n_real: int) -> list:
+        """Each real cluster's grid and optimum from the fetched statistics
+        (``out``: host arrays with a leading cluster axis), numpy only."""
         optima = []
         for ci in range(n_real):
-            stats_c = jax.tree.map(lambda a, _c=ci: a[_c], stats)
-            proc_c = procs[ci]
+            stats_c = sweep._wrap_device_stats(
+                {k: v[ci] for k, v in out.items()})
+            proc_c = specs[ci].process
             res = optimize._policy_eval_from_stats(
                 self.table, specs[ci].cfg.name, stats_c, makespans[ci],
                 specs[ci].work_s, float(np.mean(proc_c.mean_s())),

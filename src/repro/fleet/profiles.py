@@ -11,12 +11,15 @@ is exactly the post-recovery renewal state the Monte-Carlo engine
 re-anchors to between failures (``scenarios.post_recovery_config``), so a
 profile's answer does not depend on an arbitrary mid-epoch phase choice.
 
-``power_scale`` models the per-node power heterogeneity of
-"Checkpoint and Restart: An Energy Consumption Characterization in
-Clusters" (PAPERS.md): one multiplier over the whole paper ladder
-(compute, checkpoint, base, wait, and sleep powers alike), leaving
-slowdowns — and therefore Algorithm 1's *frequency* choice — untouched
-while scaling every joule the advisor trades off.
+``machine`` is the node's ``MachineProfile`` (its DVFS ladder, sleep
+state and base powers), the paper's Table-3 Xeon when not given; a
+deployment's own machine lowers from its configuration.  ``power_scale``
+models the per-node power heterogeneity of "Checkpoint and Restart: An
+Energy Consumption Characterization in Clusters" (PAPERS.md): one
+multiplier over the whole ladder (compute, checkpoint, base, wait, and
+sleep powers alike), leaving slowdowns — and therefore Algorithm 1's
+*frequency* choice — untouched while scaling every joule the advisor
+trades off.
 """
 from __future__ import annotations
 
@@ -26,7 +29,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from repro.core import optimize
-from repro.core.characterization import paper_machine_profile
+from repro.core.characterization import MachineProfile, paper_machine_profile
 from repro.core.failures import Exponential, FailureProcess, Weibull
 from repro.core.simulator import NodeStart, ScenarioConfig
 
@@ -43,7 +46,8 @@ class ClusterProfile:
     epoch models, so survivors = ``n_nodes - 1`` — the static shape the
     serving layer buckets requests by (``bucket_key``).  ``work_s`` is the
     job's remaining useful work, the equal-work horizon the policy grid is
-    scored over.
+    scored over.  ``machine`` is the node the ladder comes from (None: the
+    paper's ``paper_machine_profile()``), scaled by ``power_scale``.
     """
 
     name: str = "cluster"
@@ -57,6 +61,7 @@ class ClusterProfile:
     t_down: float = 60.0
     t_restart: float = 60.0
     work_s: float = 7 * 24 * 3600.0
+    machine: Optional[MachineProfile] = None
 
     def __post_init__(self):
         if self.n_nodes < 2:
@@ -79,7 +84,8 @@ class ClusterProfile:
     def scenario(self) -> ScenarioConfig:
         """The balanced post-recovery snapshot this profile lowers to."""
         n_surv = self.n_nodes - 1
-        profile = _scaled_profile(self.power_scale)
+        profile = _scaled_profile(self.machine or paper_machine_profile(),
+                                  self.power_scale)
         survivors = tuple(
             NodeStart(
                 exec_to_rendezvous=self.period_s * (i + 1) / self.n_nodes,
@@ -111,8 +117,7 @@ class ClusterProfile:
         )
 
 
-def _scaled_profile(power_scale: float):
-    base = paper_machine_profile()
+def _scaled_profile(base: MachineProfile, power_scale: float):
     if power_scale == 1.0:
         return base
     pt = base.power_table

@@ -12,7 +12,8 @@ at the sizes ``chip_smoke.py`` runs:
     engine's Monte-Carlo reduced over runs on the device), also at a
     whole-machine job's shape (1,023 survivors, folded inside the epoch
     scan);
-  * the fleet core at a 64-cluster bucket;
+  * the fleet core at a 64-cluster bucket, and at the dispatches of
+    128-node jobs whose folds together exceed the survivor fold's budget;
   * the SSD kernel at mamba2-370m widths;
   * the flash-attention kernel at a GQA shape (8 query heads over 4 kv
     heads, 2048 tokens, head dim 128, bfloat16).
@@ -165,6 +166,42 @@ def test_fleet_core_compiles(one_chip):
             _abstract(failures.stack_processes([s.process for s in specs]),
                       one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("max_failures,most", [(32, 2e9), (64, 1e9)])
+def test_fleet_dispatches_of_a_bucket_over_the_budget_compile_within_it(
+        one_chip, max_failures, most):
+    """Four 128-node jobs of a 1,024-node machine (Weibull nodes, the
+    advisor's 42-policy grid of 128 runs): at 32 epochs each job alone
+    folds its stacked epochs within the budget and two do not, so each
+    goes out alone (the four in one program took 6.64 GB); at 64 epochs,
+    the advisory cell's, each folds inside the epoch scan and the four
+    share one program.  Each dispatch's temporaries stay under ``most``."""
+    day = 24 * 3600.0
+    profiles = [fleet.ClusterProfile(n_nodes=128, period_s=3600.0,
+                                     mtbf_s=365 * day, family="weibull",
+                                     work_s=(2 + i) * day) for i in range(4)]
+    table = optimize.default_policy_table(
+        fleet.ClusterProfile(n_nodes=64).scenario(), 365 * day / 64)
+    n_runs = 128
+    _, per = sweep._fleet_fold(len(table), n_runs, max_failures, 127)
+    specs = [p.spec() for p in profiles[:per]]
+    assert len(specs) == {32: 1, 64: 4}[max_failures]
+    with jax.enable_x64():
+        stacked = optimize.fleet_policy_inputs([s.cfg for s in specs], table)
+        makespans = np.stack([
+            optimize.wall_makespan(s.work_s, table.ckpt_interval,
+                                   s.cfg.ckpt_duration) for s in specs])
+        core = jax.jit(functools.partial(
+            sweep._renewal_fleet_mc_core, n_runs=n_runs,
+            max_failures=max_failures))
+        compiled = core.lower(
+            _abstract(stacked, one_chip),
+            _abstract(jax.random.PRNGKey(0), one_chip),
+            _abstract(makespans, one_chip),
+            _abstract(failures.stack_processes([s.process for s in specs]),
+                      one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < most
 
 
 def test_ssd_kernel_compiles_at_mamba2_370m_widths(one_chip):
